@@ -86,9 +86,15 @@ class StreamVerifier:
         # types/validation.go:13-17, applied to the streaming path)
         self.min_device_sigs = min_device_sigs
         # private staging pool, 3 deep: up to 2 chunks fly while a 3rd
-        # packs (the double-buffer window below), so rotation can never
-        # hand back a buffer whose upload is still the newest dispatch
+        # packs (the double-buffer window below), and a chunk's buffers
+        # come round again only after that chunk was collected
         self._staging = StagingPool(slots=3)
+        # which path each dispatched chunk took: cached table with the
+        # rows stamped on device, cached table with host-packed rows,
+        # or the dense general kernel (mixed valsets, malformed rows).
+        # The fallbacks between them are silent by design; the counts
+        # are how a caller sees which one it got.
+        self.chunks = {"stamped": 0, "host_packed": 0, "dense": 0}
 
     # -- packing -----------------------------------------------------------
 
@@ -200,6 +206,7 @@ class StreamVerifier:
         pending = self._stamp_chunk(jobs, sigs, row_ts, row_job, pos,
                                     B, cap, table, thresh)
         if pending is not None:
+            self.chunks["stamped"] += 1
             return _Chunk(list(jobs), np.asarray(row_job),
                           np.asarray(row_idx), pending, row_pos=pos)
         # dense native/numpy pack, then scatter to the strided layout
@@ -250,6 +257,7 @@ class StreamVerifier:
         rows = ec.pack_rows_cached(pb, counted, commit_ids, thresh,
                                    out=out)
         pending = ec.verify_tally_rows_cached(rows, table, cap)
+        self.chunks["host_packed"] += 1
         return _Chunk(list(jobs), np.asarray(row_job),
                       np.asarray(row_idx), pending, row_pos=pos)
 
@@ -286,8 +294,10 @@ class StreamVerifier:
         nan_a = np.fromiter((nn for _, nn in row_ts), np.int64,
                             count=len(row_ts))
         try:
-            ent = ec.template_entry(sites)
-        except Exception:  # noqa: BLE001 - oversized site: host pack
+            # padded to the chunk's job capacity: one stamp program per
+            # validator-set size, however many blocks a run carries
+            ent = ec.template_entry(sites, pad_to=cap)
+        except ValueError:  # site count over the template matrix
             return None
         pool = self._staging
         dsig = pool.get("chunk.dsig", (B, 64), np.uint8)
@@ -399,6 +409,7 @@ class StreamVerifier:
 
         pending = self._dispatch(pb, power5, counted, commit_ids, thresh,
                                  c_pad)
+        self.chunks["dense"] += 1
         return _Chunk(jobs, np.asarray(row_job), np.asarray(row_idx),
                       pending)
 

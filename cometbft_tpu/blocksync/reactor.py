@@ -19,7 +19,11 @@ import threading
 import time
 from typing import Callable, List, Optional
 
-from cometbft_tpu.blocksync.pipeline import CommitJob, StreamVerifier
+from cometbft_tpu.blocksync.pipeline import (
+    CommitJob,
+    StreamVerifier,
+    make_stream_verifier,
+)
 from cometbft_tpu.blocksync.pool import BlockPool
 from cometbft_tpu.libs import failpoints as fp
 from cometbft_tpu.libs import tracing
@@ -52,7 +56,9 @@ class BlocksyncReactor(BaseService):
         self.block_exec = block_exec
         self.block_store = block_store
         self.pool = BlockPool(state.last_block_height + 1)
-        self.verifier = stream_verifier or StreamVerifier(use_pallas=False)
+        # the catch-up engine's choice (catchup.py): the cached Pallas
+        # path where an accelerator exists, the XLA kernel on a CPU
+        self.verifier = stream_verifier or make_stream_verifier()
         self.on_caught_up = on_caught_up
         self.poll_interval = poll_interval
         self.banned_peers: List[str] = []

@@ -140,9 +140,26 @@ def _parse_addr(laddr: str):
 
 def cmd_start(args) -> int:
     """run_node.go: assemble, listen, dial persistent peers, serve RPC."""
+    from cometbft_tpu.libs import deviceledger
     from cometbft_tpu.p2p.key import NetAddress
 
-    node, cfg = build_node(args.home)
+    cfg = load_config(_config_path(args.home))
+    if args.verifier:
+        # which process owns the chip is the launch's decision: a chip
+        # belongs to one process, so every other node on the machine
+        # starts with --verifier cpu
+        cfg.crypto.verifier = args.verifier
+    if cfg.crypto.verifier == "tpu":
+        # a tpu verifier compiles, and does so through the persistent
+        # cache, set before the first jit (a cpu verifier never
+        # initializes a JAX backend)
+        from cometbft_tpu.libs.jax_cache import (
+            enable_persistent_compile_cache,
+        )
+
+        print(f"compile cache: {enable_persistent_compile_cache()}")
+    node, cfg = build_node(args.home, cfg)
+    print(f"verifier {cfg.crypto.verifier}: {deviceledger.device_line()}")
     host, port = _parse_addr(cfg.p2p.laddr)
     node.start()
     addr = node.listen(host, port)
@@ -546,6 +563,10 @@ def main(argv=None) -> int:
     _home_arg(p)
     p.add_argument("--run-for", type=float, default=0,
                    help="exit after N seconds (0 = forever)")
+    p.add_argument("--verifier", default="", choices=["", "tpu", "cpu"],
+                   help="override [crypto] verifier: a process that "
+                        "starts with tpu owns the chip, so on a "
+                        "one-chip machine every other node runs cpu")
     p.set_defaults(fn=cmd_start)
 
     p = sub.add_parser("testnet", help="generate a localhost testnet")

@@ -10,16 +10,15 @@ section selecting the signature-verification backend — `verifier =
 from __future__ import annotations
 
 import os
-
-try:
-    import tomllib
-except ImportError:  # Python < 3.11: the vendored tomli is identical
-    import tomli as tomllib
+import tomllib
 from dataclasses import dataclass, field
 
 
 class ConfigError(Exception):
     pass
+
+
+_NO_ACCELERATOR_LOGGED = False
 
 
 @dataclass
@@ -153,6 +152,16 @@ class CryptoConfig:
             return None
         from cometbft_tpu.types import validation
 
+        global _NO_ACCELERATOR_LOGGED
+        if not cbatch._accel_backend() and not _NO_ACCELERATOR_LOGGED:
+            _NO_ACCELERATOR_LOGGED = True  # once per process, at start
+            import logging
+
+            logging.getLogger(__name__).error(
+                "[crypto] verifier = \"tpu\" but JAX found no "
+                "accelerator (a chip belongs to one process; another "
+                "may hold it): signatures verify on the CPU backend. "
+                "Start this node with --verifier cpu to say so.")
         return validation.device_batch_fn()
 
 

@@ -77,7 +77,7 @@ HBM_SLOT_BUDGET = 65536
 # per-message ledgers there is no allocation budget to defend here,
 # only the read-side shape discipline).
 (_C_SEQ, _C_TS, _C_DUR, _C_PCACHE, _C_SITE, _C_FLUSH,
- _C_STEADY) = range(7)
+ _C_STEADY, _C_FUN) = range(8)
 
 
 class CompileLedger:
@@ -86,7 +86,7 @@ class CompileLedger:
     whichever thread compiled (dispatcher, warmer, main)."""
 
     FIELDS = ("seq", "ts_ms", "dur_ms", "pcache_hit", "site",
-              "flush_seq", "steady")
+              "flush_seq", "steady", "fun")
 
     __slots__ = ("_ring", "_lock", "_seq", "compiles", "compile_s",
                  "pcache_hits", "steady_compiles", "steady")
@@ -106,9 +106,10 @@ class CompileLedger:
             return len(self._ring)
 
     def record(self, dur_s: float, pcache_hit: bool, site: str,
-               flush_seq: int) -> bool:
+               flush_seq: int, fun: str = "") -> bool:
         """One compile event; returns True when it was a STEADY-STATE
-        backend compile (the caller feeds the compile_storm window)."""
+        backend compile (the caller feeds the compile_storm window).
+        `fun` is the jitted function JAX named for it."""
         t = tracing.monotonic_ns()
         with self._lock:
             seq = self._seq
@@ -124,8 +125,17 @@ class CompileLedger:
             self._ring.append([seq, round(t / 1e6, 3),
                                round(dur_s * 1e3, 3),
                                1 if pcache_hit else 0, site, flush_seq,
-                               1 if steady else 0])
+                               1 if steady else 0, fun])
         return steady
+
+    def name_last_hit(self, fun: str) -> None:
+        """JAX names a cache hit's function only after the hit event
+        (on the duration event that follows): fill it in."""
+        with self._lock:
+            for r in reversed(self._ring):
+                if r[_C_PCACHE] and not r[_C_FUN]:
+                    r[_C_FUN] = fun
+                    return
 
     def mark_steady(self) -> None:
         """The shapes this process flushes are compiled: every further
@@ -273,7 +283,8 @@ class attr_context:
         attr_end(self._fr)
 
 
-def record_compile(dur_s: float, pcache_hit: bool = False) -> None:
+def record_compile(dur_s: float, pcache_hit: bool = False,
+                   fun: str = "") -> None:
     """The recording core (jax-free — cfg15's smoke drives it with no
     jax in the process): attribute to this thread's innermost frame,
     append the ledger record, and feed the compile_storm window when
@@ -288,7 +299,7 @@ def record_compile(dur_s: float, pcache_hit: bool = False) -> None:
             for fr in stack:
                 fr.ms += d
             top.n += 1
-    if _LEDGER.record(dur_s, pcache_hit, site, fseq):
+    if _LEDGER.record(dur_s, pcache_hit, site, fseq, fun):
         incidents.note_compile(1)
 
 
@@ -303,11 +314,23 @@ _ARM_LOCK = threading.Lock()
 
 def _on_duration(key, dur, **kw) -> None:
     if key == "/jax/core/compile/backend_compile_duration":
-        record_compile(float(dur), pcache_hit=False)
+        fun = str(kw.get("fun_name") or "")
+        # JAX times compile_or_get_cached as a whole, so this event
+        # also fires for an executable the persistent cache supplied
+        # (the cache_hits event just before it, same thread, says so):
+        # that one was recorded as a hit and is not a backend compile
+        if getattr(_TLS, "pcache_hit", False):
+            _TLS.pcache_hit = False
+            _LEDGER.name_last_hit(fun)
+            return
+        record_compile(float(dur), pcache_hit=False, fun=fun)
 
 
 def _on_event(key, **kw) -> None:
-    if key == "/jax/compilation_cache/cache_hits":
+    if key == "/jax/compilation_cache/compile_requests_use_cache":
+        _TLS.pcache_hit = False  # a new request: no hit seen yet
+    elif key == "/jax/compilation_cache/cache_hits":
+        _TLS.pcache_hit = True
         record_compile(0.0, pcache_hit=True)
 
 
@@ -336,6 +359,58 @@ def arm_compile_listener() -> bool:
 
 def listener_armed() -> bool:
     return _ARMED
+
+
+# --------------------------------------------------------------------------
+# which device this process runs on. Asking JAX initializes its backend,
+# and a process that does so holds the chip, so the answer is taken once
+# at the seams that are about to compute anyway (crypto.batch's backend
+# choice, the chip entry points) and kept here for /dump_devices and the
+# node's start line, which must never touch JAX themselves.
+# --------------------------------------------------------------------------
+
+_DEVICE = {"platform": None, "device_kind": None, "n_devices": 0}
+
+
+class NoAcceleratorError(RuntimeError):
+    """JAX found no accelerator where the caller needs one."""
+
+
+def probe_device() -> dict:
+    """Ask JAX for (platform, device_kind, n_devices) and record the
+    answer. Raises what backend initialization raises."""
+    import jax
+
+    devs = jax.devices()
+    _DEVICE.update(platform=devs[0].platform,
+                   device_kind=devs[0].device_kind, n_devices=len(devs))
+    return dict(_DEVICE)
+
+
+def device_info() -> dict:
+    """The last probe_device() answer; platform None before any."""
+    return dict(_DEVICE)
+
+
+def device_line() -> str:
+    """device_info() as the start lines print it."""
+    return (f"platform={_DEVICE['platform']} "
+            f"device_kind={_DEVICE['device_kind']!r} "
+            f"n_devices={_DEVICE['n_devices']}")
+
+
+def require_accelerator() -> dict:
+    """probe_device() for entry points that measure or prove the chip
+    (chip_smoke.py, bench.py full mode, tools/tpu_differential.py):
+    they refuse to run on the CPU backend instead of degrading."""
+    info = probe_device()
+    if info["platform"] == "cpu":
+        raise NoAcceleratorError(
+            "JAX found no accelerator: jax.devices()[0].platform is "
+            f"'cpu' ({info['n_devices']} device(s), "
+            f"kind {info['device_kind']!r}); this entry point needs "
+            "the TPU and does not fall back")
+    return info
 
 
 # --------------------------------------------------------------------------
@@ -727,6 +802,7 @@ def dump_devices() -> dict:
         fams = residency()
         rec = reconcile(fams)
     doc = {
+        "device": device_info(),
         "summary": counters(),
         "compiles": _LEDGER.records(),
         "residency": {
@@ -748,6 +824,14 @@ def dump_devices() -> dict:
         fam: sum(s["bytes"] for s in devs.values())
         for fam, devs in fams.items()
     }
+    # the device breaker next to the device it guards: `faults` counts
+    # every dispatch that fell back to the host, tripped or not
+    cb = sys.modules.get("cometbft_tpu.crypto.batch")
+    if cb is not None:
+        brk = cb.device_breaker()
+        doc["breaker"] = {"state": brk.state, "faults": brk.faults,
+                          "trips": brk.trips, "closes": brk.closes,
+                          "probes": brk.probes}
     vp = sys.modules.get("cometbft_tpu.verifyplane.plane")
     plane = vp and (vp._GLOBAL or vp._LAST)
     if plane is not None:

@@ -1,31 +1,34 @@
-"""Persistent JAX compilation-cache knobs, shared by the test suite
-(tests/conftest.py) and the driver entry (__graft_entry__.py).
+"""Where the persistent JAX compilation cache lives.
 
-The interpret-mode Pallas verify kernel costs minutes per compile on a
-1-core CPU host; with the on-disk cache enabled only the first-ever run
-pays (cache keys include backend + jax version, so TPU runs are
-unaffected). One helper so the two call sites can never drift apart and
-silently split the cache.
+Every entry point that compiles (chip_smoke.py, bench.py, `python -m
+cometbft_tpu start`, tools/tpu_differential.py, tests/conftest.py)
+calls this one helper before its first jit, so they all share one
+cache and none runs without it: a cold Mosaic compile of one verify
+kernel costs tens of seconds, and a fresh process would otherwise pay
+it for every kernel family again.
+
+Where `JAX_COMPILATION_CACHE_DIR` is set, JAX reads it itself and this
+helper sets no directory. Where it is not, the cache is a fixed path
+inside the checkout: the directory is part of what a later process
+must find again, so it never depends on /tmp, a pid or a time.
 """
 from __future__ import annotations
 
 import os
-from typing import Optional
 
-DEFAULT_CACHE_DIR = "/tmp/cbt_jax_cache"
-ENV_VAR = "CBT_JAX_CACHE_DIR"
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))),
+    ".jax_cache",
+)
 
 
-def enable_persistent_compile_cache(
-    cache_dir: Optional[str] = None,
-) -> str:
-    """Point jax at the shared on-disk compilation cache; returns the
-    directory used. Safe to call repeatedly."""
+def enable_persistent_compile_cache() -> str:
+    """Make sure this process compiles through the persistent cache;
+    returns the directory in use. Safe to call repeatedly."""
     import jax
 
-    path = cache_dir or os.environ.get(ENV_VAR, DEFAULT_CACHE_DIR)
-    jax.config.update("jax_compilation_cache_dir", path)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
-    return path
+    if not os.environ.get(ENV_VAR):
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+    return jax.config.jax_compilation_cache_dir

@@ -4,16 +4,25 @@ Compiles hostaccel.cpp to a shared object on first use (g++ is part of
 the image toolchain; no pybind11 — plain `ctypes` over an extern "C"
 ABI) and exposes numpy-friendly wrappers. Every entry point has a
 pure-Python fallback, so the package works identically when no
-compiler is present — `available()` says which path is live.
+compiler is present — `available()` says which path is live, and a
+failed build is logged at warning level once.
+
+The binary is named by the SHA-256 of the source it was built from, so
+a binary left on disk by an older hostaccel.cpp (the working tree keeps
+`*.so` out of git, and a copied tree carries them along) can never
+load: a changed source is a new name, which does not exist until this
+process builds it.
 """
 from __future__ import annotations
 
 import ctypes
+import glob
 import hashlib
 import logging
 import os
 import subprocess
 import threading
+import time
 from typing import Optional, Sequence
 
 import numpy as np
@@ -22,43 +31,63 @@ _log = logging.getLogger(__name__)
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "hostaccel.cpp")
-_SO = os.path.join(_DIR, "_hostaccel.so")
 
 _lock = threading.Lock()
 _lib = None
 _tried = False
+_built_s: Optional[float] = None  # seconds, when THIS process compiled
 
 
-def _compile() -> bool:
+def _so_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_DIR, f"_hostaccel.{digest}.so")
+
+
+def _compile(so: str) -> bool:
     # Compile to a per-pid temp path and os.replace() into place:
     # concurrent processes (e.g. the multi-process e2e testnet) would
     # otherwise interleave writes into the shared .so and a reader could
     # dlopen a permanently corrupt file.
-    tmp = f"{_SO}.{os.getpid()}.tmp"
+    global _built_s
+    tmp = f"{so}.{os.getpid()}.tmp"
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-o", tmp, _SRC]
+    t0 = time.monotonic()
     try:
         r = subprocess.run(cmd, capture_output=True, text=True,
                            timeout=120)
     except (OSError, subprocess.TimeoutExpired) as e:
-        _log.info("hostaccel compile unavailable: %s", e)
+        _log.warning("hostaccel compile unavailable (%s): the host "
+                     "pack runs on the pure-Python path", e)
         try:
             os.unlink(tmp)
         except OSError:
             pass
         return False
     if r.returncode != 0:
-        _log.warning("hostaccel compile failed:\n%s", r.stderr[-2000:])
+        _log.warning("hostaccel compile failed; the host pack runs on "
+                     "the pure-Python path:\n%s", r.stderr[-2000:])
         try:
             os.unlink(tmp)
         except OSError:
             pass
         return False
     try:
-        os.replace(tmp, _SO)
+        os.replace(tmp, so)
     except OSError as e:
         _log.warning("hostaccel install failed: %s", e)
         return False
+    _built_s = round(time.monotonic() - t0, 3)
     return True
+
+
+def _remove_binaries(keep: Optional[str] = None) -> None:
+    for path in glob.glob(os.path.join(_DIR, "_hostaccel*.so")):
+        if path != keep:
+            try:
+                os.unlink(path)
+            except OSError:
+                pass
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -67,14 +96,13 @@ def _load() -> Optional[ctypes.CDLL]:
         if _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_SO) or (
-            os.path.exists(_SRC)
-            and os.path.getmtime(_SRC) > os.path.getmtime(_SO)
-        ):
-            if not _compile():
+        so = _so_path()
+        if not os.path.exists(so):
+            if not _compile(so):
                 return None
+            _remove_binaries(keep=so)  # builds of older sources
         try:
-            lib = ctypes.CDLL(_SO)
+            lib = ctypes.CDLL(so)
             assert lib.hostaccel_abi_version() == 1
         except (OSError, AttributeError, AssertionError) as e:
             _log.warning("hostaccel load failed: %s", e)
@@ -115,6 +143,29 @@ def _load() -> Optional[ctypes.CDLL]:
         lib.sr25519_batch_challenges.restype = None
         _lib = lib
         return _lib
+
+
+def rebuild() -> dict:
+    """Throw away every built binary and compile hostaccel.cpp again
+    now (chip_smoke.py: the library a chip run loads was built in that
+    run, from the source git tracks). Must run before first use."""
+    global _tried
+    with _lock:
+        if _lib is not None:
+            raise RuntimeError("hostaccel already loaded in this process")
+        _remove_binaries()
+        _tried = False
+    return build_info()
+
+
+def build_info() -> dict:
+    """Which path is live: the binary's path (named by source hash) and
+    the seconds this process spent compiling it, None if it was found
+    on disk."""
+    ok = _load() is not None
+    return {"available": ok,
+            "binary": os.path.basename(_so_path()) if ok else None,
+            "built_s": _built_s}
 
 
 def available() -> bool:

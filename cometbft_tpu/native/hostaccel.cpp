@@ -10,7 +10,7 @@
 // toolchain is plain g++); differentially tested against hashlib in
 // tests/test_native.py.
 //
-// Build: g++ -O3 -shared -fPIC -o _hostaccel.so hostaccel.cpp
+// Build: g++ -O3 -shared -fPIC -o _hostaccel.<source sha256>.so hostaccel.cpp
 // (done on demand by cometbft_tpu/native/__init__.py).
 
 #include <cstdint>

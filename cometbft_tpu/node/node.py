@@ -310,9 +310,20 @@ class Node(BaseService):
             self.switch.add_reactor(self.mempool_reactor)
             if blocksync:
                 # syncing node: blocksync drives first, consensus starts
-                # at SwitchToConsensus (node.go:527 sequencing)
+                # at SwitchToConsensus (node.go:527 sequencing). A node
+                # told to verify on the host (batch_fn None: [crypto]
+                # verifier = "cpu") does so here too and never asks JAX
+                # for a device another process owns.
+                host_sv = None
+                if batch_fn is None:
+                    from cometbft_tpu.blocksync.catchup import (
+                        HostCommitVerifier,
+                    )
+
+                    host_sv = HostCommitVerifier()
                 self.blocksync_engine = BlocksyncReactor(
                     state, self.block_exec, self.block_store,
+                    stream_verifier=host_sv,
                     on_caught_up=self._switch_to_consensus,
                 )
             # every p2p node SERVES blocks even when not syncing itself
@@ -400,6 +411,17 @@ class Node(BaseService):
 
             self.verify_plane.start()
             verifyplane.set_global_plane(self.verify_plane)
+            # compile before serving: consensus starts below, and the
+            # first vote must not wait out a cold compile (prints, like
+            # the cmd/cli start lines: which device this node verifies
+            # on is what an operator checks first)
+            secs = self.verify_plane.prime(self.consensus.state.validators,
+                                           self.consensus.state.chain_id)
+            print(f"verify plane: {deviceledger.device_line()}; "
+                  + ("host path, nothing to compile" if secs is None
+                     else f"fused flush for "
+                          f"{len(self.consensus.state.validators)} "
+                          f"validators ready in {secs:.1f}s"))
             if self.verify_plane._mesh_devices is not None:
                 # resolve the flush mesh now so a misconfigured
                 # multichip node reports its real fan-out at START,

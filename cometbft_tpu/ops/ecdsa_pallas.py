@@ -29,7 +29,7 @@ from jax.experimental.pallas import tpu as pltpu
 from cometbft_tpu.crypto import secp256k1_ref as ref
 from cometbft_tpu.ops import ecdsa_kernel as ek
 from cometbft_tpu.ops.field import FSECP, NLIMBS
-from cometbft_tpu.ops.field_lf import FieldLF, const_col
+from cometbft_tpu.ops.field_lf import FieldLF, const_col, interpret_mode
 
 FS = FieldLF(FSECP)
 B_TILE = 128
@@ -246,7 +246,7 @@ def _verify_rows(rows, base):
     )
     out = pl.pallas_call(
         _kernel,
-        interpret=(jax.default_backend() == "cpu"),
+        interpret=interpret_mode(),
         out_shape=jax.ShapeDtypeStruct((1, B), jnp.int32),
         grid=grid,
         in_specs=[col(E_KROWS), full],

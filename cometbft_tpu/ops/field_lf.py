@@ -28,6 +28,14 @@ import numpy as np
 from cometbft_tpu.ops.field import LIMB_BITS, NLIMBS, Field
 
 
+def interpret_mode() -> bool:
+    """Whether pallas_call runs the kernels of this dialect in the
+    Pallas interpreter: only on the CPU backend (the tier-1 tests),
+    which has no Mosaic. Every kernel asks here, so a chip entry point
+    can check once that the answer is False."""
+    return jax.default_backend() == "cpu"
+
+
 def const_col(limbs, b: int):
     """Materialize limb constants as an (n, b) int32 array in-trace.
 

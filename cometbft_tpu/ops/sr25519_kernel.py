@@ -55,7 +55,7 @@ from cometbft_tpu.ops.ed25519_pallas import (
     pt_neg,
 )
 from cometbft_tpu.ops.field import NLIMBS, F25519
-from cometbft_tpu.ops.field_lf import const_col
+from cometbft_tpu.ops.field_lf import const_col, interpret_mode
 
 
 def rist_decode(s, d_col, sqrt_m1_col):
@@ -177,7 +177,7 @@ def _verify_rows_sr(rows, base):
     )
     out = pl.pallas_call(
         _kernel_sr,
-        interpret=(jax.default_backend() == "cpu"),
+        interpret=interpret_mode(),
         out_shape=jax.ShapeDtypeStruct((1, B), jnp.int32),
         grid=grid,
         in_specs=[col(C_KROWS), full],

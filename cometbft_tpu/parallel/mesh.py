@@ -30,22 +30,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from cometbft_tpu.ops import ed25519_kernel as ek
 
-# jax.shard_map went top-level in 0.5.x; older containers only have the
-# experimental module (and spell the unchecked-replication kwarg
-# check_rep instead of check_vma). One shim keeps every builder below
-# running on both.
-if hasattr(jax, "shard_map"):
-    _shard_map, _UNCHECKED_KW = jax.shard_map, "check_vma"
-else:  # pragma: no cover - exercised on jax<0.5 containers
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _UNCHECKED_KW = "check_rep"
-
-
 def _smap(fn, mesh, in_specs, out_specs, unchecked: bool = False):
-    kw = {_UNCHECKED_KW: False} if unchecked else {}
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
+    kw = {"check_vma": False} if unchecked else {}
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 def make_mesh(devices=None, axis: str = "batch") -> Mesh:

@@ -46,7 +46,19 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from cometbft_tpu.libs.deviceledger import rows_bucket
+
 MAX_FUSED_ROWS = 65536  # per-device rows budget (B_loc when sharded)
+
+# Shape buckets. The stride count and the group count are shapes of the
+# jitted flush (B = n_dev * strides * M rows, a (groups, limbs)
+# threshold matrix), and every new shape costs a trace, a Mosaic
+# lowering and a compile of tens of seconds on the dispatcher thread —
+# longer than a vote waits for its verdict. Rounding both up keeps a
+# node to the few programs its start-up compile (VerifyPlane.prime)
+# covers: padded strides are dead rows, padded groups have a threshold
+# no tally reaches.
+MIN_FUSED_COMMITS = 4
 
 # Test seam: tier-1 has no accelerator, so the sharded plumbing is
 # proven on a forced multi-device CPU host with the expensive kernels
@@ -407,9 +419,13 @@ def plan_fused(batch, pool=None, mesh=None, half=None,
         except ValueError:
             return None  # over even the full mesh's table budget
     mesh, n_dev, M = chosen
+    # shape buckets (MIN_FUSED_COMMITS): strides round up to a power of
+    # two where the rows budget allows it, else stay exact
+    if rows_bucket(n_strides) * M <= MAX_FUSED_ROWS:
+        n_strides = rows_bucket(n_strides)
     B = n_dev * n_strides * M
 
-    n_commits = len(groups)
+    n_commits = max(MIN_FUSED_COMMITS, rows_bucket(len(groups)))
     pos = shard_positions(row_v, row_s, M, n_strides)
     counted_pos = [None if ci is None else int(pos[ci])
                    for ci in counted_ridx]
@@ -423,6 +439,7 @@ def plan_fused(batch, pool=None, mesh=None, half=None,
 
         pool = staging_pool()
     thresh = np.zeros((n_commits, ek.TALLY_LIMBS), np.int32)
+    thresh[:, -1] = ek.POWER_MASK  # padded group slots: unreachable
     for gid, g in enumerate(groups):
         thresh[gid] = ek.threshold_limbs(max(g.threshold - 1, 0))[0]
 

@@ -496,6 +496,23 @@ class FlushLedger:
             },
         }
 DEFAULT_RESULT_TIMEOUT = 30.0
+# Waiters that gave up on a verdict (process-wide, monotone). A caller
+# whose wait times out verifies on the host and carries on, which is
+# right for liveness and invisible in the flush ledger: the flush it
+# waited for still lands as `fused`. /dump_flushes reports this count
+# so a plane that serves slower than its callers wait can be seen.
+_result_timeouts = 0
+_RESULT_TIMEOUTS_LOCK = threading.Lock()
+
+
+def result_timeouts() -> int:
+    return _result_timeouts
+
+
+def _note_result_timeout() -> None:
+    global _result_timeouts
+    with _RESULT_TIMEOUTS_LOCK:
+        _result_timeouts += 1
 # stop()-time leftover drain budget: rows host-verified synchronously
 # before remaining futures fail fast (a few seconds worst-case on the
 # pure-Python path, not minutes)
@@ -558,6 +575,7 @@ class VerifyFuture:
     def result(self, timeout: Optional[float] = None) -> Tuple[bool, ...]:
         if not self._ev.wait(DEFAULT_RESULT_TIMEOUT
                              if timeout is None else timeout):
+            _note_result_timeout()
             raise PlaneError("verify plane result timed out")
         if self._err is not None:
             if isinstance(self._err, PlaneError):
@@ -964,6 +982,48 @@ class VerifyPlane:
 
     def is_running(self) -> bool:
         return self._running
+
+    def prime(self, vals, chain_id: str,
+              timeout: float = 900.0) -> Optional[float]:
+        """Compile before serving: send one throwaway vote-shaped row
+        for validator set `vals` through the running dispatcher, so
+        that the set's window table is built and the single-stride
+        fused flush (stamping prologue + cached kernel) is traced,
+        lowered and compiled before the first real vote waits on it.
+        Cold, that takes longer than DEFAULT_RESULT_TIMEOUT, and a
+        waiter that times out verifies on the host without a word.
+        The row carries a zero signature, tallies nothing, and shows
+        in the flush ledger as what it is: the flush that paid the
+        compile. Returns the seconds it took, or None when this plane
+        verifies on the host (nothing to compile)."""
+        if not self._use_device or self._kernels is not None:
+            return None
+        from cometbft_tpu.types.block_id import BlockID, PartSetHeader
+        from cometbft_tpu.types.canonical import PREVOTE_TYPE
+        from cometbft_tpu.types.timestamp import Timestamp
+        from cometbft_tpu.types.vote import Vote, sign_bytes_template
+
+        val = vals.validators[0]
+        bid = BlockID(b"\x00" * 32, PartSetHeader(1, b"\x00" * 32))
+        vote = Vote(vote_type=PREVOTE_TYPE, height=1, round=0,
+                    block_id=bid, timestamp=Timestamp(1, 1),
+                    validator_address=val.address, validator_index=0)
+        tmpl = sign_bytes_template(chain_id, PREVOTE_TYPE, 1, 0, bid)
+        group = QuorumGroup(
+            2**62, name="prime",
+            valset_pubs=tuple(v.pub_key.data for v in vals.validators),
+            valset_powers=tuple(v.voting_power
+                                for v in vals.validators))
+        t0 = time.monotonic()
+        try:
+            self.submit_many(
+                [(val.pub_key, vote.sign_bytes(chain_id), bytes(64))],
+                group=group, vidx=(0,), chain_id=chain_id,
+                stamp=[(tmpl, 1, 1)]).result(timeout)
+        except PlaneError:
+            _log.exception("verify plane start-up compile did not "
+                           "finish; first flushes will pay it")
+        return time.monotonic() - t0
 
     def in_dispatcher(self) -> bool:
         """True on the dispatcher thread (recursion guard: the
@@ -1666,6 +1726,8 @@ class VerifyPlane:
             try:
                 self._mesh = fz.plane_mesh(self._mesh_devices)
             except Exception:  # noqa: BLE001 - no backend: stay single
+                _log.exception("verify plane mesh did not resolve; "
+                               "flushes stay on one device")
                 self._mesh = None
             self.mesh_ndev = (0 if self._mesh is None
                               else int(self._mesh.devices.size))
@@ -2040,6 +2102,7 @@ class VerifyPlane:
             "running": self._running,
             "summary": self.ledger.summary(),
             "flushes": self.ledger.records(),
+            "result_timeouts": result_timeouts(),
         }
 
 
@@ -2098,7 +2161,7 @@ def dump_flushes() -> dict:
     p = _GLOBAL or _LAST
     if p is None:
         return {"running": False, "summary": {"flushes": 0},
-                "flushes": []}
+                "flushes": [], "result_timeouts": result_timeouts()}
     return p.dump_flushes()
 
 

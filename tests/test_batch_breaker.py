@@ -65,7 +65,7 @@ def test_device_fault_trips_breaker_host_path_correct():
     # 2nd faulted batch: breaker trips
     got = cbatch.verify_batch(pubs, msgs, sigs, kernels=KERNELS, breaker=brk)
     np.testing.assert_array_equal(got, np.asarray(exp))
-    assert brk.state == "open" and brk.trips == 1
+    assert brk.state == "open" and brk.trips == 1 and brk.faults == 2
 
     # while open (cooldown not lapsed) the device is NOT dispatched:
     # the armed failpoint would raise, so correct results prove the
@@ -121,6 +121,12 @@ def test_flake_action_degrades_not_halts():
     for _ in range(4):
         got = cbatch.verify_batch(pubs, msgs, sigs, kernels=KERNELS, breaker=brk)
         np.testing.assert_array_equal(got, np.asarray(exp))
+    # single faults between successes never trip the breaker; `faults`
+    # is the only trace they leave, and reset() does not erase it
+    assert brk.state == "closed" and brk.trips == 0
+    assert brk.faults == 2
+    brk.reset()
+    assert brk.faults == 2
 
 
 def test_device_batch_fn_covered_by_breaker():

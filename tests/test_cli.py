@@ -66,6 +66,29 @@ def test_init_start_rpc(tmp_path):
         node.stop()
 
 
+def test_start_verifier_override_keeps_off_the_device(tmp_path, capsys):
+    """One process per chip: `start --verifier cpu` overrides a config
+    that says tpu, says on its start line which device it verifies on
+    (none), and sets no compile cache because it compiles nothing."""
+    home = str(tmp_path / "node")
+    assert cli.main(["init", "--home", home, "--chain-id", "cpu-chain"]) == 0
+    path = os.path.join(home, "config", "config.toml")
+    cfg = load_config(path)
+    assert cfg.crypto.verifier == "tpu"  # the default init writes
+    cfg.consensus.timeout_propose = 0.4
+    cfg.consensus.timeout_commit = 0.01
+    cfg.p2p.laddr = "tcp://127.0.0.1:0"
+    cfg.rpc.laddr = "tcp://127.0.0.1:0"
+    cfg.base.blocksync = False
+    save_config(cfg, path)
+    assert cli.main(["start", "--home", home, "--run-for", "1.5",
+                     "--verifier", "cpu"]) == 0
+    out = capsys.readouterr().out
+    assert "verifier cpu: platform=" in out
+    assert "compile cache:" not in out
+    assert "rpc listening on" in out
+
+
 def test_testnet_generation(tmp_path):
     out = str(tmp_path / "net")
     assert cli.main(["testnet", "--v", "3", "--output", out,

@@ -5,14 +5,14 @@ the pure-Python ZIP-215 oracle — the per-validator window tables and
 the in-kernel entry select are a pure re-layout of h*(-A), so any
 divergence is a consensus fork.
 
-RUNS ON THE REAL TPU ONLY (CBT_TEST_ON_TPU=1): the round-5 kernel
-keeps its valset table block in VMEM via a BlockSpec index_map, and
-the Pallas INTERPRET path for that shape compiles for multiple HOURS
-on this 1-core CPU host (measured; Mosaic compiles the same kernel in
-~90 s). CPU coverage of the surrounding bookkeeping lives in
-test_ed25519_cached_host.py; the kernel itself is exercised on TPU by
-these tests, by `python tools/tpu_differential.py`, and by every
-bench.py run (which asserts correctness before timing).
+RUNS ON THE CHIP ONLY (CBT_TEST_ON_TPU=1, through the chip tool): the
+kernel keeps its valset table block in VMEM via a BlockSpec index_map,
+and the Pallas INTERPRET path for that shape compiles for hours on a
+CPU, where Mosaic takes 4-6 s on a v5e (PR 21 chip runs). CPU coverage
+of the surrounding bookkeeping lives in test_ed25519_cached_host.py; the
+kernel itself is exercised on the chip by these tests, by
+`python tools/tpu_differential.py`, and by every `chip_smoke.py` run,
+which sends the same edge vectors through it.
 """
 import os
 
@@ -26,8 +26,8 @@ from cometbft_tpu.ops import ed25519_kernel as k
 pytestmark = pytest.mark.skipif(
     not os.environ.get("CBT_TEST_ON_TPU"),
     reason="pallas-interpret compile of the in-kernel-gather kernel "
-           "takes hours on CPU; set CBT_TEST_ON_TPU=1 (Mosaic ~90s). "
-           "TPU coverage: tools/tpu_differential.py + bench.py asserts.",
+           "takes hours on CPU; set CBT_TEST_ON_TPU=1 on the chip. "
+           "Chip coverage: chip_smoke.py + tools/tpu_differential.py.",
 )
 
 

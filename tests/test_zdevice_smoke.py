@@ -71,6 +71,28 @@ def test_compile_record_shape_and_attribution(fresh_ledger):
     deviceledger.record_compile(0.001)
     assert led.records()[-1]["site"] == "outer2"
     deviceledger.attr_end(o2)
+    # the listener pair: JAX fires the duration event for a persistent-
+    # cache hit too (after its cache_hits event, same thread). That is
+    # one hit and no backend compile, and it names the function.
+    before = led.counters()
+    deviceledger._on_event(
+        "/jax/compilation_cache/compile_requests_use_cache")
+    deviceledger._on_event("/jax/compilation_cache/cache_hits")
+    deviceledger._on_duration(
+        "/jax/core/compile/backend_compile_duration", 0.2,
+        fun_name="jit(cached_fn)")
+    deviceledger._on_event(
+        "/jax/compilation_cache/compile_requests_use_cache")
+    deviceledger._on_duration(
+        "/jax/core/compile/backend_compile_duration", 3.0,
+        fun_name="jit(cold_fn)")
+    after = led.counters()
+    assert after["pcache_hits"] - before["pcache_hits"] == 1
+    assert after["compiles"] - before["compiles"] == 1
+    hit, cold = led.records()[-2:]
+    assert hit["pcache_hit"] == 1 and hit["fun"] == "jit(cached_fn)"
+    assert cold["pcache_hit"] == 0 and cold["fun"] == "jit(cold_fn)"
+    assert cold["dur_ms"] == 3000.0
     # bounded ring
     small = deviceledger.CompileLedger(capacity=16)
     for i in range(50):
@@ -232,22 +254,39 @@ def _mini_net(n_nodes=2):
     return nodes
 
 
-def test_dump_devices_over_real_rpc(fresh_ledger):
+def test_dump_devices_over_real_rpc(fresh_ledger, monkeypatch,
+                                    capsys):
     """GET /dump_devices and the JSON-RPC form over a live server (the
     curl surface), /metrics device families sampled from the jax-free
     core, and post-stop history (the ledger is process-global — the
     _LAST property for free)."""
     with deviceledger.attr_context("rpc.test", 3):
         deviceledger.record_compile(0.025)
+    # the device this process verifies on, as the seam that asked JAX
+    # recorded it (crypto.batch; not asked here: this file is jax-free)
+    monkeypatch.setattr(deviceledger, "_DEVICE", {
+        "platform": "tpu", "device_kind": "TPU v5 lite", "n_devices": 1})
     nodes = _mini_net(2)
+    # a plane-enabled node says at start which device it verifies on
+    from cometbft_tpu.verifyplane import VerifyPlane
+
+    nodes[0].verify_plane = VerifyPlane(use_device=False)
     try:
         for n in nodes:
             n.start()
+        assert ("verify plane: platform=tpu device_kind='TPU v5 lite' "
+                "n_devices=1; host path, nothing to compile"
+                ) in capsys.readouterr().out
         url = nodes[0].rpc_listen("127.0.0.1", 0)
         assert nodes[0].consensus.wait_for_height(1, timeout=30.0)
         with urllib.request.urlopen(url + "/dump_devices",
                                     timeout=10) as r:
             doc = json.loads(r.read().decode())
+        assert doc["device"] == {"platform": "tpu",
+                                 "device_kind": "TPU v5 lite",
+                                 "n_devices": 1}
+        assert set(doc["breaker"]) == {"state", "faults", "trips",
+                                       "closes", "probes"}
         assert doc["summary"]["compiles"] == 1
         assert doc["compiles"][0]["site"] == "rpc.test"
         assert doc["compiles"][0]["flush_seq"] == 3
