@@ -143,7 +143,15 @@ class CatchupLedger:
     Every fused verify+apply segment appends one record; counters are
     cumulative for the engine run(s) feeding this ledger. All stamps
     ride the ledger clock (tracing.monotonic_ns) — byte-identical
-    under simnet replay."""
+    under simnet replay.
+
+    The ``*_ms`` columns are the readings of the step's stages
+    (tracing.stage): ``read_ms`` of ``catchup.refill``, ``verify_ms`` of
+    ``catchup.verify``, ``apply_ms`` of ``catchup.apply``. ``apply_ms``
+    INCLUDES ``warm_ms``, the sum of the step's ``catchup.warm_ahead``
+    stages (one per applied block: the next valset's hash compared
+    against the current one's, and the warmer's request when they
+    differ)."""
 
     def __init__(self, capacity: int = LEDGER_CAPACITY):
         self._ring: deque = deque(maxlen=max(8, int(capacity)))
@@ -157,7 +165,8 @@ class CatchupLedger:
 
     def record(self, first: int, last: int, blocks: int, sigs: int,
                skipped: int, read_ms: float, verify_ms: float,
-               apply_ms: float, boundary: bool, warmed: bool) -> dict:
+               apply_ms: float, boundary: bool, warmed: bool,
+               warm_ms: float = 0.0) -> dict:
         rec = {
             "seq": 0,  # patched under the lock
             "at_ms": round(tracing.monotonic_ns() / 1e6, 3),
@@ -166,6 +175,7 @@ class CatchupLedger:
             "read_ms": round(read_ms, 3),
             "verify_ms": round(verify_ms, 3),
             "apply_ms": round(apply_ms, 3),
+            "warm_ms": round(warm_ms, 3),
             "boundary": bool(boundary), "warmed": bool(warmed),
         }
         with self._lock:
@@ -227,6 +237,8 @@ class CatchupLedger:
                 sum(r["apply_ms"] for r in recs), 3)
             out["read_ms_total"] = round(
                 sum(r["read_ms"] for r in recs), 3)
+            out["warm_ms_total"] = round(
+                sum(r["warm_ms"] for r in recs), 3)
         return out
 
 
@@ -335,22 +347,29 @@ class CatchupEngine:
             self.cursor.save()
         return self.state
 
-    def _refill(self, tip: int) -> float:
+    def _refill(self, tip: int) -> None:
         # drop anything the cursor already passed (a resumed engine's
         # buffer starts empty, but a retried run may hold stale heads)
         h = self.state.last_block_height
         while self._buf and self._buf[0][0] <= h:
             self._buf.popleft()
-        t0 = tracing.monotonic_ns()
         while len(self._buf) < self.read_ahead and self._next_read <= tip:
             fp.fail_point("catchup.read_ahead")
             blk, commit = self.source.load(self._next_read)
             self._buf.append((self._next_read, blk, commit))
             self._next_read += 1
-        return (tracing.monotonic_ns() - t0) / 1e6
 
     def _step(self, tip: int) -> None:
-        read_ms = self._refill(tip)
+        # every region of the step is one always-on stage
+        # (tracing.stage): the ledger's *_ms columns are those stages'
+        # own readings, and what no stage covers is the little between
+        # them (ledger.record, the cursor's fields)
+        with tracing.stage("catchup.step"):
+            self._step_staged(tip)
+
+    def _step_staged(self, tip: int) -> None:
+        with tracing.stage("catchup.refill") as st_read:
+            self._refill(tip)
         if not self._buf:
             raise CatchupError(
                 f"history exhausted at {self.state.last_block_height} "
@@ -358,17 +377,18 @@ class CatchupEngine:
             )
         # pre-scan: one fused segment = consecutive buffered blocks
         # under the CURRENT valset, bounded at the first hash change
-        vals = self.state.validators
-        vhash = vals.hash()
-        seg: List[tuple] = []
-        boundary = False
-        for (h, blk, commit) in self._buf:
-            if blk.header.validators_hash != vhash:
-                boundary = True
-                break
-            seg.append((h, blk, commit))
-            if len(seg) >= self.max_run:
-                break
+        with tracing.stage("catchup.scan"):
+            vals = self.state.validators
+            vhash = vals.hash()
+            seg: List[tuple] = []
+            boundary = False
+            for (h, blk, commit) in self._buf:
+                if blk.header.validators_hash != vhash:
+                    boundary = True
+                    break
+                seg.append((h, blk, commit))
+                if len(seg) >= self.max_run:
+                    break
         if not seg:
             h0, blk0, _ = self._buf[0]
             raise CatchupError(
@@ -378,51 +398,54 @@ class CatchupEngine:
             )
         # verify: one cross-height fused flush, skipping heights the
         # persisted cursor already verified (resume re-verifies ZERO)
-        jobs = [CatchupJob(vals=vals, block_id=blk.block_id(),
-                           height=h, commit=commit,
-                           chain_id=self.state.chain_id)
-                for (h, blk, commit) in seg
-                if h > self.cursor.verified]
+        with tracing.stage("catchup.jobs"):
+            jobs = [CatchupJob(vals=vals, block_id=blk.block_id(),
+                               height=h, commit=commit,
+                               chain_id=self.state.chain_id)
+                    for (h, blk, commit) in seg
+                    if h > self.cursor.verified]
         skipped = len(seg) - len(jobs)
         sigs = 0
-        t0 = tracing.monotonic_ns()
-        if jobs:
-            with tracing.span("catchup.verify", cat="catchup",
-                              blocks=len(jobs),
-                              from_height=jobs[0].height):
+        with tracing.stage("catchup.verify", blocks=len(jobs),
+                           from_height=seg[0][0]) as st_verify:
+            if jobs:
                 errs = self.verifier.verify(jobs)
-            for job, err in zip(jobs, errs):
-                if err is not None:
-                    raise CatchupError(
-                        f"commit verification failed at height "
-                        f"{job.height}: {err}"
-                    )
-            sigs = sum(
-                sum(1 for s in job.commit.signatures
-                    if getattr(s, "signature", None))
-                for job in jobs)
-            self.cursor.verified = max(self.cursor.verified, seg[-1][0])
-        verify_ms = (tracing.monotonic_ns() - t0) / 1e6
+                for job, err in zip(jobs, errs):
+                    if err is not None:
+                        raise CatchupError(
+                            f"commit verification failed at height "
+                            f"{job.height}: {err}"
+                        )
+                sigs = sum(
+                    sum(1 for s in job.commit.signatures
+                        if getattr(s, "signature", None))
+                    for job in jobs)
+                self.cursor.verified = max(self.cursor.verified,
+                                           seg[-1][0])
         # apply in order; warm-ahead fires the moment the next epoch's
         # valset becomes known (state.next_validators changes), which
         # is one height BEFORE the boundary the pre-scan found
         warmed = False
-        t0 = tracing.monotonic_ns()
-        for (h, blk, commit) in seg:
-            if self.block_store is not None:
-                self.block_store.save_block(blk, commit)
-            self.state = self.apply_fn(self.state, blk, commit)
-            if self.warm_ahead and self._maybe_warm_ahead():
-                warmed = True
-            self._buf.popleft()
-        apply_ms = (tracing.monotonic_ns() - t0) / 1e6
+        warm_ms = 0.0
+        with tracing.stage("catchup.apply", blocks=len(seg)) as st_apply:
+            for (h, blk, commit) in seg:
+                if self.block_store is not None:
+                    self.block_store.save_block(blk, commit)
+                self.state = self.apply_fn(self.state, blk, commit)
+                if self.warm_ahead:
+                    with tracing.stage("catchup.warm_ahead") as st_warm:
+                        if self._maybe_warm_ahead():
+                            warmed = True
+                    warm_ms += st_warm.ms
+                self._buf.popleft()
         self.cursor.applied = self.state.last_block_height
-        self.cursor.save()
+        with tracing.stage("catchup.cursor"):
+            self.cursor.save()
         self.ledger.record(
             first=seg[0][0], last=seg[-1][0], blocks=len(seg),
-            sigs=sigs, skipped=skipped, read_ms=read_ms,
-            verify_ms=verify_ms, apply_ms=apply_ms,
-            boundary=boundary, warmed=warmed,
+            sigs=sigs, skipped=skipped, read_ms=st_read.ms,
+            verify_ms=st_verify.ms, apply_ms=st_apply.ms,
+            warm_ms=warm_ms, boundary=boundary, warmed=warmed,
         )
         incidents.note_catchup(True)  # progress: re-arm the stall watch
 

@@ -21,6 +21,7 @@ from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from cometbft_tpu.libs import tracing
 from cometbft_tpu.ops import ed25519_kernel as ek
 from cometbft_tpu.types.commit import Commit
 from cometbft_tpu.types.validation import (
@@ -256,7 +257,8 @@ class StreamVerifier:
                        np.int32)
         rows = ec.pack_rows_cached(pb, counted, commit_ids, thresh,
                                    out=out)
-        pending = ec.verify_tally_rows_cached(rows, table, cap)
+        with tracing.stage("stream.dispatch", path="host_packed"):
+            pending = ec.verify_tally_rows_cached(rows, table, cap)
         self.chunks["host_packed"] += 1
         return _Chunk(list(jobs), np.asarray(row_job),
                       np.asarray(row_idx), pending, row_pos=pos)
@@ -311,8 +313,9 @@ class StreamVerifier:
         # row is countable (the for_block filter already ran); dead
         # lanes keep the pool's zero fill (live=0 -> zero row)
         dfl[pos] = (3 | (rj << 2) | (rj << 10)).astype(np.int32)
-        return ec.verify_tally_delta_cached(dsig, dts, dfl, ent, table,
-                                            cap, thresh)
+        with tracing.stage("stream.dispatch", path="stamped"):
+            return ec.verify_tally_delta_cached(dsig, dts, dfl, ent,
+                                                table, cap, thresh)
 
     def _pack_chunk(self, jobs) -> Optional[_Chunk]:
         """jobs: [(global_idx, CommitJob)] for this chunk."""
@@ -407,8 +410,9 @@ class StreamVerifier:
                 job.vals.total_voting_power() * 2 // 3
             )[0]
 
-        pending = self._dispatch(pb, power5, counted, commit_ids, thresh,
-                                 c_pad)
+        with tracing.stage("stream.dispatch", path="dense"):
+            pending = self._dispatch(pb, power5, counted, commit_ids,
+                                     thresh, c_pad)
         self.chunks["dense"] += 1
         return _Chunk(jobs, np.asarray(row_job), np.asarray(row_idx),
                       pending)
@@ -445,6 +449,53 @@ class StreamVerifier:
         self, jobs: Sequence[CommitJob]
     ) -> List[Optional[VerificationError]]:
         results: List[Optional[VerificationError]] = [None] * len(jobs)
+        with tracing.stage("stream.prechecks", jobs=len(jobs)):
+            indexed = self._prechecked(jobs, results)
+            total_rows = sum(
+                len(j.commit.signatures) for _, j in indexed
+            )
+        if total_rows < self.min_device_sigs:
+            from cometbft_tpu.types import validation as tv
+
+            for gi, job in indexed:
+                try:
+                    tv.verify_commit_light(
+                        job.chain_id, job.vals, job.block_id, job.height,
+                        job.commit, batch_fn=None,
+                    )
+                except VerificationError as e:
+                    results[gi] = e
+            return results
+
+        in_flight: List[_Chunk] = []
+        for chunk_pairs in self._split_for_tables(indexed):
+            # host pack or delta staging, template entry and (nested,
+            # stream.dispatch) the device call
+            with tracing.stage("stream.pack", jobs=len(chunk_pairs),
+                               rows=sum(len(j.commit.signatures)
+                                        for _, j in chunk_pairs)):
+                chunk = self._pack_any(chunk_pairs)
+            if chunk is None:
+                # zero packable rows (e.g. every signature ABSENT): fail
+                # CLOSED — these commits tallied no power at all
+                for gi, job in chunk_pairs:
+                    results[gi] = NotEnoughPowerError(
+                        0, job.vals.total_voting_power() * 2 // 3
+                    )
+            else:
+                in_flight.append(chunk)
+            # keep at most 2 chunks in flight: fetch the oldest while the
+            # newest computes (double buffering)
+            if len(in_flight) > 2:
+                self._collect(in_flight.pop(0), results)
+        for chunk in in_flight:
+            self._collect(chunk, results)
+        return results
+
+    def _prechecked(self, jobs, results):
+        """The structural prechecks and the routing of what the fused
+        pass cannot take (their verdicts go into `results`); returns
+        [(index, job)] of what it can."""
         done = set()
         # structural prechecks stay host-side (cheap, no device round trip)
         for i, job in enumerate(jobs):
@@ -473,43 +524,7 @@ class StreamVerifier:
                 except VerificationError as e:
                     results[i] = e
                 done.add(i)
-
-        indexed = [(i, j) for i, j in enumerate(jobs) if i not in done]
-        total_rows = sum(
-            len(j.commit.signatures) for _, j in indexed
-        )
-        if total_rows < self.min_device_sigs:
-            from cometbft_tpu.types import validation as tv
-
-            for gi, job in indexed:
-                try:
-                    tv.verify_commit_light(
-                        job.chain_id, job.vals, job.block_id, job.height,
-                        job.commit, batch_fn=None,
-                    )
-                except VerificationError as e:
-                    results[gi] = e
-            return results
-
-        in_flight: List[_Chunk] = []
-        for chunk_pairs in self._split_for_tables(indexed):
-            chunk = self._pack_any(chunk_pairs)
-            if chunk is None:
-                # zero packable rows (e.g. every signature ABSENT): fail
-                # CLOSED — these commits tallied no power at all
-                for gi, job in chunk_pairs:
-                    results[gi] = NotEnoughPowerError(
-                        0, job.vals.total_voting_power() * 2 // 3
-                    )
-            else:
-                in_flight.append(chunk)
-            # keep at most 2 chunks in flight: fetch the oldest while the
-            # newest computes (double buffering)
-            if len(in_flight) > 2:
-                self._collect(in_flight.pop(0), results)
-        for chunk in in_flight:
-            self._collect(chunk, results)
-        return results
+        return [(i, j) for i, j in enumerate(jobs) if i not in done]
 
     def _split_for_tables(self, indexed):
         """Chunk, then sub-split cached-table chunks to the static
@@ -533,21 +548,23 @@ class StreamVerifier:
         return self._pack_chunk(jobs)
 
     def _collect(self, chunk: _Chunk, results) -> None:
-        valid, tally, quorum = chunk.pending
-        valid = np.asarray(valid)
-        quorum = np.asarray(quorum)
-        for j, (gi, job) in enumerate(chunk.jobs):
-            rows = chunk.row_job == j
-            if chunk.row_pos is not None:
-                row_valid = valid[chunk.row_pos[rows]]
-            else:
-                row_valid = valid[: len(chunk.row_job)][rows]
-            if not row_valid.all():
-                bad = chunk.row_idx[rows][~row_valid][0]
-                results[gi] = InvalidSignatureError(int(bad))
-            elif not bool(quorum[j]):
-                needed = job.vals.total_voting_power() * 2 // 3
-                results[gi] = NotEnoughPowerError(-1, needed)
+        # waits for the device, then blames
+        with tracing.stage("stream.collect", jobs=len(chunk.jobs)):
+            valid, tally, quorum = chunk.pending
+            valid = np.asarray(valid)
+            quorum = np.asarray(quorum)
+            for j, (gi, job) in enumerate(chunk.jobs):
+                rows = chunk.row_job == j
+                if chunk.row_pos is not None:
+                    row_valid = valid[chunk.row_pos[rows]]
+                else:
+                    row_valid = valid[: len(chunk.row_job)][rows]
+                if not row_valid.all():
+                    bad = chunk.row_idx[rows][~row_valid][0]
+                    results[gi] = InvalidSignatureError(int(bad))
+                elif not bool(quorum[j]):
+                    needed = job.vals.total_voting_power() * 2 // 3
+                    results[gi] = NotEnoughPowerError(-1, needed)
 
 
 def make_stream_verifier(use_pallas: Optional[bool] = None,

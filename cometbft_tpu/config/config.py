@@ -384,22 +384,19 @@ class TracingConfig:
     and near-free while off. `enable = true` installs the global
     tracer (ring of `buffer` events, served by GET /dump_traces and
     the dump_traces RPC as perfetto-loadable Chrome trace JSON).
-    `profile_dir` additionally arms the jax.profiler bracket around
-    verify-plane device flights — device traces land in that directory
-    aligned with the host spans (expensive; profiling runs only)."""
+    The stages of the served paths (`tracing.stage`) need no knob:
+    they are always on, and any jax.profiler capture of the running
+    process carries them on the device plane's clock."""
 
     enable: bool = False
     buffer: int = 16384     # ring capacity, in events
-    profile_dir: str = ""
 
     def apply(self) -> None:
         """Symmetric: applying a config with tracing off DISABLES the
-        global tracer and clears the profile dir — rebuilding a node
-        from an edited config must not leave the previous config's
-        tracer (or jax.profiler arming) running."""
+        global tracer — rebuilding a node from an edited config must
+        not leave the previous config's tracer running."""
         from cometbft_tpu.libs import tracing
 
-        tracing.set_profile_dir(self.profile_dir)
         if self.enable:
             tracing.enable(capacity=self.buffer)
         else:

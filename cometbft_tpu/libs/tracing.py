@@ -15,7 +15,8 @@ Design rules:
   * OFF BY DEFAULT, and near-free while off: every hook is a module
     function that loads one global and returns a shared no-op context
     manager when no tracer is installed. Call sites fire per flush /
-    per step / per fsync — never per signature.
+    per step / per fsync — never per signature. The one exception is
+    :func:`stage`: always on, bounded, a couple of microseconds.
   * Clock is ``time.perf_counter_ns`` by default. The simnet installs
     ``Timestamp.now().to_ns()`` (its virtual clock) via
     :func:`set_clock`, so the same (seed, schedule) produces an
@@ -34,19 +35,25 @@ Event vocabulary (Chrome trace-event phases):
                            for verify-plane flights so pack(k+1)
                            VISIBLY overlaps device-flight(k) in the UI
 
-An opt-in ``jax.profiler`` bracket (:func:`profiler_start` /
-:func:`profiler_stop`, armed by ``[tracing] profile_dir``) wraps
-verify-plane flights so device traces line up with the host spans.
+  stage(name)           -> ALWAYS ON: one record in the bounded stage
+                           ring, one event of any running jax.profiler
+                           capture's host plane (the shared clock with
+                           the device plane), and the same "X" event a
+                           span gives when the tracer is on. For the few
+                           stages of a served operation; see
+                           :func:`stage`.
 """
 from __future__ import annotations
 
 import json
+import sys
 import threading
 import time
 from collections import deque
 from typing import Callable, List, Optional
 
 DEFAULT_CAPACITY = 16384
+STAGE_CAPACITY = 16384
 
 
 class _NullSpan:
@@ -191,20 +198,30 @@ _CLOCK: Optional[Callable[[], int]] = None
 _CLOCK_GEN: int = 0
 
 
+def _clock_changed() -> None:
+    """monotonic_ns() may now resolve to another clock domain: stamps
+    taken before and after do not compare, so the generation moves on
+    and the stage ring (whose records carry such stamps) starts over."""
+    global _CLOCK_GEN, _STAGES_DROPPED
+    _CLOCK_GEN += 1
+    _STAGES.clear()
+    _STAGES_DROPPED = 0
+
+
 def enable(capacity: int = DEFAULT_CAPACITY,
            clock: Optional[Callable[[], int]] = None,
            deterministic: bool = False) -> Tracer:
     """Install (and return) a fresh global tracer."""
-    global _TRACER, _CLOCK_GEN
+    global _TRACER
     _TRACER = Tracer(capacity, clock, deterministic)
-    _CLOCK_GEN += 1
+    _clock_changed()
     return _TRACER
 
 
 def disable() -> None:
-    global _TRACER, _CLOCK_GEN
+    global _TRACER
     _TRACER = None
-    _CLOCK_GEN += 1
+    _clock_changed()
 
 
 def enabled() -> bool:
@@ -218,10 +235,11 @@ def tracer() -> Optional[Tracer]:
 def set_clock(fn: Optional[Callable[[], int]]) -> None:
     """Install a ns clock for the current AND any future tracer. The
     simnet passes ``lambda: Timestamp.now().to_ns()`` so traces run on
-    the virtual clock; None restores perf_counter_ns."""
-    global _CLOCK, _CLOCK_GEN
+    the virtual clock; None restores perf_counter_ns. Clears the stage
+    ring: its stamps are of the clock that was."""
+    global _CLOCK
     _CLOCK = fn
-    _CLOCK_GEN += 1
+    _clock_changed()
     t = _TRACER
     if t is not None:
         t.set_clock(fn)
@@ -315,57 +333,94 @@ def tail(n: int = 40) -> List[str]:
 
 
 # --------------------------------------------------------------------------
-# opt-in jax.profiler bracket ([tracing] profile_dir)
+# stages: the always-on spans of a served operation
 # --------------------------------------------------------------------------
 
-_PROFILE_DIR: str = ""
-_PROFILE_LOCK = threading.Lock()
-_PROFILING = False
+# (name, t0_ns, dur_ns, tid) per closed stage, oldest first by END time
+# (a nested stage lands before the stage around it)
+_STAGES: deque = deque(maxlen=STAGE_CAPACITY)
+_STAGES_DROPPED = 0
+# jax.profiler.TraceAnnotation once jax was found imported. Looked up
+# in sys.modules and never imported here: host-only and simnet
+# processes stay jax-free, and without jax there is no profiler whose
+# clock a stage could share.
+_ANNOTATION = None
 
 
-def set_profile_dir(path: str) -> None:
-    global _PROFILE_DIR
-    _PROFILE_DIR = path or ""
+def _annotation():
+    global _ANNOTATION
+    jax = sys.modules.get("jax")
+    # a jax still half-way through its own import has no .profiler yet
+    prof = getattr(jax, "profiler", None)
+    _ANNOTATION = getattr(prof, "TraceAnnotation", None)
+    return _ANNOTATION
 
 
-def profile_dir() -> str:
-    return _PROFILE_DIR
+class _Stage:
+    __slots__ = ("name", "args", "t0", "ms", "_ann")
 
+    def __init__(self, name: str, args: dict):
+        self.name = name
+        self.args = args
+        self.ms = 0.0
 
-def profiler_start() -> bool:
-    """Start a jax.profiler capture into profile_dir (no-op unless a
-    dir is configured AND tracing is enabled — the capture exists to
-    line device timelines up with host spans, and gating on the tracer
-    keeps `enable = false` genuinely free even with a profile_dir
-    configured). Returns True when THIS call started a capture — the
-    caller that got True must call :func:`profiler_stop` when its
-    bracketed work lands (the jax profiler is process-global and
-    cannot nest, so overlapping flights share one capture)."""
-    global _PROFILING
-    if not _PROFILE_DIR or _TRACER is None:
+    def __enter__(self):
+        cls = _ANNOTATION or _annotation()
+        if cls is not None:
+            self._ann = cls(self.name, **self.args)
+            self._ann.__enter__()
+        else:
+            self._ann = None
+        self.t0 = monotonic_ns()
+        return self
+
+    def __exit__(self, *exc):
+        global _STAGES_DROPPED
+        dur = monotonic_ns() - self.t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self.ms = dur / 1e6
+        if len(_STAGES) == STAGE_CAPACITY:
+            _STAGES_DROPPED += 1
+        _STAGES.append((self.name, self.t0, dur, threading.get_ident()))
+        t = _TRACER
+        if t is not None:
+            t._complete(self.name, "", self.t0, dur, self.args)
         return False
-    with _PROFILE_LOCK:
-        if _PROFILING:
-            return False
-        try:
-            import jax
-
-            jax.profiler.start_trace(_PROFILE_DIR)
-        except Exception:  # noqa: BLE001 - profiling must never fault
-            return False
-        _PROFILING = True
-        return True
 
 
-def profiler_stop() -> None:
-    global _PROFILING
-    with _PROFILE_LOCK:
-        if not _PROFILING:
-            return
-        try:
-            import jax
+def stage(name: str, **args) -> _Stage:
+    """An ALWAYS-ON span around one stage of a served operation (a
+    catch-up step's verify, a commit check's host pack). Unlike
+    :func:`span` it needs no tracer:
 
-            jax.profiler.stop_trace()
-        except Exception:  # noqa: BLE001 - profiling must never fault
-            pass
-        _PROFILING = False
+      * leaving it appends ``(name, t0_ns, dur_ns, tid)`` to one
+        process-global ring of STAGE_CAPACITY records on
+        :func:`monotonic_ns` (read with :func:`stages`; overflow drops
+        the oldest and counts in :func:`stages_dropped`), and the
+        object then holds ``.ms``: a caller that keeps a ledger column
+        reads that instead of timing the region a second time;
+      * while jax is imported it is also a
+        ``jax.profiler.TraceAnnotation(name, **args)``: nothing without
+        a capture, and with one an event of the profile's host plane
+        under this name with its args as stats, on the device plane's
+        timeline. Any capture of a running process shows the program's
+        stages beside the device operations, with no switch to set;
+      * with the tracer on it pushes the "X" event ``span(name,
+        **args)`` would.
+
+    Call sites fire per stage of an operation or per block, never per
+    signature."""
+    return _Stage(name, args)
+
+
+def stages() -> List[tuple]:
+    """The stage ring's records, oldest first (atomic snapshot: see
+    Tracer.events)."""
+    return list(_STAGES)
+
+
+def stages_dropped() -> int:
+    """Records pushed out of the full stage ring since it was last
+    cleared (a change of clock domain clears it)."""
+    return _STAGES_DROPPED

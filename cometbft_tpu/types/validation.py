@@ -20,6 +20,7 @@ from typing import Callable, List, Optional
 
 import numpy as np
 
+from cometbft_tpu.libs import tracing
 from cometbft_tpu.types.commit import (
     BLOCK_ID_FLAG_COMMIT,
     Commit,
@@ -194,16 +195,20 @@ def _verify(
     lookup_by_address: bool,
     batch_fn: Optional[Callable],
 ) -> None:
-    if _should_batch_verify(commit) and batch_fn is not None:
-        _verify_batch(
-            chain_id, vals, commit, voting_power_needed,
-            ignore_sig, count_sig, count_all, lookup_by_address, batch_fn,
-        )
-    else:
-        _verify_single(
-            chain_id, vals, commit, voting_power_needed,
-            ignore_sig, count_sig, count_all, lookup_by_address,
-        )
+    # the body all three VerifyCommit variants share, as one always-on
+    # stage; the batch path's own stages nest in it
+    with tracing.stage("commit.verify", sigs=len(commit.signatures)):
+        if _should_batch_verify(commit) and batch_fn is not None:
+            _verify_batch(
+                chain_id, vals, commit, voting_power_needed,
+                ignore_sig, count_sig, count_all, lookup_by_address,
+                batch_fn,
+            )
+        else:
+            _verify_single(
+                chain_id, vals, commit, voting_power_needed,
+                ignore_sig, count_sig, count_all, lookup_by_address,
+            )
 
 
 def _row(chain_id, vals, commit, idx, cs, lookup_by_address):
@@ -246,36 +251,40 @@ def _verify_batch(
     idxs: List[int] = []
     tallied = 0
     seen = set()
-    for idx, cs in enumerate(commit.signatures):
-        if ignore_sig(cs):
-            continue
-        resolved = _row(chain_id, vals, commit, idx, cs, lookup_by_address)
-        if resolved is None:
-            continue
-        if lookup_by_address:
-            # duplicate check only for resolved validators
-            # (validation.go:188-198: skip-unknown precedes seenVals)
-            if cs.validator_address in seen:
-                raise VerificationError(
-                    f"double vote from {cs.validator_address.hex()}"
-                )
-            seen.add(cs.validator_address)
-        pub_key, power = resolved
-        pubs.append(pub_key)
-        sigs.append(cs.signature)
-        idxs.append(idx)
-        if count_sig(cs):
-            tallied += power
-            if not count_all and tallied > voting_power_needed:
-                break
+    with tracing.stage("commit.collect"):
+        for idx, cs in enumerate(commit.signatures):
+            if ignore_sig(cs):
+                continue
+            resolved = _row(chain_id, vals, commit, idx, cs,
+                            lookup_by_address)
+            if resolved is None:
+                continue
+            if lookup_by_address:
+                # duplicate check only for resolved validators
+                # (validation.go:188-198: skip-unknown precedes seenVals)
+                if cs.validator_address in seen:
+                    raise VerificationError(
+                        f"double vote from {cs.validator_address.hex()}"
+                    )
+                seen.add(cs.validator_address)
+            pub_key, power = resolved
+            pubs.append(pub_key)
+            sigs.append(cs.signature)
+            idxs.append(idx)
+            if count_sig(cs):
+                tallied += power
+                if not count_all and tallied > voting_power_needed:
+                    break
 
     if tallied <= voting_power_needed:
         raise NotEnoughPowerError(tallied, voting_power_needed)
 
     # sign-bytes built AFTER collection: one vectorized template patch
     # over the collected rows (template packing), or the legacy loop
-    msgs = _commit_msgs(chain_id, commit, idxs)
-    valid = np.asarray(batch_fn(pubs, msgs, sigs))[: len(pubs)]
+    with tracing.stage("commit.sign_bytes", rows=len(idxs)):
+        msgs = _commit_msgs(chain_id, commit, idxs)
+    with tracing.stage("commit.batch_fn", rows=len(pubs)):
+        valid = np.asarray(batch_fn(pubs, msgs, sigs))[: len(pubs)]
     if not valid.all():
         bad = int(np.flatnonzero(~valid)[0])
         raise InvalidSignatureError(idxs[bad])
@@ -352,21 +361,30 @@ def device_batch_fn(use_pallas: Optional[bool] = None,
             # would thrash the LRU, so the default stays general.
             from cometbft_tpu.ops import ed25519_cached as ec
 
-            return ec.verify_batch_cached(pub_bytes, msgs, sigs)
+            # packs, runs and fetches inside the one call
+            with tracing.stage("ed25519.dispatch", rows=n):
+                return ec.verify_batch_cached(pub_bytes, msgs, sigs)
         if use_pallas:
             from cometbft_tpu.ops import ed25519_pallas as kp
 
             pad = kp.pad_to_tile(n)
-            pb = ek.pack_batch(pub_bytes, msgs, sigs, pad_to=pad)
-            valid = np.asarray(kp.verify_rows(kp.pack_rows(pb)))
+            with tracing.stage("ed25519.pack", rows=n, padded=pad):
+                rows = kp.pack_rows(
+                    ek.pack_batch(pub_bytes, msgs, sigs, pad_to=pad))
+            with tracing.stage("ed25519.dispatch", rows=n):
+                out = kp.verify_rows(rows)
         else:
-            pb = ek.pack_batch(pub_bytes, msgs, sigs)
-            valid = np.asarray(
-                ek.verify_kernel(
+            pad = ek.bucket_size(max(n, 1))
+            with tracing.stage("ed25519.pack", rows=n, padded=pad):
+                pb = ek.pack_batch(pub_bytes, msgs, sigs, pad_to=pad)
+            with tracing.stage("ed25519.dispatch", rows=n):
+                out = ek.verify_kernel(
                     pb.ay, pb.asign, pb.ry, pb.rsign, pb.sdig, pb.hdig,
                     pb.precheck,
                 )
-            )
+        # the device's time and the copy back, as the host waits it out
+        with tracing.stage("ed25519.fetch"):
+            valid = np.asarray(out)
         return valid[:n]
 
     def fn(pubs, msgs, sigs):

@@ -1809,11 +1809,6 @@ class VerifyPlane:
             # — comp_ms in the ledger, site/flush_seq in /dump_devices
             attr = deviceledger.attr_begin("plane.flush", led[_L_SEQ])
             try:
-                # [tracing] profile_dir: bracket the device flight with
-                # a jax.profiler capture so device traces line up with
-                # the host spans (no-op unless configured)
-                prof = tracing.profiler_stop if tracing.profiler_start() \
-                    else None
                 t_d0 = tracing.monotonic_ns()
                 fz.dispatch_fused(plan)
                 t_d1 = tracing.monotonic_ns()
@@ -1873,9 +1868,6 @@ class VerifyPlane:
                         led[_L_NDEV] = 1
                         led[_L_DEV0] = 0
                         return _host_verdicts(rows), None
-                    finally:
-                        if prof is not None:
-                            prof()
                     self._breaker.record_success()
                     # device observatory steady declaration: after two
                     # successful fused collects the flush shapes are
@@ -1903,8 +1895,6 @@ class VerifyPlane:
                 # compiles a FAILED dispatch paid still belong to this
                 # flush (the grouped/host fallback below records it)
                 led[_L_COMP] = round(attr.ms, 3)
-                if prof is not None:
-                    prof()  # un-bracket a failed dispatch
                 self._breaker.record_failure()
                 _log.exception(
                     "fused verify-plane dispatch failed; falling back "

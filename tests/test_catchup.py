@@ -295,6 +295,88 @@ def test_store_history_source_contract():
         src.load(1)
 
 
+STEP_STAGES = ["catchup.refill", "catchup.scan", "catchup.jobs",
+               "catchup.verify", "catchup.apply", "catchup.cursor",
+               "catchup.step"]  # in the order they close
+
+
+def test_step_stages_cover_the_step_and_feed_the_ledger(history,
+                                                        tmp_path):
+    """Every step closes the seven step-level stages once, in order,
+    with one catchup.warm_ahead per applied block inside
+    catchup.apply; the children fit inside the step; and the ledger's
+    *_ms columns are those stages' own readings, not second timers."""
+    items, vals_at = history
+    tracing.set_clock(None)  # an empty stage ring
+    eng = _engine(items, vals_at,
+                  cursor_path=str(tmp_path / "cursor.json"))
+    eng.run()
+    recs = [r for r in tracing.stages() if r[0].startswith("catchup.")]
+    ledger = eng.ledger.records()
+    steps, cur = [], []
+    for r in recs:
+        cur.append(r)
+        if r[0] == "catchup.step":
+            steps.append(cur)
+            cur = []
+    assert not cur and len(steps) == len(ledger) >= 4
+    for step, led in zip(steps, ledger):
+        by_name = {r[0]: r for r in step}
+        assert [r[0] for r in step
+                if r[0] != "catchup.warm_ahead"] == STEP_STAGES
+        warm = [r for r in step if r[0] == "catchup.warm_ahead"]
+        assert len(warm) == led["blocks"]
+        _, a0, adur, _ = by_name["catchup.apply"]
+        assert all(a0 <= w0 and w0 + wdur <= a0 + adur
+                   for _, w0, wdur, _ in warm)
+        _, s0, sdur, _ = by_name["catchup.step"]
+        children = [by_name[n] for n in STEP_STAGES[:-1]]
+        assert all(s0 <= c0 and c0 + cdur <= s0 + sdur
+                   for _, c0, cdur, _ in children)
+        assert sum(c[2] for c in children) <= sdur
+        for col, name in (("read_ms", "catchup.refill"),
+                          ("verify_ms", "catchup.verify"),
+                          ("apply_ms", "catchup.apply")):
+            assert led[col] == round(by_name[name][2] / 1e6, 3)
+        assert led["warm_ms"] == round(sum(w[2] for w in warm) / 1e6, 3)
+        assert led["warm_ms"] <= led["apply_ms"]
+    assert eng.ledger.summary()["warm_ms_total"] == round(
+        sum(r["warm_ms"] for r in ledger), 3)
+
+
+def test_stream_verifier_stages_once_per_chunk():
+    """StreamVerifier.verify closes stream.prechecks once, and
+    stream.dispatch inside stream.pack, then stream.collect, once per
+    chunk. The device call is stood in for (the verdicts it would
+    return): what is under test is where the stages sit."""
+    import numpy as np
+
+    from cometbft_tpu.blocksync.pipeline import CommitJob, StreamVerifier
+
+    items, vals_at = make_history(n_blocks=4, n_vals=3, epoch_len=8)
+    jobs = [CommitJob(vals_at(h), blk.block_id(), h, commit, CHAIN)
+            for h, (blk, commit) in sorted(items.items())]
+    # 3 signatures a commit, 6 a chunk: two chunks of two commits
+    sv = StreamVerifier(max_sigs=6, use_pallas=False, min_device_sigs=1)
+
+    def device(pb, power5, counted, commit_ids, thresh, n_commits):
+        return (np.ones(pb.padded, np.bool_), None,
+                np.ones(n_commits, np.bool_))
+
+    sv._dispatch = device
+    tracing.set_clock(None)
+    assert sv.verify(jobs) == [None] * 4
+    assert sv.chunks == {"stamped": 0, "host_packed": 0, "dense": 2}
+    recs = [r for r in tracing.stages() if r[0].startswith("stream.")]
+    assert [r[0] for r in recs] == [
+        "stream.prechecks",
+        "stream.dispatch", "stream.pack",
+        "stream.dispatch", "stream.pack",
+        "stream.collect", "stream.collect"]
+    for (_, d0, ddur, _), (_, p0, pdur, _) in (recs[1:3], recs[3:5]):
+        assert p0 <= d0 and d0 + ddur <= p0 + pdur
+
+
 def test_cursor_roundtrip_and_corrupt_file(tmp_path):
     path = str(tmp_path / "cursor.json")
     c = CatchupCursor(path)

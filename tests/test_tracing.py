@@ -4,6 +4,7 @@ simnet trace-determinism acceptance (same seed+schedule => identical
 span names/order/timestamps under the virtual clock).
 """
 import json
+import threading
 
 import pytest
 
@@ -97,10 +98,153 @@ def test_write_and_tail(tmp_path):
     assert tracing.tail(1) == ["beta(X)"]
 
 
-def test_profiler_bracket_noop_without_dir():
-    tracing.set_profile_dir("")
-    assert tracing.profiler_start() is False
-    tracing.profiler_stop()  # must not raise
+# ---------------------------------------------------------------------------
+# stages: always on, bounded, on the profiler's clock
+# ---------------------------------------------------------------------------
+
+
+def test_stage_records_with_tracer_off_and_exposes_ms():
+    assert not tracing.enabled()
+    with tracing.stage("unit.outer", rows=3) as outer:
+        with tracing.stage("unit.inner") as inner:
+            pass
+    recs = tracing.stages()
+    # a nested stage lands before the stage around it
+    assert [r[0] for r in recs] == ["unit.inner", "unit.outer"]
+    (_, i0, idur, itid), (_, o0, odur, otid) = recs
+    assert itid == otid == threading.get_ident()
+    assert o0 <= i0 and i0 + idur <= o0 + odur
+    # .ms is the very reading the record holds
+    assert outer.ms == odur / 1e6 and inner.ms == idur / 1e6
+    assert tracing.stages_dropped() == 0
+    assert tracing.export_chrome()["traceEvents"] == []  # no tracer
+
+
+def test_stage_records_when_its_body_raises():
+    with pytest.raises(KeyError):
+        with tracing.stage("unit.raises") as st:
+            raise KeyError("x")
+    assert [r[0] for r in tracing.stages()] == ["unit.raises"]
+    assert st.ms == tracing.stages()[0][2] / 1e6
+
+
+def test_stage_ring_is_bounded_and_counts_drops():
+    over = 5
+    for i in range(tracing.STAGE_CAPACITY + over):
+        with tracing.stage("unit.fill" if i else "unit.first"):
+            pass
+    recs = tracing.stages()
+    assert len(recs) == tracing.STAGE_CAPACITY
+    assert tracing.stages_dropped() == over
+    assert recs[0][0] == "unit.fill"  # the oldest went first
+    tracing.set_clock(None)
+    assert tracing.stages() == [] and tracing.stages_dropped() == 0
+
+
+def test_stage_exports_the_event_a_span_would():
+    ticks = iter(range(1000, 100000, 1000))
+    tracing.enable(capacity=16, clock=lambda: next(ticks),
+                   deterministic=True)
+    with tracing.span("unit.same", rows=4, path="dense"):
+        pass
+    with tracing.stage("unit.same", rows=4, path="dense"):
+        pass
+    with tracing.stage("unit.bare"):
+        pass
+    a, b, c = tracing.export_chrome()["traceEvents"]
+    assert a["ph"] == "X" and a["args"] == {"rows": 4, "path": "dense"}
+    # same event but for when it happened
+    assert {**a, "ts": b["ts"]} == b
+    assert c["name"] == "unit.bare" and "args" not in c
+    # and the stage is in its own ring as well, on the tracer's clock
+    assert [(r[0], r[1], r[2]) for r in tracing.stages()] == [
+        ("unit.same", 3000, 1000), ("unit.bare", 5000, 1000)]
+
+
+def test_set_clock_clears_stages_and_a_virtual_clock_repeats():
+    def run():
+        ticks = iter(range(0, 10**6, 250))
+        tracing.set_clock(lambda: next(ticks))
+        with tracing.stage("unit.a"):
+            with tracing.stage("unit.b", k=1):
+                pass
+        with tracing.stage("unit.c"):
+            pass
+        return tracing.stages()
+
+    with tracing.stage("unit.wall_clock"):
+        pass
+    first = run()  # set_clock dropped the wall-clock record
+    assert [r[0] for r in first] == ["unit.b", "unit.a", "unit.c"]
+    assert [(r[1], r[2]) for r in first] == [(250, 250), (0, 750),
+                                             (1000, 250)]
+    assert run() == first
+
+
+def test_stage_is_an_event_of_a_profiler_capture(tmp_path):
+    """Under a real jax.profiler capture (CPU) a stage's name and args
+    are in the profile's host plane, on the profile's own clock."""
+    import glob
+    import time
+
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.enable_hlo_proto = False
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with tracing.stage("unit.profiled", rows=7, path="dense") as st:
+            time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    found = [(plane.name, e)
+             for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for e in line.events
+             if e.name == "unit.profiled"]
+    assert len(found) == 1
+    plane_name, ev = found[0]
+    assert plane_name.startswith("/host:")
+    assert {k: v for k, v in ev.stats} == {"rows": 7, "path": "dense"}
+    # the same region on two clocks: the annotation is entered first
+    # and left last, so it is no shorter and barely longer
+    assert st.ms * 1e6 <= ev.duration_ns <= st.ms * 1e6 + 50e6
+    # the session clock, not perf_counter's
+    assert ev.start_ns < tracing.stages()[-1][1]
+
+
+def test_stage_is_cheap():
+    """A stage costs microseconds (about 2 here, with jax imported);
+    the limit is loose enough to hold on a busy host."""
+    import time
+
+    import jax  # noqa: F401 - the dearer case: the annotation is made
+
+    costs = []
+    for _ in range(10_000):
+        t = time.perf_counter_ns()
+        with tracing.stage("unit.cost", rows=1):
+            pass
+        costs.append(time.perf_counter_ns() - t)
+    assert sorted(costs)[len(costs) // 2] < 20_000
+
+
+def test_stages_need_no_jax():
+    """libs/tracing stays importable, and a stage usable, in a process
+    that never imports jax (host-only and simnet runs)."""
+    import subprocess
+    import sys
+
+    code = ("import sys\n"
+            "from cometbft_tpu.libs import tracing\n"
+            "with tracing.stage('unit.nojax', rows=1) as st:\n"
+            "    pass\n"
+            "assert [r[0] for r in tracing.stages()] == ['unit.nojax']\n"
+            "assert st.ms > 0 and 'jax' not in sys.modules\n")
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=60)
 
 
 def test_dump_traces_route():

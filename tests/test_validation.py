@@ -165,6 +165,42 @@ def test_power_precheck_before_verification():
     assert calls == []  # batch_fn never invoked
 
 
+@pytest.mark.parametrize("case,kw,raises,closed", [
+    ("accepted", {}, None,
+     ["commit.collect", "commit.sign_bytes", "commit.batch_fn",
+      "commit.verify"]),
+    ("tampered", {"invalid": (1,)}, validation.InvalidSignatureError,
+     ["commit.collect", "commit.sign_bytes", "commit.batch_fn",
+      "commit.verify"]),
+    ("short_of_power", {"absent": (0, 1, 2)},
+     validation.NotEnoughPowerError,
+     ["commit.collect", "commit.verify"]),
+])
+def test_commit_stages_close_on_every_exit(case, kw, raises, closed):
+    """verify_commit_light's always-on stages (libs/tracing.stage):
+    the children close in order inside commit.verify, whether the call
+    returns, blames a signature or stops short of the power."""
+    from cometbft_tpu.libs import tracing
+
+    vs, commit, bid = make_commit(**kw)
+    tracing.set_clock(None)  # an empty stage ring
+    fn = validation.oracle_batch_fn()
+    if raises is None:
+        validation.verify_commit_light(CHAIN_ID, vs, bid, HEIGHT,
+                                       commit, fn)
+    else:
+        with pytest.raises(raises):
+            validation.verify_commit_light(CHAIN_ID, vs, bid, HEIGHT,
+                                           commit, fn)
+    recs = tracing.stages()
+    assert [r[0] for r in recs] == closed
+    _, v0, vdur, _ = recs[-1]
+    ends = [v0]
+    for _, c0, cdur, _ in recs[:-1]:  # in order, inside, no overlap
+        assert ends[-1] <= c0 and c0 + cdur <= v0 + vdur
+        ends.append(c0 + cdur)
+
+
 def test_single_path_matches_batch():
     """No batch_fn -> single-verify loop; same outcomes."""
     vs, commit, bid = make_commit()
