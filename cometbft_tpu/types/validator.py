@@ -18,6 +18,11 @@ from typing import Dict, List, Optional, Sequence, Tuple
 from cometbft_tpu.crypto import merkle
 from cometbft_tpu.crypto.keys import PubKey
 from cometbft_tpu.libs import protoenc as pe
+from cometbft_tpu.libs import tracing
+
+# the always-on stage around every merkle root actually built (a miss
+# of the root memo); stage names are a contract (README span table)
+HASH_STAGE = "valset.hash"
 
 MAX_TOTAL_VOTING_POWER = (2**63 - 1) // 8  # validator_set.go:25
 PRIORITY_WINDOW_SIZE_FACTOR = 2  # validator_set.go:31
@@ -66,6 +71,11 @@ class ValidatorSet:
 
     NOT thread-safe (mirrors the reference; callers hold their own locks).
     """
+
+    # hash()'s memo: (the `validators` list the root was computed from,
+    # the root). A class default, so a set put together by hand through
+    # __new__ (copy, state._valset_from_j) starts with an empty memo.
+    _root: Optional[Tuple[List[Validator], bytes]] = None
 
     def __init__(self, validators: Sequence[Validator]):
         # NewValidatorSet semantics (validator_set.go:70-79): genesis
@@ -125,10 +135,30 @@ class ValidatorSet:
         self._total_power = total
 
     def hash(self) -> bytes:
-        """Merkle root of SimpleValidator leaves (validator_set.go:347)."""
-        return merkle.hash_from_byte_slices(
-            [v.bytes() for v in self.validators]
-        )
+        """Merkle root of SimpleValidator leaves (validator_set.go:347),
+        computed once per membership and remembered.
+
+        The leaves are Validator.bytes(): key type, key bytes, voting
+        power, in list order. Proposer priorities are not in them, so
+        rotating the proposer keeps the memo and copy() carries it over.
+        The memo is held against the `validators` list object it was
+        computed from: update_with_change_set replaces that list
+        wholesale (ed25519_cached.table_for_valset keys on the same
+        fact), so a changed set never answers with the old root. A
+        stale root is a consensus fault (validate_block compares it with
+        the header), and the memo rests on ONE RULE: nothing assigns a
+        member's `voting_power` or `pub_key`, or an element of
+        `validators`, outside update_with_change_set. Whoever must,
+        replaces the list (`vs.validators = list(...)`), which drops it.
+        """
+        memo = self._root
+        if memo is not None and memo[0] is self.validators:
+            return memo[1]
+        vals = self.validators
+        with tracing.stage(HASH_STAGE, n=len(vals)):
+            root = merkle.hash_from_byte_slices([v.bytes() for v in vals])
+        self._root = (vals, root)
+        return root
 
     # -- proposer rotation ---------------------------------------------------
 
@@ -193,6 +223,10 @@ class ValidatorSet:
         vs.validators = [replace(v) for v in self.validators]
         vs._index = dict(self._index)
         vs._total_power = self._total_power
+        # same leaves, same root: held against the copy's own list
+        memo = self._root
+        if memo is not None and memo[0] is self.validators:
+            vs._root = (vs.validators, memo[1])
         vs.proposer = None
         if self.proposer is not None:
             i = self._index.get(self.proposer.address, -1)
@@ -254,6 +288,9 @@ class ValidatorSet:
         if not vals:
             raise ValidatorSetError("validator set is empty after update")
         self.validators = vals
+        # new leaves: the new list alone unkeys the root memo; dropping it
+        # lets the old list go
+        self._root = None
         self._reindex()
         self._total_power = None
         self._update_total_voting_power()

@@ -59,3 +59,34 @@ def pytest_terminal_summary(terminalreporter):
             f" {nodeid} took {dur:.1f}s — mark it slow (keep a fast"
             " sibling) or shrink it"
         )
+
+
+# ---------------------------------------------------------------------------
+# ValidatorSet.hash() remembers its root (ISSUE 26) and a stale root is
+# a consensus fault. This wrapper lives on the test side only (no hook
+# in the program): under it every root the program is handed is checked
+# against a fresh merkle root of the leaves as they are.
+
+
+@pytest.fixture
+def checked_valset_roots(monkeypatch):
+    """Wraps ValidatorSet.hash for the test; yields the list of roots
+    handed out, one per call. How many of them were computed is the
+    count of `valset.hash` stages (tracing.stages())."""
+    from cometbft_tpu.crypto import merkle
+    from cometbft_tpu.types.validator import ValidatorSet
+
+    real = ValidatorSet.hash
+    roots = []
+
+    def checked(self):
+        root = real(self)
+        fresh = merkle.hash_from_byte_slices(
+            [v.bytes() for v in self.validators])
+        assert root == fresh, (
+            f"stale validator-set root: {root.hex()} != {fresh.hex()}")
+        roots.append(root)
+        return root
+
+    monkeypatch.setattr(ValidatorSet, "hash", checked)
+    return roots

@@ -30,6 +30,10 @@ from cometbft_tpu.types.commit import (
 from cometbft_tpu.types.timestamp import Timestamp
 from cometbft_tpu.types.validator import Validator, ValidatorSet
 
+# every engine run of this module checks each remembered validator-set
+# root against a fresh one (conftest.checked_valset_roots)
+pytestmark = pytest.mark.usefixtures("checked_valset_roots")
+
 CHAIN = "catchup-chain"
 N_BLOCKS = 10
 EPOCH_LEN = 4
@@ -195,6 +199,43 @@ def test_warm_ahead_fires_before_the_boundary(history):
     assert by_hash[vals_at(5).hash()] < 5
     assert by_hash[vals_at(9).hash()] < 9
     assert eng.ledger.counters["warm_requests"] >= 2
+
+
+@pytest.mark.parametrize("n_blocks,max_run", [(10, 3), (22, 2), (22, 8)])
+def test_one_merkle_root_per_distinct_valset(n_blocks, max_run,
+                                             checked_valset_roots):
+    """However many blocks are applied, the replay builds at most one
+    merkle root per distinct validator set (the `valset.hash` stages of
+    the ring: the pre-scan and the per-block warm-ahead check answer
+    from the set's memo), and the warmer is asked about each next set
+    at the height it was asked before the memo: the last block but one
+    of the epoch before it."""
+    items, vals_at = make_history(n_blocks=n_blocks)
+    distinct = {id(vals_at(h)) for h in range(1, n_blocks + 3)}
+    cursor_h = [0]
+    warmer = _Warmer()
+    warmer.request_valset = lambda vals, chain_id=None: \
+        warmer.requests.append((vals.hash(), cursor_h[0]))
+    eng = _engine(items, vals_at, warmer=warmer, read_ahead=max_run,
+                  max_run=max_run,
+                  on_apply=lambda h: cursor_h.__setitem__(0, h))
+    tracing.set_clock(None)  # an empty stage ring
+    del checked_valset_roots[:]
+    eng.run()
+    computes = [r for r in tracing.stages() if r[0] == "valset.hash"]
+    assert len(computes) <= len(distinct)
+    # asked far more often than that: one pre-scan a step, two checks a
+    # block, one per warm request
+    assert len(checked_valset_roots) \
+        >= 2 * n_blocks + len(eng.ledger.records())
+    # epoch e (blocks 4e+1..4e+4) becomes state.next_validators when
+    # block 4e-1 is applied: the request lands there, once per epoch
+    epochs_reached = range(1, (n_blocks + 1) // EPOCH_LEN + 1)
+    assert warmer.requests == [
+        (vals_at(EPOCH_LEN * e + 1).hash(), EPOCH_LEN * e - 1)
+        for e in epochs_reached]
+    assert eng.ledger.counters["warm_requests"] == len(warmer.requests)
+    assert eng.state.last_block_height == n_blocks
 
 
 def test_warm_ahead_off_means_no_requests(history):

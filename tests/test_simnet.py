@@ -15,6 +15,7 @@ import json
 import pytest
 
 from cometbft_tpu.libs import failpoints as fp
+from cometbft_tpu.libs import tracing
 from cometbft_tpu.simnet import (
     Simnet,
     SimnetFailure,
@@ -612,3 +613,33 @@ def test_gateway_forged_header_scenario(tmp_path):
     assert json.dumps(results, sort_keys=True) == \
         json.dumps(results2, sort_keys=True)
     assert ev_hash == ev_hash2
+
+
+def test_remembered_valset_roots_stay_fresh_through_a_rotation(
+        tmp_path, checked_valset_roots):
+    """ValidatorSet.hash() remembers its root (ISSUE 26). Over a
+    schedule whose app emits validator updates (an `epoch` op: kvstore
+    ``val:`` txs through the real ABCI -> update_with_change_set ->
+    state/execution.py path), every root any node is handed, from a
+    memo or computed, equals a fresh merkle root (the wrapper asserts
+    it inside the run); the rotation lands, so roots of two memberships
+    were in play; and most answers came from the memo (few
+    `valset.hash` stages for many calls)."""
+    sched = [{"at": 0.3, "op": "epoch", "node": 0, "churn": 0.5}]
+    with Simnet(4, seed=26, basedir=str(tmp_path), power=100_000,
+                extra_validators=8) as sim:
+        genesis_root = sim.net.genesis.validators.hash()
+        assert sim.run(sched, until_height=6, max_time=90.0)
+        sim.assert_safety()
+        rec = sim.epoch_results[0]
+        assert "error" not in rec and rec["out"] and rec["in"], rec
+        finals = {n.node.consensus.state.validators.hash()
+                  for n in sim.net.nodes}
+        # read here: leaving the simnet restores the clock, which
+        # clears the stage ring
+        computes = sum(1 for r in tracing.stages()
+                       if r[0] == "valset.hash")
+    assert len(finals) == 1 and genesis_root not in finals
+    assert genesis_root in checked_valset_roots
+    assert finals <= set(checked_valset_roots)
+    assert 2 <= computes < len(checked_valset_roots) // 2

@@ -243,3 +243,130 @@ def test_rotated_out_valset_memo_entry_evictable(monkeypatch):
             "rotated-out epoch's table still strongly referenced"
     finally:
         tc.set_capacities(**saved)
+
+
+# ---------------------------------------------------------------------------
+# The merkle-root memo (ISSUE 26): hash() computes once per membership.
+# A stale root is a consensus fault, so every root handed out, on a set
+# and on its copies, after any operation, equals a fresh one.
+# ---------------------------------------------------------------------------
+
+
+def _fresh_root(vs):
+    from cometbft_tpu.crypto import merkle
+
+    return merkle.hash_from_byte_slices([v.bytes() for v in vs.validators])
+
+
+def _hash_computes():
+    from cometbft_tpu.libs import tracing
+    from cometbft_tpu.types.validator import HASH_STAGE
+
+    return sum(1 for rec in tracing.stages() if rec[0] == HASH_STAGE)
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_hash_equals_a_fresh_root_after_any_sequence(seed, tmp_path):
+    """Seeded random walks over everything that touches a set: copies,
+    proposer rotation, change sets of every shape (power-only, add,
+    remove, empty), a state-store round trip. hash() is asked at random
+    moments (so memos of every age are in play) and checked on every
+    live set after every operation."""
+    import random
+    from dataclasses import replace
+
+    from cometbft_tpu.state.state import State, StateStore
+
+    rnd = random.Random(f"valset-root/{seed}")
+    pool = mkvals([1] * 24)  # the keys members are drawn from
+    members = rnd.sample(range(len(pool)), 6)
+    live = [ValidatorSet([Validator(pool[i].pub_key, rnd.randint(1, 50))
+                          for i in members])]
+    store = StateStore(str(tmp_path / "state.db"))
+    genesis = State.make_genesis("root-chain", ValidatorSet(mkvals([1])))
+
+    def power_only(vs):
+        v = rnd.choice(vs.validators)
+        vs.update_with_change_set(
+            [Validator(v.pub_key, v.voting_power + rnd.randint(1, 9))])
+
+    def add(vs):
+        out = [p for p in pool if not vs.has_address(p.address)]
+        if out:
+            vs.update_with_change_set(
+                [Validator(rnd.choice(out).pub_key, rnd.randint(1, 50))])
+
+    def remove(vs):
+        if len(vs) > 2:
+            vs.update_with_change_set(
+                [Validator(rnd.choice(vs.validators).pub_key, 0)])
+
+    def mixed(vs):
+        out = [p for p in pool if not vs.has_address(p.address)]
+        a, b = rnd.sample(vs.validators, 2)
+        changes = [Validator(a.pub_key, a.voting_power + 3)]
+        if len(vs) > 2:
+            changes.append(Validator(b.pub_key, 0))
+        if out:
+            changes.append(Validator(out[0].pub_key, rnd.randint(1, 50)))
+        vs.update_with_change_set(changes)
+
+    def round_trip(vs):
+        st = replace(genesis, validators=vs, next_validators=vs.copy())
+        store.save(st)
+        loaded = store.load()
+        live.extend([loaded.validators, loaded.next_validators])
+
+    ops = [
+        lambda vs: live.append(vs.copy()),
+        lambda vs: vs.increment_proposer_priority(rnd.randint(1, 4)),
+        lambda vs: live.append(
+            vs.copy_increment_proposer_priority(rnd.randint(1, 3))),
+        power_only, add, remove, mixed,
+        lambda vs: vs.update_with_change_set([]),
+        round_trip,
+    ]
+    for _ in range(60):
+        vs = rnd.choice(live)
+        if rnd.random() < 0.6:
+            vs.hash()  # a memo taken before the operation
+        rnd.choice(ops)(vs)
+        for s in live:
+            assert s.hash() == _fresh_root(s)
+        del live[:-6]  # keep the walk cheap: the six youngest sets
+    store.close()
+
+
+def test_hash_computes_once_per_membership():
+    """The `valset.hash` stage fires on a computed root only: any
+    number of calls on an unchanged set and on its copies compute once,
+    a change set once more; a hit records nothing."""
+    from cometbft_tpu.libs import tracing
+
+    vals = mkvals([10, 20, 30])
+    vs = ValidatorSet(vals)
+    tracing.set_clock(None)  # an empty stage ring
+    c0 = _hash_computes()
+    root = vs.hash()
+    assert _hash_computes() == c0 + 1
+    cp = vs.copy()
+    rot = vs.copy_increment_proposer_priority(3)
+    vs.increment_proposer_priority(2)
+    vs.update_with_change_set([])
+    for s in (vs, cp, rot, cp.copy(), rot.copy()):
+        for _ in range(5):
+            assert s.hash() == root
+    assert _hash_computes() == c0 + 1
+    # a copy taken BEFORE the first hash() has nothing to carry
+    cold = ValidatorSet(vals).copy()
+    assert cold.hash() == root and _hash_computes() == c0 + 2
+    # a change set: one more on the changed set, none on the others
+    vs.update_with_change_set([Validator(vals[0].pub_key, 11)])
+    changed = vs.hash()
+    assert changed != root and changed == _fresh_root(vs)
+    assert vs.hash() == vs.copy().hash() == changed
+    assert cp.hash() == rot.hash() == root
+    assert _hash_computes() == c0 + 3
+    # whoever replaces the list by hand drops the memo with it
+    vs.validators = list(vs.validators)
+    assert vs.hash() == changed and _hash_computes() == c0 + 4
