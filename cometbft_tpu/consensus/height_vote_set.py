@@ -79,6 +79,16 @@ class HeightVoteSet:
             vs = self._rounds[vote.round][vote.vote_type]
         return vs.add_vote(vote, verify=verify)
 
+    def stage_vote(self, vote: Vote):
+        """`VoteSet.stage_vote` on the set `add_vote(vote)` would reach
+        if it were called now; None where it would reach none."""
+        with self._lock:
+            if not 0 <= vote.round <= self.round + 1:
+                return None
+            self._ensure_round(vote.round)
+            vs = self._rounds[vote.round].get(vote.vote_type)
+        return None if vs is None else vs.stage_vote(vote)
+
     def prevotes(self, round_: int) -> Optional[VoteSet]:
         with self._lock:
             self._ensure_round(round_)

@@ -26,6 +26,7 @@ from dataclasses import dataclass
 from typing import Callable, Optional
 
 from cometbft_tpu.consensus import heightledger
+from cometbft_tpu.consensus import vote_intake
 from cometbft_tpu.consensus import wal as walmod
 from cometbft_tpu.consensus.height_vote_set import HeightVoteSet
 from cometbft_tpu.libs import controller as controlplane
@@ -150,6 +151,9 @@ class ConsensusState(BaseService):
         self.commit_round = -1
         self._triggered_precommit_wait = False
         self._thread: Optional[threading.Thread] = None
+        # a message _intake_votes took off msg_queue behind a run of
+        # votes and did not handle: the next one _next_msg returns
+        self._held_msg = None
 
         # test override hooks (state.go:122-125 decideProposal/doPrevote)
         self.decide_proposal_fn = self._default_decide_proposal
@@ -310,17 +314,68 @@ class ConsensusState(BaseService):
             if item is None:
                 continue
             try:
-                self._handle(item, write_wal=True)
+                if item[0] == "vote":
+                    self._intake_votes(item)
+                else:
+                    self._handle_logged(item)
             except fp.SimulatedCrash as e:
                 # the in-process stand-in for a process kill: halt the
                 # machine dead (no graceful teardown) so the crash-
                 # recovery tests can restart over the same home dir
                 self._halt(str(e))
                 return
-            except Exception:  # noqa: BLE001 - engine must not die silently
-                import traceback
 
-                traceback.print_exc()
+    def _handle_logged(self, item) -> None:
+        """`_handle` for one message of the receive routine: only a
+        simulated crash gets past it."""
+        try:
+            self._handle(item, write_wal=True)
+        except fp.SimulatedCrash:
+            raise
+        except Exception:  # noqa: BLE001 - engine must not die silently
+            import traceback
+
+            traceback.print_exc()
+
+    def _intake_votes(self, first) -> None:
+        """Handle the vote message `first` and the vote messages the
+        queue already holds behind it (none is waited for; at most the
+        plane's `max_batch`; the first message of another kind ends
+        the run and is held for the next turn), through the vote
+        intake: their signature checks go to the verify plane
+        together, and each message is then handled as the loop above
+        would have handled it in its turn, the internal queue first.
+        With no plane the run is `first` alone."""
+        from cometbft_tpu.verifyplane import global_plane
+
+        plane = global_plane()
+        items = [first]
+        while plane is not None and len(items) < plane.max_batch:
+            try:
+                nxt = self.msg_queue.get_nowait()
+            except queue.Empty:
+                break
+            if nxt[0] != "vote":
+                self._held_msg = nxt
+                break
+            items.append(nxt)
+
+        def handle(item) -> None:
+            if not self.is_running():
+                return  # stopped: the rest stays unhandled, as queued
+            while item is not first:  # _next_msg looked before `first`
+                try:
+                    inner = self.internal_queue.get_nowait()
+                except queue.Empty:
+                    break
+                self._handle_logged(inner)
+            self._handle_logged(item)
+
+        vote_intake.intake(
+            items, lambda item: item[1].vote,
+            lambda vote: self.votes if vote.height == self.height
+            else None,
+            handle)
 
     def _halt(self, reason: str) -> None:
         """Kill the machine in place (crash simulation landing): marks
@@ -345,6 +400,9 @@ class ConsensusState(BaseService):
             return self.internal_queue.get_nowait()
         except queue.Empty:
             pass
+        if self._held_msg is not None:
+            item, self._held_msg = self._held_msg, None
+            return item
         try:
             return self.msg_queue.get(timeout=timeout)
         except queue.Empty:

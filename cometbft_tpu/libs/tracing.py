@@ -382,7 +382,8 @@ class _Stage:
         self.ms = dur / 1e6
         if len(_STAGES) == STAGE_CAPACITY:
             _STAGES_DROPPED += 1
-        _STAGES.append((self.name, self.t0, dur, threading.get_ident()))
+        _STAGES.append((self.name, self.t0, dur, threading.get_ident(),
+                        self.args))
         t = _TRACER
         if t is not None:
             t._complete(self.name, "", self.t0, dur, self.args)
@@ -394,9 +395,10 @@ def stage(name: str, **args) -> _Stage:
     catch-up step's verify, a commit check's host pack). Unlike
     :func:`span` it needs no tracer:
 
-      * leaving it appends ``(name, t0_ns, dur_ns, tid)`` to one
-        process-global ring of STAGE_CAPACITY records on
-        :func:`monotonic_ns` (read with :func:`stages`; overflow drops
+      * leaving it appends ``(name, t0_ns, dur_ns, tid)`` and its
+        args to one process-global ring of STAGE_CAPACITY records on
+        :func:`monotonic_ns` (read with :func:`stages` or
+        :func:`stage_records`; overflow drops
         the oldest and counts in :func:`stages_dropped`), and the
         object then holds ``.ms``: a caller that keeps a ledger column
         reads that instead of timing the region a second time;
@@ -415,8 +417,14 @@ def stage(name: str, **args) -> _Stage:
 
 
 def stages() -> List[tuple]:
-    """The stage ring's records, oldest first (atomic snapshot: see
-    Tracer.events)."""
+    """The stage ring's records as ``(name, t0_ns, dur_ns, tid)``,
+    oldest first (atomic snapshot: see Tracer.events)."""
+    return [r[:4] for r in list(_STAGES)]
+
+
+def stage_records() -> List[tuple]:
+    """:func:`stages` with each record's args as a fifth field: the
+    dict its stage was entered with (``votes.intake``'s ``n``)."""
     return list(_STAGES)
 
 

@@ -45,6 +45,48 @@ class _BlockVotes:
     sum: int = 0
 
 
+class _StagedVote:
+    """A vote whose signature check `VoteSet.stage_vote` put in flight
+    ahead of its `add_vote`, with what it was submitted with."""
+
+    __slots__ = ("vote_set", "vote", "future", "power", "need_ext",
+                 "ext_err", "group", "counted")
+
+    def __init__(self, vote_set, vote, future, power, need_ext, ext_err,
+                 group, counted):
+        self.vote_set = vote_set
+        self.vote = vote  # held, so that id(vote) stays this vote's
+        self.future = future
+        self.power = power
+        self.need_ext = need_ext
+        self.ext_err = ext_err
+        self.group = group
+        self.counted = counted
+
+    def unwind(self) -> None:
+        """The vote was not admitted on this check (refused or a
+        duplicate by the time of its turn, or never added at all):
+        where the plane counted its power, take it back out of the
+        fused tally, which then stands where the serial path leaves
+        it."""
+        from cometbft_tpu.verifyplane import PlaneError
+
+        if not self.counted:
+            return
+        try:
+            counted = all(self.future.result())
+        except PlaneError:
+            return
+        if counted:
+            self.group.retract(self.power)
+
+    def release(self) -> None:
+        """End of the intake call that staged it: unwind it if no
+        `add_vote` took it up."""
+        if self.vote_set._staged.pop(id(self.vote), None) is self:
+            self.unwind()
+
+
 class VoteSet:
     def __init__(self, chain_id: str, height: int, round_: int,
                  signed_msg_type: int, valset: ValidatorSet,
@@ -73,6 +115,9 @@ class VoteSet:
         self.verify_plane = None
         self._plane_groups: Dict[bytes, object] = {}
         self._valset_cols = None  # (pubs tuple, powers tuple), lazy
+        # id(vote) -> _StagedVote: checks `stage_vote` put in flight
+        # ahead of the vote's own add_vote
+        self._staged: Dict[int, _StagedVote] = {}
         # flush-seq observer: called with the verify-plane flush-ledger
         # seq that served an admitted vote (the consensus height
         # ledger's /dump_flushes join key); None = nobody listening
@@ -206,29 +251,30 @@ class VoteSet:
             self._plane_groups[key] = g
         return g
 
-    def _add_vote_plane(self, vote: Vote, plane) -> bool:
-        from cometbft_tpu.verifyplane import PlaneError
+    def _plane_terms(self, vote: Vote):
+        """(need_ext, ext_err, group, counted) of a vote that passed
+        `_precheck`, as of now. Caller holds the lock."""
+        need_ext, ext_err = self._ext_discipline(vote)
+        group = self._plane_group(vote.block_id)
+        # counted = this vote would add power to its block's tally
+        # if valid and still admissible (existing None, or
+        # peer-maj23-unlocked equivocation with a free slot); a
+        # discipline violation rejects the vote regardless
+        existing = self.votes[vote.validator_index]
+        bv = self.votes_by_block.get(vote.block_id.key())
+        counted = ext_err is None and (
+            existing is None
+            or (bv is not None and bv.peer_maj23
+                and bv.votes[vote.validator_index] is None)
+        )
+        return need_ext, ext_err, group, counted
 
-        with self._lock:
-            val = self._precheck(vote)
-            if val is None:
-                return False
-            need_ext, ext_err = self._ext_discipline(vote)
-            group = self._plane_group(vote.block_id)
-            # counted = this vote would add power to its block's tally
-            # if valid and still admissible (existing None, or
-            # peer-maj23-unlocked equivocation with a free slot); a
-            # discipline violation rejects the vote regardless
-            existing = self.votes[vote.validator_index]
-            bv = self.votes_by_block.get(vote.block_id.key())
-            counted = ext_err is None and (
-                existing is None
-                or (bv is not None and bv.peer_maj23
-                    and bv.votes[vote.validator_index] is None)
-            )
-
-        # signature staging + the wait happen OUTSIDE the lock: that is
-        # what lets concurrent gossip callers coalesce into one flush
+    def _plane_submit(self, vote: Vote, plane, val, need_ext: bool,
+                      group, counted: bool):
+        """One submission a vote (vote row, extension row, `stamp`
+        metadata, `counted`), OUTSIDE the lock: that is what lets
+        concurrent gossip callers, and the votes of one staged burst,
+        coalesce into one flush. Returns the future."""
         rows = [(val.pub_key, vote.sign_bytes(self.chain_id),
                  vote.signature)]
         vidx = [vote.validator_index]
@@ -254,11 +300,77 @@ class VoteSet:
                          vote.extension_signature))
             vidx.append(vote.validator_index)
             stamp.append(None)
+        return plane.submit_many(rows, power=val.voting_power,
+                                 group=group, counted=counted,
+                                 vidx=vidx, chain_id=self.chain_id,
+                                 stamp=stamp)
+
+    def stage_vote(self, vote: Vote) -> Optional["_StagedVote"]:
+        """Put `vote`'s signature check in flight now, for the
+        `add_vote(vote)` that follows (a caller with several votes in
+        hand stages them all, then adds them in arrival order). A
+        signature check is pure, so its verdict holds whatever the set
+        admits in between; nothing else is decided here: `add_vote`
+        prechecks again, and admission reconciles the `counted`
+        predicted now. None where there is nothing to stage (no plane,
+        a vote the precheck refuses or finds a duplicate, a plane that
+        does not take it): `add_vote` then does all of it in its turn,
+        as without staging.
+
+        The staged check is found again by the vote's IDENTITY
+        (`_staged` is keyed by `id(vote)`): `add_vote` must get this
+        very object, not an equal copy, which would be checked a
+        second time. The caller owns what is returned and calls its
+        `release()` when its votes are handled, taken up or not; until
+        then the `_StagedVote` holds the vote, so the id cannot pass
+        to another object."""
+        from cometbft_tpu.verifyplane import PlaneError
+
+        plane = self._plane()
+        if plane is None or id(vote) in self._staged:
+            return None
         try:
-            fut = plane.submit_many(rows, power=val.voting_power,
-                                    group=group, counted=counted,
-                                    vidx=vidx, chain_id=self.chain_id,
-                                    stamp=stamp)
+            with self._lock:
+                val = self._precheck(vote)
+                if val is None:
+                    return None
+                need_ext, ext_err, group, counted = self._plane_terms(vote)
+            fut = self._plane_submit(vote, plane, val, need_ext, group,
+                                     counted)
+        except (VoteSetError, PlaneError):
+            return None
+        staged = _StagedVote(self, vote, fut, val.voting_power, need_ext,
+                             ext_err, group, counted)
+        self._staged[id(vote)] = staged
+        return staged
+
+    def _add_vote_plane(self, vote: Vote, plane) -> bool:
+        from cometbft_tpu.verifyplane import PlaneError
+
+        staged = self._staged.pop(id(vote), None) if self._staged else None
+        assert staged is None or staged.vote is vote
+        val = None
+        try:
+            with self._lock:
+                val = self._precheck(vote)
+                if val is not None and staged is None:
+                    need_ext, ext_err, group, counted = \
+                        self._plane_terms(vote)
+        finally:
+            if val is None and staged is not None:
+                staged.unwind()  # refused, or a duplicate by now
+        if val is None:
+            return False
+        try:
+            if staged is not None:
+                # staged ahead: the check is in flight or done; what it
+                # was submitted with is what admission reconciles
+                fut, need_ext, ext_err, group, counted = (
+                    staged.future, staged.need_ext, staged.ext_err,
+                    staged.group, staged.counted)
+            else:
+                fut = self._plane_submit(vote, plane, val, need_ext,
+                                         group, counted)
             verdicts = fut.result()
         except PlaneError:
             # plane stopped/saturated mid-call: serial host fallback

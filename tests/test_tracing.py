@@ -120,6 +120,17 @@ def test_stage_records_with_tracer_off_and_exposes_ms():
     assert tracing.export_chrome()["traceEvents"] == []  # no tracer
 
 
+def test_stage_records_keeps_the_args_beside_the_four_fields():
+    with tracing.stage("unit.args", n=7):
+        pass
+    with tracing.stage("unit.bare"):
+        pass
+    recs = tracing.stage_records()
+    assert [r[:4] for r in recs] == tracing.stages()
+    assert [(r[0], r[4]) for r in recs] == [("unit.args", {"n": 7}),
+                                            ("unit.bare", {})]
+
+
 def test_stage_records_when_its_body_raises():
     with pytest.raises(KeyError):
         with tracing.stage("unit.raises") as st:

@@ -292,11 +292,14 @@ def test_quorum_retract_clears_transient_crossing():
     assert g.quorum_reached
 
 
-def test_voteset_reaches_quorum_through_plane(plane):
+@pytest.mark.parametrize("feed", ["threads", "burst"])
+def test_voteset_reaches_quorum_through_plane(plane, feed):
     """Gossiped precommits (vote + extension signatures as ONE
     submission each) coalesce through the plane; the VoteSet's 2/3
     quorum comes out of the fused group tally, and a forged extension
-    is rejected without its power standing."""
+    is rejected without its power standing. The rows meet either way:
+    fed by three threads at once, or by one thread through the vote
+    intake (consensus/vote_intake.py), which stages the burst."""
     from cometbft_tpu.types import canonical
     from cometbft_tpu.types.block_id import BlockID, PartSetHeader
     from cometbft_tpu.types.timestamp import Timestamp
@@ -333,11 +336,22 @@ def test_voteset_reaches_quorum_through_plane(plane):
         except Exception as e:  # noqa: BLE001 - assert below
             errs.append((i, e))
 
-    threads = [threading.Thread(target=add, args=(i,)) for i in range(3)]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join()
+    if feed == "burst":
+        from cometbft_tpu.consensus import vote_intake
+        from cometbft_tpu.consensus.height_vote_set import HeightVoteSet
+
+        hvs = HeightVoteSet(chain, 5, vs, ext_enabled=True)
+        vset = hvs.precommits(0)
+        assert vote_intake.intake([mk(i) for i in range(3)], lambda v: v,
+                                  lambda v: hvs, hvs.add_vote) == [True] * 3
+        assert max(d["submissions"] for d in plane.dispatch_log) > 1
+    else:
+        threads = [threading.Thread(target=add, args=(i,))
+                   for i in range(3)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
     assert not errs, errs
     group = vset._plane_groups[bid.key()]
     assert group.quorum_reached and group.tally == 30
