@@ -432,9 +432,13 @@ def leg_stream(data, host):
           and got[bad[0]] == ("invalid_signature", data["stream_bad_sig"]),
           "the bad block", bad, [got[i] for i in bad])
     stats = ec.table_cache_stats()
-    check(sv.chunks["stamped"] == len(data["stream_segments"])
+    # a segment is cut into chunks of sv.max_sigs device rows
+    cap = sv.max_sigs // ec.table_pad(N_STREAM_VALS)
+    n_chunks = sum(-(-len(seg) // cap) for seg in data["stream_segments"])
+    check(sv.chunks["stamped"] == n_chunks
           and sv.chunks["host_packed"] == 0 and sv.chunks["dense"] == 0,
-          "chunks left the device-stamped cached-table path", sv.chunks)
+          "chunks left the device-stamped cached-table path", sv.chunks,
+          n_chunks)
     check(stats["misses"] - stats0["misses"] == 2,
           "expected one table build or patch per validator set",
           stats0, stats)

@@ -6,13 +6,16 @@ block, ~1k sigs each). The TPU restructuring packs MANY consecutive
 commits into one fused device pass: every signature row carries a
 commit_id, the kernel verifies all rows in parallel and computes each
 commit's voting-power quorum bit with a segmented one-hot tally
-(ed25519_kernel.tally_core), so a 16k-signature pass retires ~16 blocks
+(ed25519_kernel.tally_core), so an 8k-signature pass retires 8 blocks
 of 1k validators at once.
 
-Double buffering comes free from JAX async dispatch: the kernel call for
-chunk k returns immediately, so the host packs chunk k+1 while the device
+A verify() call is cut into chunks of at most CHUNK_ROWS device rows: a
+64-block run of 1k validators is 8 such passes, overlapped. Double
+buffering comes free from JAX async dispatch: the kernel call for chunk
+k returns immediately, so the host packs chunk k+1 while the device
 works; fetching chunk k's results overlaps the next dispatch
-(SURVEY.md §7 stage 2's H2D-hiding requirement).
+(SURVEY.md §7 stage 2's H2D-hiding requirement). Every verdict of the
+call is in hand before verify() returns.
 """
 from __future__ import annotations
 
@@ -35,6 +38,15 @@ from cometbft_tpu.types.validator import ValidatorSet
 # Fixed commit-axis padding: keeps the kernel's static n_commits constant
 # across runs (one compile per signature bucket, not per run length).
 MAX_COMMITS_PER_CHUNK = 64
+
+# Device rows a chunk holds at most. Small enough that a full run at 1k
+# validators (blocksync MAX_RUN, catchup.MAX_RUN: 64 blocks) is several
+# chunks, so verify()'s two-in-flight loop has a next chunk to pack while
+# the device verifies the last: a run that is one chunk packs and
+# verifies in turn. The first pack of a call is the part nothing hides,
+# so smaller is better until the host's per-chunk costs set the pace:
+# swept 4,096 to 65,536 on a v5e (PERF.md section 6, PR 28).
+CHUNK_ROWS = 8192
 
 # Device-side sign-bytes stamping for the cached chunk path (ISSUE 19):
 # ship per-row (sig, ts, flags) deltas plus ONE resident template per
@@ -75,7 +87,7 @@ class StreamVerifier:
     the reference's per-sig blame fallback, types/validation.go:243-250).
     """
 
-    def __init__(self, max_sigs: int = 65536, use_pallas: bool = False,
+    def __init__(self, max_sigs: int = CHUNK_ROWS, use_pallas: bool = False,
                  min_device_sigs: int = 129):
         from cometbft_tpu.libs.staging import StagingPool
 
@@ -470,10 +482,12 @@ class StreamVerifier:
         in_flight: List[_Chunk] = []
         for chunk_pairs in self._split_for_tables(indexed):
             # host pack or delta staging, template entry and (nested,
-            # stream.dispatch) the device call
+            # stream.dispatch) the device call; flying = chunks the
+            # device still has while the host packs this one (0: it idles)
             with tracing.stage("stream.pack", jobs=len(chunk_pairs),
                                rows=sum(len(j.commit.signatures)
-                                        for _, j in chunk_pairs)):
+                                        for _, j in chunk_pairs),
+                               flying=len(in_flight)):
                 chunk = self._pack_any(chunk_pairs)
             if chunk is None:
                 # zero packable rows (e.g. every signature ABSENT): fail
@@ -568,7 +582,7 @@ class StreamVerifier:
 
 
 def make_stream_verifier(use_pallas: Optional[bool] = None,
-                         max_sigs: int = 65536) -> StreamVerifier:
+                         max_sigs: int = CHUNK_ROWS) -> StreamVerifier:
     if use_pallas is None:
         from cometbft_tpu.crypto.batch import _accel_backend
 

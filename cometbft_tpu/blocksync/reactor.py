@@ -8,11 +8,11 @@ bans peers serving bad blocks (:480-496), switches to consensus when
 caught up (:391-401).
 
 TPU restructuring: instead of one VerifyCommitLight per block, a RUN of
-consecutive ready blocks is verified in one fused multi-commit device
-pass (pipeline.StreamVerifier). Validator-set changes mid-run are
-handled by re-verifying from the height where the set changed — the
-optimistic batch is correct whenever the set is stable, which is the
-overwhelmingly common case in replay."""
+consecutive ready blocks is verified in one call of the fused
+multi-commit verifier (pipeline.StreamVerifier). Validator-set changes
+mid-run are handled by re-verifying from the height where the set
+changed — the optimistic batch is correct whenever the set is stable,
+which is the overwhelmingly common case in replay."""
 from __future__ import annotations
 
 import threading
@@ -33,7 +33,7 @@ from cometbft_tpu.state.state import State
 from cometbft_tpu.store.blockstore import BlockStore
 from cometbft_tpu.types.block import Block
 
-MAX_RUN = 64  # blocks fused per device pass (64 x 1k sigs fills a bucket)
+MAX_RUN = 64  # blocks per verify call (at 1k sigs, 8 overlapped passes of 8)
 
 fp.register("blocksync.process",
             "a run of verified-ready blocks about to be processed "

@@ -14,6 +14,7 @@ ledger.
 """
 import json
 
+import numpy as np
 import pytest
 
 from cometbft_tpu.blocksync import catchup as cu
@@ -416,6 +417,196 @@ def test_stream_verifier_stages_once_per_chunk():
         "stream.collect", "stream.collect"]
     for (_, d0, ddur, _), (_, p0, pdur, _) in (recs[1:3], recs[3:5]):
         assert p0 <= d0 and d0 + ddur <= p0 + pdur
+
+
+class _LateRead:
+    """What a device call hands back before it ran. The verdicts are
+    computed from the STAGED host buffers only when they are fetched:
+    the device may read a numpy argument at any moment up to the
+    collect of its result (libs/staging.py), so a buffer rewritten
+    under a chunk in flight changes what this returns."""
+
+    def __init__(self, run, pick):
+        self.run, self.pick = run, pick
+
+    def __array__(self, dtype=None, copy=None):
+        return self.run()[self.pick]
+
+
+class _StandInDevice:
+    """Stands in for the cached-table path's device (the Pallas kernel
+    does not run on the CPU): a table of `width` slots for any set, and
+    in place of `verify_tally_delta_cached` a host check of every live
+    staged row against its commit (`check=False`: all rows valid). It
+    keeps each call's static shapes, and which chunk every staging
+    buffer was last handed out for."""
+
+    def __init__(self, monkeypatch, sv, width, check=True):
+        from cometbft_tpu.ops import ed25519_cached as ec
+
+        self.width, self.check = width, check
+        self.calls = []        # (B, template rows, n_commits) per chunk
+        self.collected = set()
+        self.owner = {}        # id(staging buffer) -> chunk it was for
+        self._packing = None
+        table = type("Table", (), {"n_vals": width, "pub_raw": True})()
+        monkeypatch.setattr(ec, "table_for_valset", lambda vs: table)
+        monkeypatch.setattr(ec, "verify_tally_delta_cached", self.delta)
+        pack, collect, get = (sv._pack_chunk_cached, sv._collect,
+                              sv._staging.get)
+
+        def packing(jobs, tbl):
+            self._packing = jobs
+            return pack(jobs, tbl)
+
+        def collecting(chunk, results):
+            collect(chunk, results)
+            self.collected.add(chunk.pending[0].chunk)
+
+        def getting(*a, **kw):
+            buf = get(*a, **kw)
+            # the rotation contract: never the buffer of a chunk whose
+            # result was not fetched yet
+            assert self.owner.get(id(buf), -1) in self.collected | {-1}, a
+            self.owner[id(buf)] = len(self.calls)
+            return buf
+
+        sv._pack_chunk_cached, sv._collect = packing, collecting
+        sv._staging.get = getting
+
+    def delta(self, sig, ts, flags, ent, table, n_commits, thresh=None):
+        jobs, k, M = self._packing, len(self.calls), self.width
+        self.calls.append((sig.shape[0], int(ent.pre_mat.shape[0]),
+                           n_commits))
+        done = []
+
+        def run():
+            if done:
+                return done[0]
+            valid = np.ones(sig.shape[0], np.bool_)
+            quorum = np.ones(n_commits, np.bool_)
+            live = np.flatnonzero(flags & 1) if self.check else ()
+            for b in live:
+                j, i = divmod(int(b), M)
+                assert (int(flags[b]) >> 2) & 0xFF == j
+                job = jobs[j][1]
+                cs = job.commit.signatures[i]
+                msg = canonical.canonical_vote_bytes(
+                    job.chain_id, canonical.PRECOMMIT_TYPE,
+                    job.commit.height, job.commit.round,
+                    job.commit.block_id, cs.timestamp)
+                valid[b] = job.vals.validators[i].pub_key \
+                    .verify_signature(msg, bytes(sig[b]))
+            for j in range(len(jobs) if self.check else 0):
+                quorum[j] = 3 * valid[j * M:j * M + 3].sum() > 2 * 3
+            done.append((valid, quorum))
+            return done[0]
+
+        out = (_LateRead(run, 0), None, _LateRead(run, 1))
+        out[0].chunk = k
+        return out
+
+
+def _stream_jobs(items, vals_at):
+    from cometbft_tpu.blocksync.pipeline import CommitJob
+
+    return [CommitJob(vals_at(h), blk.block_id(), h, commit, CHAIN)
+            for h, (blk, commit) in sorted(items.items())]
+
+
+def _flip(items, h, idx):
+    sig = items[h][1].signatures[idx]
+    sig.signature = sig.signature[:10] + \
+        bytes([sig.signature[10] ^ 1]) + sig.signature[11:]
+
+
+def _outcomes(errs):
+    return [(type(e).__name__, getattr(e, "idx", None)) for e in errs]
+
+
+@pytest.mark.parametrize("n_blocks,order", [
+    (8, "pack pack pack collect pack collect collect collect"),
+    (9, "pack pack pack collect pack collect pack collect collect collect"),
+], ids=["4-chunks", "5-chunks"])
+def test_stream_verifier_overlaps_the_chunks_of_one_call(
+        monkeypatch, n_blocks, order):
+    """A verify call of several cached-table chunks: two fly while the
+    next packs, a chunk's staging buffers come round only after it was
+    collected, and every verdict equals the host's, with a bad
+    signature in the first, a middle and the last chunk blamed at its
+    commit-signature index."""
+    from cometbft_tpu.blocksync.pipeline import StreamVerifier
+
+    n_chunks = order.split().count("pack")
+    items, vals_at = make_history(n_blocks=n_blocks, epoch_len=100)
+    # 128 slots a commit, 256 rows a chunk: two commits a chunk
+    bad = {2: 0, 5: 2, n_blocks: 1}  # height -> flipped signature
+    for h, idx in bad.items():
+        _flip(items, h, idx)
+    jobs = _stream_jobs(items, vals_at)
+    sv = StreamVerifier(max_sigs=256, use_pallas=True, min_device_sigs=1)
+    dev = _StandInDevice(monkeypatch, sv, width=128)
+    tracing.set_clock(None)
+    got = sv.verify(jobs)
+    assert _outcomes(got) == _outcomes(HostCommitVerifier().verify(jobs))
+    assert {j.height: e.idx for j, e in zip(jobs, got)
+            if e is not None} == bad
+    assert sv.chunks == {"stamped": n_chunks, "host_packed": 0, "dense": 0}
+    assert dev.calls == [(256, 8, 2)] * n_chunks
+    assert dev.collected == set(range(n_chunks))
+    recs = [(r[0][len("stream."):], r[4]) for r in tracing.stage_records()
+            if r[0] in ("stream.pack", "stream.collect")]
+    # three packs before anything is fetched, then a fetch before
+    # each further pack, then the rest
+    assert [name for name, _ in recs] == order.split()
+    assert [a["flying"] for name, a in recs if name == "pack"] == \
+        [0, 1] + [2] * (n_chunks - 2)
+    assert [a["jobs"] for name, a in recs if name == "pack"] == \
+        [2] * (n_blocks // 2) + [1] * (n_blocks % 2)
+
+
+@pytest.mark.parametrize("width,cap", [(256, 32), (1024, 8), (4096, 2),
+                                       (16384, 1)])
+def test_stream_default_chunk_is_one_shape_per_table_width(
+        monkeypatch, width, cap):
+    """With the default capacity a chunk holds CHUNK_ROWS device rows
+    (one commit where the table is wider than that): 32 / 8 / 2 / 1
+    commits by the table's width, and the batch, the template matrix
+    and the tally compile to the same shapes whatever a run's length."""
+    from cometbft_tpu.blocksync import pipeline
+
+    items, vals_at = make_history(n_blocks=64, epoch_len=100)
+    jobs = _stream_jobs(items, vals_at)
+    sv = pipeline.make_stream_verifier(use_pallas=True)
+    assert sv.max_sigs == pipeline.CHUNK_ROWS == 8192
+    sv.min_device_sigs = 1
+    dev = _StandInDevice(monkeypatch, sv, width=width, check=False)
+    for n in (1, 16, 17, 64):
+        del dev.calls[:]
+        assert sv.verify(jobs[:n]) == [None] * n
+        assert len(dev.calls) == -(-n // cap)
+        assert set(dev.calls) == {(max(8192, width), max(8, cap), cap)}
+    assert sv.chunks["host_packed"] == sv.chunks["dense"] == 0
+
+
+def test_tampered_block_in_a_later_chunk_fails_the_whole_run(monkeypatch):
+    """A run's verdicts are all in hand before its first block is
+    applied: a bad commit in the third of four chunks raises at its
+    height, the cursor stays behind the run and nothing is applied."""
+    from cometbft_tpu.blocksync.pipeline import StreamVerifier
+
+    items, vals_at = make_history(n_blocks=8, epoch_len=100)
+    _flip(items, 6, 1)
+    sv = StreamVerifier(max_sigs=256, use_pallas=True, min_device_sigs=1)
+    dev = _StandInDevice(monkeypatch, sv, width=128)
+    applied = []
+    eng = _engine(items, vals_at, read_ahead=8, max_run=8, verifier=sv,
+                  on_apply=applied.append)
+    with pytest.raises(CatchupError, match=r"height 6: .*\(#1\)"):
+        eng.run()
+    assert len(dev.calls) == 4 and dev.collected == {0, 1, 2, 3}
+    assert (eng.cursor.verified, eng.cursor.applied) == (0, 0)
+    assert applied == [] and eng.state.last_block_height == 0
 
 
 def test_cursor_roundtrip_and_corrupt_file(tmp_path):
