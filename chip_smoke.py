@@ -251,14 +251,7 @@ def leg_gate():
     check(native.available(), "native.available() is False")
     check(interpret_mode() is False,
           "Pallas kernels would run in the interpreter")
-    import bench
-
-    floor_p50, floor_min = bench.measure_dispatch_floor()
-    return {"hostaccel": info, "pallas_interpret": False,
-            # informational: what one trivial jitted call fetched back
-            # costs on this machine (ROADMAP S2)
-            "dispatch_floor_ms_p50": round(floor_p50, 3),
-            "dispatch_floor_ms_min": round(floor_min, 3)}
+    return {"hostaccel": info, "pallas_interpret": False}
 
 
 def _kernel_batches():

@@ -70,7 +70,7 @@ class CatchupJob:
     """One block's commit to verify — field-compatible with the
     pipeline's CommitJob (duck-typed on purpose: this module must not
     import blocksync/pipeline at module load, which pulls jax into
-    host-only processes — the smoke bench and the simnet soak)."""
+    host-only processes — the simnet soak and the tier-1 tests)."""
 
     vals: object
     block_id: object
@@ -81,9 +81,9 @@ class CatchupJob:
 
 class HostCommitVerifier:
     """jax-free verify path: verify_commit_light per job on the host.
-    The explicit choice for host-only runs (smoke bench, simnet soak,
-    tier-1 tests) where importing the fused device pipeline is either
-    forbidden or pointless."""
+    The explicit choice for host-only runs (simnet soak, tier-1 tests)
+    where importing the fused device pipeline is either forbidden or
+    pointless."""
 
     def verify(self, jobs) -> List[Optional[Exception]]:
         from cometbft_tpu.types import validation as tv

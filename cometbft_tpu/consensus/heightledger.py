@@ -15,9 +15,9 @@ Design rules (FlushLedger's, restated for consensus):
     per height (allocated at height entry, mutated in place, and the
     very same list becomes the ring slot), raw ``tracing.monotonic_ns``
     ints stamped per step transition — no dicts, spans, or strings on
-    the step path. ``bench.py`` measures the per-transition cost
-    (``height_ledger_bookkeeping_us``, the cfg7-style row); budget is
-    < 10 us with tracing OFF.
+    the step path. The per-transition budget is < 10 us with tracing
+    OFF (``tests/test_zheight_smoke.py::
+    test_height_ledger_step_bookkeeping_budget``).
   * Every stamp rides :func:`tracing.monotonic_ns` — the trace clock
     when tracing is on, the simnet's virtual clock under simulation —
     so the same (seed, schedule) replays a byte-identical height

@@ -154,8 +154,8 @@ def device_breaker() -> CircuitBreaker:
 
 
 # One staging pool for THE device, mirroring the breaker: every caller
-# that packs rows for upload (verify plane flushes, blocksync chunks,
-# the bench) rotates through the same two persistent host buffers per
+# that packs rows for upload (verify plane flushes, blocksync chunks)
+# rotates through the same two persistent host buffers per
 # bucket shape, so the dispatcher can pack flush k+1 while the device
 # still verifies flush k (libs/staging.py). Device-resident caches
 # (valset/window tables) never ride this pool — donation-safe.

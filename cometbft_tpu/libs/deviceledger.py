@@ -7,7 +7,7 @@ but the device itself was a black box: compiles, device-resident
 bytes, and on-device time were invisible. Both device-plane
 post-mortems this repo has paid for were exactly that blindness: the
 round-5 multichip timeout (per-call shard_map REBUILDS — steady-state
-shapes recompiling every flush) and the r05 bench regression
+shapes recompiling every flush) and the r05 measurement regression
 (cold-compile pollution of a streaming config). This module is the
 instrument that would have caught both live.
 
@@ -17,25 +17,24 @@ Design rules (the FlushLedger discipline, restated for the device):
     ledger's per-event cost is irrelevant — but the PER-FLUSH
     attribution hooks (attr_begin/attr_end around a dispatch) ride the
     verify plane's hot path and stay under the 10 us budget
-    (``bench.device_ledger_bookkeeping_us``, asserted in tier-1).
+    (``tests/test_zdevice_smoke.py::test_device_hook_budget``).
   * ONE process-global ``jax.monitoring`` listener is the single
-    source of compile truth: bench.py's CompileWatch reads its deltas,
-    production /dump_devices serves its ring, and the two can never
-    disagree. jax listeners cannot be unregistered, so the listener
+    source of compile truth: the benchmark and chip_smoke.py read its
+    counters, production /dump_devices serves its ring, and the two can
+    never disagree. jax listeners cannot be unregistered, so the listener
     writes through the module global — ``install()`` swaps the ledger
     under it for test isolation (the incidents pattern).
   * Attribution is a thread-local context stack: the verify plane
     wraps each fused dispatch in ``attr_begin("plane.flush", seq)``,
-    mesh builders wrap their step builds, bench wraps each config —
+    mesh builders wrap their step builds —
     whoever is innermost when the compile lands names the ledger
     record's ``site``/``flush_seq``, and the accumulated ms bubbles to
     every frame so the plane can stamp ``comp_ms`` into the flush
     ledger (a post-rotation cold compile is attributed to the flush
     that paid for it).
   * STEADY-STATE flag: once the caller declares the shapes compiled
-    (the plane marks it after its second successful fused collect;
-    bench marks it after warmup), every further backend compile is
-    recorded ``steady=1`` and feeds the ``compile_storm`` incident
+    (the plane marks it after its second successful fused collect),
+    every further backend compile is recorded ``steady=1`` and feeds the ``compile_storm`` incident
     window (libs/incidents) — the round-5 regression class, caught
     live instead of by timeout.
   * The core NEVER imports jax: arming the listener requires jax to be
@@ -81,8 +80,8 @@ HBM_SLOT_BUDGET = 65536
 
 
 class CompileLedger:
-    """Bounded ring of compile events + the monotone counters bench
-    and /metrics read. Lock-guarded: jax delivers monitoring events on
+    """Bounded ring of compile events + the monotone counters the
+    benchmark and /metrics read. Lock-guarded: jax delivers monitoring events on
     whichever thread compiled (dispatcher, warmer, main)."""
 
     FIELDS = ("seq", "ts_ms", "dur_ms", "pcache_hit", "site",
@@ -214,7 +213,7 @@ def ledger_tail(n: int = 8) -> List[str]:
 # --------------------------------------------------------------------------
 # attribution: a thread-local context stack. The innermost frame names
 # the compile's site/flush_seq; accumulated ms bubbles to EVERY frame
-# so an outer scope (a bench config) sees its nested compiles too.
+# so an outer frame sees its nested compiles too.
 # --------------------------------------------------------------------------
 
 
@@ -258,8 +257,8 @@ def attr_begin_fallback(site: str) -> Optional[_Attr]:
     """Push a frame ONLY when this thread has no attribution active —
     the fallback call-site label for seams (mesh step first-calls)
     whose compiles should be named when nothing richer (the plane's
-    per-flush frame, a bench config) already claims them. Returns
-    None (and pushes nothing) when a frame is active."""
+    per-flush frame, a caller's outer frame) already claims them.
+    Returns None (and pushes nothing) when a frame is active."""
     stack = getattr(_TLS, "stack", None)
     if stack:
         return None
@@ -285,8 +284,8 @@ class attr_context:
 
 def record_compile(dur_s: float, pcache_hit: bool = False,
                    fun: str = "") -> None:
-    """The recording core (jax-free — cfg15's smoke drives it with no
-    jax in the process): attribute to this thread's innermost frame,
+    """The recording core (jax-free: tests/test_zdevice_smoke.py drives
+    it with no jax in the process): attribute to this thread's innermost frame,
     append the ledger record, and feed the compile_storm window when
     the process already declared steady state."""
     stack = getattr(_TLS, "stack", None)
@@ -304,8 +303,8 @@ def record_compile(dur_s: float, pcache_hit: bool = False,
 
 
 # --------------------------------------------------------------------------
-# the one jax.monitoring listener (bench.CompileWatch reads the same
-# counters — one compile truth for bench and production)
+# the one jax.monitoring listener (the benchmark reads the same
+# counters: one compile truth for measurement and production)
 # --------------------------------------------------------------------------
 
 _ARMED = False
@@ -401,7 +400,7 @@ def device_line() -> str:
 
 def require_accelerator() -> dict:
     """probe_device() for entry points that measure or prove the chip
-    (chip_smoke.py, bench.py full mode, tools/tpu_differential.py):
+    (chip_smoke.py, benchmarks/run.py, tools/tpu_differential.py):
     they refuse to run on the CPU backend instead of degrading."""
     info = probe_device()
     if info["platform"] == "cpu":
@@ -423,7 +422,7 @@ def require_accelerator() -> dict:
 
 def _dev_ids(value) -> List[int]:
     """Device ids a cached table occupies, duck-typed so the jax-free
-    tests (and cfg15's smoke) attribute fake tables through a bare
+    tests attribute fake tables through a bare
     ``devs`` attribute: explicit ``devs`` wins; else the jax arrays'
     own placement (``tab.devices()``); else n_dev sequential; else
     device 0."""
@@ -461,8 +460,7 @@ def _add(fam: Dict, dev, nbytes: int, slots: int) -> None:
 def residency(tables=None, shards=None) -> Dict[str, Dict]:
     """{family: {dev: {bytes, slots}}} over everything device- or
     staging-resident right now. ``tables``/``shards`` override the
-    global cache snapshots (the jax-free tests and cfg15's smoke pass
-    fake entries; None samples ops/table_cache). ``dev`` keys are chip
+    global cache snapshots (the jax-free tests pass fake entries; None samples ops/table_cache). ``dev`` keys are chip
     ids (ints) or ``"host"`` for pinned host staging. Families:
 
       * ``valset_tables`` — single-device window tables (ops/table_cache
@@ -633,7 +631,8 @@ def _pct(sorted_vals: List[float], q: float) -> float:
 class CostSurfaces:
     """Bounded per-(family, rows_bucket, n_dev) flush-cost cells. The
     observe path is the plane's per-flush hook (always on, inside the
-    10 us budget bench.cost_hooks_bookkeeping_us asserts); percentiles
+    10 us budget tests/test_zdevice_smoke.py::test_cost_hook_budget
+    asserts); percentiles
     and marginal-cost fits happen at READ time only."""
 
     __slots__ = ("_cells", "_lock", "observed", "dropped_cells")
@@ -717,7 +716,7 @@ def surfaces() -> CostSurfaces:
 
 
 def install_surfaces(s: CostSurfaces) -> CostSurfaces:
-    """Swap the global recorder (tests/bench isolation); returns the
+    """Swap the global recorder (test isolation); returns the
     previous one — the install() pattern, applied to cost cells."""
     global _SURFACES
     old = _SURFACES
@@ -729,8 +728,8 @@ def observe_flush(path: str, stamp: str, rows: int, n_dev: int,
                   comp_ms: float, h2d_ms: float, dev_ms: float) -> None:
     """The plane's per-flush seam: derive the jit-family label from the
     flush path + stamp origin and record one observation. Kept module-
-    level (not a method call off the plane) so bench and the jax-free
-    smoke drive the identical code the hot path runs."""
+    level (not a method call off the plane) so the jax-free tests
+    drive the identical code the hot path runs."""
     fam = path + ":stamped" if stamp == "device" else path
     _SURFACES.observe(fam, rows, max(1, int(n_dev)),
                       comp_ms, h2d_ms, dev_ms)

@@ -180,7 +180,7 @@ class IncidentRecorder:
         short burst (a few rebuilds inside one flush), and a stale
         poke-time anchor would expire-and-discard exactly that burst.
         Compiles land on whichever thread compiled (dispatcher,
-        warmer, bench), so the same lock discipline applies."""
+        warmer, main), so the same lock discipline applies."""
         t = tracing.monotonic_ns()
         with self._lock:
             start, count = self._comp_win

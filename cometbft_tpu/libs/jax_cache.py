@@ -1,7 +1,8 @@
 """Where the persistent JAX compilation cache lives.
 
-Every entry point that compiles (chip_smoke.py, bench.py, `python -m
-cometbft_tpu start`, tools/tpu_differential.py, tests/conftest.py)
+Every entry point that compiles (chip_smoke.py, benchmarks/run.py,
+`python -m cometbft_tpu start`, tools/tpu_differential.py,
+tests/conftest.py)
 calls this one helper before its first jit, so they all share one
 cache and none runs without it: a cold Mosaic compile of one verify
 kernel costs tens of seconds, and a fresh process would otherwise pay

@@ -3,7 +3,7 @@
 The plane's lane waits, the flush ledger's stage summary, and the
 loadtime generator all summarize bounded latency windows; a single
 picker keeps their rank rounding identical, so a soak-test p99
-assertion and a cfg9 report can never disagree about what "p99"
+assertion and a loadtime report can never disagree about what "p99"
 means.
 """
 from __future__ import annotations

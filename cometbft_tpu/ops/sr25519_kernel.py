@@ -265,7 +265,7 @@ def batch_challenges(msgs, pubs, r_encs) -> np.ndarray:
         if use_native and ln > 0:
             # whole transcripts in one C call (the numpy BatchStrobe
             # below paid ~70 ms of python/numpy op dispatch per 5k-row
-            # commit — the round-4 cfg3 host bottleneck); BatchStrobe
+            # commit — the round-4 mixed-commit host bottleneck); BatchStrobe
             # stays as the differential reference (tests/test_native)
             s = prefix.strobe
             ch = native.sr25519_batch_challenges(
@@ -308,7 +308,7 @@ def pack_batch_sr(pubkeys, msgs, sigs, pad_to=None,
     )
     # one vectorized pass over the whole batch (the per-row bigint loop
     # with its 64-step nibble split was the dominant host cost of the
-    # mixed 10k bench config — ~0.5 s for 5k rows)
+    # mixed 10k commit — ~0.5 s for 5k rows)
     lenok = np.array(
         [len(pubkeys[i]) == 32 and len(sigs[i]) == 64
          and bool(sigs[i][63] & 0x80) for i in range(n)],

@@ -17,10 +17,9 @@ here so that:
     that;
   * the next-epoch table warmer (verifyplane/warmer.py) can mark the
     keys it pre-built and the first post-rotation lookup attributes
-    its hit honestly (``warmed_hits``) — the cold-vs-warmed evidence
-    cfg13 measures;
+    its hit honestly (``warmed_hits``) — the cold-vs-warmed evidence;
   * none of it imports jax, so the bounding/eviction/warm-attribution
-    logic is testable (and benchable: ``cfg13_smoke``) on the 1-core
+    logic is testable (tests/test_warmer.py) on the 1-core
     tier-1 host without a device or a minutes-long interpret compile.
 
 Thread-safety: callers synchronize on :data:`LOCK` (ed25519_cached
@@ -67,8 +66,8 @@ STATS = {"hits": 0, "misses": 0, "key_memo_hits": 0,
 def default_size(value) -> int:
     """Best-effort byte size of a cached table: the device arrays'
     nbytes plus the host-side pubkey/power copies. Duck-typed so the
-    jax-free tests (and cfg13_smoke) can size fake tables through a
-    bare ``nbytes`` attribute."""
+    jax-free tests can size fake tables through a bare ``nbytes``
+    attribute."""
     n = getattr(value, "nbytes", None)
     if isinstance(n, (int, float)):
         return int(n)

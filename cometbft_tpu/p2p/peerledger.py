@@ -17,7 +17,7 @@ Design rules (the FlushLedger discipline, restated for p2p):
     routines) that BECOMES the drop-ring slot when the peer goes away —
     no per-message allocation beyond a first-touch channel slot. The
     per-message budget is < 10 us with tracing off
-    (``bench.peer_ledger_bookkeeping_us``, asserted in tier-1).
+    (``tests/test_zpeer_smoke.py::test_peer_ledger_message_budget``).
   * Every stamp rides :func:`tracing.monotonic_ns` — the trace clock
     when tracing is on, the simnet's virtual clock under simulation —
     so the same (seed, schedule) replays a byte-identical peer ledger

@@ -51,10 +51,10 @@ _STEP_CACHE: dict = {}
 
 # Memoization regression guard (the round-5 MULTICHIP timeout was
 # per-call shard_map rebuilds): every builder counts its probe, so
-# tests — and the bench's multichip smoke — can assert steady-state
-# calls HIT instead of silently re-tracing. The counters are mutated
-# from the verify plane's dispatcher thread AND from test/bench/scrape
-# probes concurrently, so increments ride one module lock — an
+# tests can assert steady-state calls HIT instead of silently
+# re-tracing. The counters are mutated from the verify plane's
+# dispatcher thread AND from test/scrape probes concurrently, so
+# increments ride one module lock — an
 # unguarded += loses counts exactly when several threads flush at once
 # (the same race the plane's sheds counter fixed in PR 7).
 _CACHE_STATS = {"hits": 0, "misses": 0}
@@ -80,8 +80,8 @@ def _cache_put(key, fn):
     """Memoize a freshly-built step, wrapped so its FIRST invocation
     attributes the lazy jit trace/compile to this builder in the
     device observatory's compile ledger (libs/deviceledger) — unless
-    a richer frame (the verify plane's per-flush attribution, a bench
-    config) is already active on the calling thread, in which case
+    a richer frame (the verify plane's per-flush attribution, a
+    caller's own) is already active on the calling thread, in which case
     that frame keeps the credit. After the first call the wrapper is
     a list check: steady-state dispatch cost is untouched."""
     from cometbft_tpu.libs import deviceledger

@@ -26,7 +26,7 @@ Snapshot generation rides :class:`SnapshotArchive`: any state blob
 (the app's committed state, or a document assembled from the
 block/state stores) becomes a chunked, merkle-rooted, servable
 snapshot. The archive is store-agnostic on purpose — the persistent
-soak app and bench both feed it directly.
+soak app feeds it directly.
 """
 from __future__ import annotations
 

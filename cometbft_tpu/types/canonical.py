@@ -256,8 +256,8 @@ class DeltaRows:
     def expand(self) -> SignRows:
         """Numpy reference expansion FROM THE STAGED WORDS — not from
         the original int64s — so byte-equality against patch_rows
-        proves the int32 delta staging round-trips losslessly (the
-        cfg19_smoke acceptance check, no jax required)."""
+        proves the int32 delta staging round-trips losslessly (no jax
+        required: tests/test_sign_template.py)."""
         w = self.ts_words()
         secs = (w[:, 0].view(np.uint32).astype(np.uint64)
                 | (w[:, 1].astype(np.int64).view(np.uint64)

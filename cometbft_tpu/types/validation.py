@@ -356,7 +356,7 @@ def device_batch_fn(use_pallas: Optional[bool] = None,
             # the EXACT pubkey list — callers must present a stable
             # list (the full valset in order) or every call pays a
             # table rebuild. The batch paths that guarantee stability
-            # (blocksync StreamVerifier, the bench) use it; the
+            # (blocksync StreamVerifier) use it; the
             # per-commit subset lists verify_commit_light produces
             # would thrash the LRU, so the default stays general.
             from cometbft_tpu.ops import ed25519_cached as ec

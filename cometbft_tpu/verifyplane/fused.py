@@ -80,49 +80,6 @@ def set_device_stamping(on: bool) -> None:
     DEVICE_STAMP = bool(on)
 
 
-# jax-free replicas of the packed-row layout constants, for the staging
-# byte-budget arithmetic below (cfg19_smoke runs with no jax import;
-# tests cross-check these against ed25519_cached.V_THRESH /
-# ed25519_kernel.TALLY_LIMBS in a jax-enabled process)
-_V_THRESH_REPLICA = 27
-_TALLY_LIMBS_REPLICA = 6
-
-
-def delta_slot_specs(B: int) -> dict:
-    """name -> (shape, itemsize) of the staging slots a DEVICE-STAMPED
-    flush of B rows occupies: raw signatures, the (secs_lo, secs_hi,
-    nanos) timestamp words, and the packed live/counted/template/commit
-    flags. Pure arithmetic — the cfg19_smoke byte budget."""
-    return {"fused.dsig": ((B, 64), 1),
-            "fused.dts": ((B, 3), 4),
-            "fused.dflags": ((B,), 4)}
-
-
-def legacy_slot_specs(B: int, n_commits: int = 1) -> dict:
-    """name -> (shape, itemsize) of the staging slots a HOST-PACKED
-    flush of B rows occupies (the scatter buffers plus the packed rows
-    the device actually reads)."""
-    t_rows = max(1, -(-(n_commits * _TALLY_LIMBS_REPLICA) // B))
-    return {"fused.ry": ((B, 20), 4),
-            "fused.rsign": ((B,), 4),
-            "fused.sdig": ((B, 64), 4),
-            "fused.hdig": ((B, 64), 4),
-            "fused.precheck": ((B,), 1),
-            "fused.counted": ((B,), 1),
-            "fused.cid": ((B,), 4),
-            "fused.rows": ((_V_THRESH_REPLICA + t_rows, B), 4)}
-
-
-def specs_bytes(specs: dict) -> int:
-    total = 0
-    for shape, itemsize in specs.values():
-        n = itemsize
-        for d in shape:
-            n *= d
-        total += n
-    return total
-
-
 class _Plan:
     """A fully host-side staged fused flush: everything up to (but not
     including) the device dispatch. Splitting plan from execution lets
@@ -213,8 +170,7 @@ def shard_positions(vidx, strides, m_shard: int,
     (v mod m_shard)`` where d = v // m_shard owns the validator's table
     shard and B_loc = n_strides*m_shard is one device's slice width.
     With one device m_shard is the whole padded valset and this
-    degenerates to the classic ``s*M + v``. Pure numpy — cfg11's smoke
-    exercises it with no jax in the process."""
+    degenerates to the classic ``s*M + v``. Pure numpy, no jax needed."""
     v = np.asarray(vidx, np.int64)
     s = np.asarray(strides, np.int64)
     b_loc = n_strides * m_shard
@@ -450,7 +406,7 @@ def plan_fused(batch, pool=None, mesh=None, half=None,
         # device-stamped delta staging: ship 80 B/row — raw signature,
         # (secs_lo, secs_hi, nanos) words, packed flags — and let the
         # device prologue rebuild the packed rows next to the resident
-        # template. Slot layout mirrors delta_slot_specs; the pool's
+        # template. Three slots (fused.dsig / .dts / .dflags); the pool's
         # zero fill makes unoccupied lanes live=0, which the prologue
         # expands to the same all-zero columns host packing pads with.
         sites, site_ids = stamp
@@ -543,8 +499,8 @@ def plan_fused(batch, pool=None, mesh=None, half=None,
     plan.warm = False
     # rows-x-cost utilization: the fraction of the staged device pass
     # doing real work (n live rows over the B padded slots the kernel
-    # sweeps across the whole fan-out) — the ledger's util column, so
-    # cfg11/cfg12 report how much of the mesh a flush actually used
+    # sweeps across the whole fan-out) — the ledger's util column: how
+    # much of the mesh a flush actually used
     plan.util = round(n / B, 4) if B else 0.0
     return plan
 

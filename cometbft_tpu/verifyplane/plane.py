@@ -212,8 +212,8 @@ def split_device_columns(tenants: tuple, rows: int, comp_ms, h2d_ms,
     multi-tenant batch splits row-proportionally with the LAST tenant
     taking the integer residual, so the shares always sum back to the
     flush totals with zero drift (the HBM _split_exact discipline
-    applied to time). Pure arithmetic — cfg20's smoke drives it with
-    no jax in the process."""
+    applied to time). Pure arithmetic, no jax needed
+    (tests/test_tenants.py)."""
     comp_us = ms_to_us(comp_ms)
     h2d_us = ms_to_us(h2d_ms)
     dev_us = ms_to_us(dev_ms)
@@ -224,8 +224,8 @@ def split_device_columns(tenants: tuple, rows: int, comp_ms, h2d_ms,
         chain = tenants[0][0]
         return SPLIT_EXACT, [(chain, comp_us, h2d_us, dev_us, dbytes)]
     # unrolled columns (no per-share tuple comprehensions): this runs
-    # inside the per-flush hook budget bench.cost_hooks_bookkeeping_us
-    # asserts, so the constant factor matters
+    # inside the per-flush hook budget (test_cost_hook_budget in
+    # tests/test_zdevice_smoke.py), so the constant factor matters
     out = []
     c_acc = h_acc = d_acc = b_acc = 0
     last = len(tenants) - 1
@@ -1574,7 +1574,7 @@ class VerifyPlane:
         # The scratch list mutates in place and becomes the ring slot.
         # Differencing needs every stamp from one clock domain: a
         # tracing enable/disable or simnet clock install/restore while
-        # the flush was airborne (test/bench teardown) would difference
+        # the flush was airborne (test teardown) would difference
         # a virtual-epoch ns against a perf_counter ns — same hazard
         # queued_ms guards with clock_gen at pack time. The stage
         # timings are recorded as 0.0 then; the record itself stays.
@@ -1600,8 +1600,8 @@ class VerifyPlane:
         charge the flush's device-time columns to its tenants under
         the recorded split rule, and feed the device ledger's cost
         surfaces one observation. Always on — the whole hook stays
-        under the 10 us budget (bench.cost_hooks_bookkeeping_us,
-        asserted in tier-1), so there is no enable knob to forget."""
+        under the 10 us budget (tests/test_zdevice_smoke.py::
+        test_cost_hook_budget), so there is no enable knob to forget."""
         tens = led[_L_TEN]
         if tens:
             rule, shares = split_device_columns(
@@ -2079,7 +2079,7 @@ class VerifyPlane:
     def lane_wait_stats(self) -> dict:
         """Per-lane submit-to-result wall latency percentiles over the
         recent bounded window (real clock — powers the soak harness's
-        p99-under-flood assertion and cfg9's report)."""
+        p99-under-flood assertion)."""
         from cometbft_tpu.libs.quantiles import wait_summary_ms
 
         return {lane: wait_summary_ms(waits)

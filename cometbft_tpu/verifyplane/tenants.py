@@ -260,7 +260,7 @@ class TenantRegistry:
         """Batched note_device over one flush's split shares — ONE lock
         acquisition for the whole fused batch. This is the plane's
         _charge_flush path, bound by the per-flush hook budget
-        (bench.cost_hooks_bookkeeping_us, tier-1-asserted < 10 us)."""
+        (tests/test_zdevice_smoke.py::test_cost_hook_budget, < 10 us)."""
         with self._lock:
             for chain_id, comp_us, h2d_us, dev_us, delta_bytes in shares:
                 t = self._touch(chain_id)
@@ -273,7 +273,7 @@ class TenantRegistry:
         """Registry-wide device-time totals, live + retired, in the
         accumulators' native integer microseconds. The conservation
         invariant: these equal the flush ledger's column sums over the
-        same window (reconcile_device asserts it, cfg20 embeds it)."""
+        same window (reconcile_device asserts it)."""
         with self._lock:
             tot = {"comp_us": self.retired["comp_us"],
                    "h2d_us": self.retired["h2d_us"],
@@ -488,8 +488,7 @@ def reconcile_device(records, registry: TenantRegistry) -> dict:
     the registry's live+retired per-tenant accumulators. While the
     ledger ring still holds every charged flush (and no other plane
     fed the registry), every drift is EXACTLY zero — integer us, no
-    tolerance band. cfg20 embeds this; a unit test drives it across
-    evict()/retirement."""
+    tolerance band. A unit test drives it across evict()/retirement."""
     led = {"comp_us": 0, "h2d_us": 0, "device_us": 0, "delta_bytes": 0}
     for r in records:
         if not r.get("tenants"):

@@ -36,9 +36,8 @@ load-bearing):
     supersede an unstarted older request instead of queueing stale
     epochs.
 
-No jax import at module level: the warmer object (and everything
-cfg13_smoke / the tier-1 tests drive) is host-only until a real build
-runs.
+No jax import at module level: the warmer object (and everything the
+tier-1 tests drive) is host-only until a real build runs.
 """
 from __future__ import annotations
 
@@ -61,8 +60,8 @@ fp.register("warmer.build",
 class TableWarmer:
     """Background builder of next-epoch valset tables.
 
-    `build_fn(pubs, powers)` overrides the real device build (tests,
-    cfg13_smoke); the default builds through ed25519_cached into the
+    `build_fn(pubs, powers)` overrides the real device build (tests);
+    the default builds through ed25519_cached into the
     shared bounded caches. `mesh_fn()` resolves the verify plane's
     flush mesh (default: the global plane's already-resolved mesh) so
     a multichip node warms its sharded tables too. `breaker` defaults
@@ -321,8 +320,8 @@ class TableWarmer:
         if consensus already paid the cold build inline (the rotation
         landed before the warm ran), the lookup here is a hit and
         marking it would falsely credit the warmer for a stall that
-        happened (warmed_hits is the honest-signal counter cfg13 and
-        /metrics attribution rely on). Best-effort: when a dispatcher
+        happened (warmed_hits is the honest-signal counter /metrics
+        attribution relies on). Best-effort: when a dispatcher
         flush and this warm race the SAME cold build concurrently
         (both miss, both build), the warmer's miss still marks — a
         single-flight build lock isn't worth buying for a stats
@@ -418,8 +417,8 @@ class TableWarmer:
     # -- observability -----------------------------------------------------
 
     def wait_idle(self, timeout: float = 10.0) -> bool:
-        """Block until no request is pending or building (tests and the
-        cfg13 bench use this to measure the warmed path honestly)."""
+        """Block until no request is pending or building (tests use
+        this to reach the warmed path deterministically)."""
         deadline = time.monotonic() + timeout
         with self._cv:
             while self._req is not None or self._tmpl_req is not None \

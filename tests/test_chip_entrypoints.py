@@ -1,7 +1,7 @@
 """The chip entry points refuse a CPU, and the compile cache is placed
 from outside.
 
-chip_smoke.py and bench.py full mode prove and measure the TPU; with no
+chip_smoke.py proves the TPU and benchmarks/run.py measures it; with no
 accelerator they must fail before doing anything, never degrade to a
 run that passes without touching the chip. The persistent compile cache
 follows JAX_COMPILATION_CACHE_DIR where it is set and otherwise sits at
@@ -10,8 +10,6 @@ one fixed path inside the checkout (libs/jax_cache.py).
 import os
 import subprocess
 import sys
-
-import bench
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -66,17 +64,17 @@ def test_chip_smoke_refuses_a_cpu():
     assert "no TPU" in r.stderr and "platform is 'cpu'" in r.stderr
 
 
-def test_bench_full_mode_refuses_a_cpu(monkeypatch, capsys):
-    """Full mode exits non-zero before any cell runs; nothing is
-    written under a device cell's name. (--smoke is the host-only mode:
-    tests/test_zbench_smoke.py.)"""
-    ran = []
-    monkeypatch.setattr(bench, "FULL_CONFIGS",
-                        [("cfg_sentinel", lambda: ran.append(1))])
-    assert bench.main([]) == 2
-    out = capsys.readouterr()
-    assert not ran and out.out == ""
-    assert "no accelerator" in out.err
+def test_benchmark_run_refuses_a_cpu():
+    """JAX_PLATFORMS=cpu without --rehearse: the cell prepares, finds no
+    TPU, exits 2 with nothing on stdout (no line a reader could take
+    for a measurement) and says so on stderr. qa200.bursts is the
+    cheapest cell to prepare (175 validators)."""
+    r = _run(["benchmarks/run.py", "--workload", "qa200.bursts",
+              "--seed", "0", "--seconds", "1"])
+    assert r.returncode == 2
+    assert r.stdout == ""
+    assert "nothing measured" in r.stderr
+    assert "platform is 'cpu'" in r.stderr
 
 
 def test_chip_smoke_last_line_has_the_contract_keys_only():

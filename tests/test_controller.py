@@ -2,9 +2,9 @@
 cooldowns, clamp bounds, the structurally-off-limits CONSENSUS lane,
 the bounded decision ledger, and the module-global dump surface.
 
-All host-only: the controller is driven against fakes here (the live
-plane/admission integration is covered by test_verify_plane's setter
-tests and the simnet scenarios in test_soak)."""
+All host-only: the controller is driven against fakes here, once
+against the real plane and admission setters, and on a live network in
+the simnet scenarios of test_soak."""
 import pytest
 
 from cometbft_tpu.libs import controller as cp
@@ -163,6 +163,54 @@ def test_relax_never_passes_base():
         c.poke(h, 0)
     assert c.actuator_values() == pytest.approx(base)
     assert c.dump()["state"]["decisions_total"] == 0
+
+
+def test_closed_loop_on_real_actuators_spares_the_consensus_window():
+    """The same loop against the real setters (a host VerifyPlane and
+    the mempool's AdmissionController): three peak -> trough cycles
+    widen the bulk window and lower the watermark at each peak, walk
+    both back to base at each trough, keep every decision inside its
+    bounds, and never move the CONSENSUS lane's window."""
+    from cometbft_tpu.mempool.admission import AdmissionController
+    from cometbft_tpu.verifyplane.plane import VerifyPlane
+
+    led = FakeLedger()
+    fill = [0.1]
+    plane = VerifyPlane(window_ms=0.5, use_device=False)
+    adm = AdmissionController(high_watermark=0.9, low_watermark=0.7,
+                              fill_fn=lambda: fill[0])
+    c = cp.Controller(slo_commit_p99_ms=100.0, decision_interval=1,
+                      cooldown=0)
+    old_global, old_last = cp._GLOBAL, cp._LAST
+    try:
+        c.attach(plane=plane, admission=adm, height_ledger=led,
+                 bounds={cp.ACT_BULK_WINDOW: (1.0, 8.0),
+                         cp.ACT_GATEWAY_WINDOW: (0.5, 4.0),
+                         cp.ACT_ADMISSION: (0.3, 0.9)})
+        consensus_window, base_bulk = plane.window, plane.bulk_window
+        height = 0
+        for _ in range(3):
+            led.p99, fill[0] = 500.0, 0.8    # peak: 5x over the SLO
+            for _ in range(8):
+                height += 1
+                c.poke(height, 0)
+            assert plane.bulk_window > base_bulk
+            assert adm.high_watermark < 0.9
+            led.p99, fill[0] = 10.0, 0.1     # trough: headroom
+            for _ in range(16):
+                height += 1
+                c.poke(height, 0)
+            assert plane.bulk_window == pytest.approx(base_bulk)
+            assert adm.high_watermark == 0.9
+        assert plane.window == consensus_window
+        dump = c.dump()
+        assert dump["state"]["decisions_total"] >= 6
+        for d in dump["decisions"]:
+            act = dump["actuators"][d["actuator"]]
+            assert act["min"] - 1e-9 <= d["new"] <= act["max"] + 1e-9, d
+    finally:
+        cp._GLOBAL, cp._LAST = old_global, old_last
+        plane.stop()
 
 
 def test_fill_pressure_triggers_before_shed_storm():

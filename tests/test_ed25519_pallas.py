@@ -120,8 +120,8 @@ def test_pad_to_tile():
 def test_tally_multi_tile_with_invalid_and_quorum_miss():
     """verify_tally_rows across a >2-tile grid: invalid rows excluded
     from the tally, quorum-miss detected (round-2 verdict item 5 at a
-    CPU-affordable 4-tile shape; the 10k shape runs on TPU below and in
-    bench.py every round)."""
+    CPU-affordable 4-tile shape; the 10k shape runs on TPU below, in
+    chip_smoke.py's commit leg and under the benchmark's `correct`)."""
     n = 4 * kp.B_TILE  # 512 rows, 4 grid steps
     pubs, msgs, sigs = make_sigs(64)
     pubs, msgs, sigs = pubs * 8, msgs * 8, sigs * 8
@@ -154,7 +154,8 @@ def test_tally_multi_tile_with_invalid_and_quorum_miss():
 @pytest.mark.skipif(
     not os.environ.get("CBT_TEST_ON_TPU"),
     reason="10,240-row grid is TPU-scale; CPU interpret takes minutes "
-           "(bench.py asserts this shape on the real chip every round)",
+           "(on the chip: chip_smoke.py's commit leg and the benchmark's "
+           "`correct` in valset-10k.commit check this shape)",
 )
 def test_tally_10k_shape_vs_xla():
     n = 10_240

@@ -74,7 +74,7 @@ def test_step_cache_hit_counters():
 
 def test_cache_stats_exact_under_two_threads():
     """ISSUE 10 satellite: the memo counters are mutated by the verify
-    plane's dispatcher thread AND test/bench/scrape probes concurrently
+    plane's dispatcher thread AND test/scrape probes concurrently
     — increments ride one module lock, so two hammering threads land
     EXACTLY 2N hits (an unguarded += loses counts under preemption,
     the same race the sheds counter fixed in PR 7)."""

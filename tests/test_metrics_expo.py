@@ -295,7 +295,7 @@ def test_scrape_staging_stats_move_under_flush_traffic():
         # flushes would: slots misses to warm a fresh shape, then hits
         for _ in range(5):
             plane._staging.get("expo.flush", (8, 4), "int32")
-        # and the process-global pool (blocksync/bench path)
+        # and the process-global pool (blocksync path)
         cbatch.staging_pool().get("expo.flush2", (2, 2), "int32")
         after, res_after = pool_kinds(m.expose_text())
         # the private pool's 2 slots were allocation misses, the other

@@ -246,7 +246,7 @@ def test_stamp_verify_delta_matches_host_pack_real_table():
 def test_stamp_rows_device_matches_host_pack_wide():
     """Slow sibling: every FUZZ_SECS x FUZZ_NANOS cross product, two
     templates in one flush (tmpl_id bits live), nil BlockID — the
-    multi-site stamp path cfg19 drives at 10k rows."""
+    multi-site stamp path a 10k-row flush takes."""
     pytest.importorskip("jax")
     from cometbft_tpu.ops import ed25519_cached as ec
     from cometbft_tpu.ops import ed25519_kernel as ek
@@ -500,8 +500,8 @@ def test_staging_pool_concurrent_flushes():
     The rotation contract is one writer per KEY (each dispatcher/
     pipeline owns its buffer names), but nothing serializes DIFFERENT
     keys — the verify-plane dispatcher, blocksync's private pool
-    pattern, and bench all hammer one process-global pool from their
-    own threads. Each thread here rotates its own key under load and
+    pattern, and crypto/batch all hammer one process-global pool from
+    their own threads. Each thread here rotates its own key under load and
     checks its buffer still holds its own pattern after every get
     (cross-key aliasing would corrupt it); the lock-protected counters
     must come out EXACT, not approximately."""

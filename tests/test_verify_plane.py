@@ -583,6 +583,112 @@ def test_deck_stats_and_ledger_columns_on_host_plane():
     assert st["halves"] == 0
 
 
+def test_deck_ready_first_picker():
+    """The landing picker takes the first flight whose probe says its
+    results can be fetched (a later flight lands before an earlier one
+    still flying: no head-of-line blocking) and None when no flight has
+    a probe or none is ready, which callers read as land-the-oldest."""
+    from cometbft_tpu.verifyplane.plane import _ready_index
+
+    class Flight:
+        def __init__(self, ready):
+            self.ready = ready
+
+    assert _ready_index([Flight(lambda: False), Flight(lambda: True),
+                         Flight(lambda: True)]) == 1
+    assert _ready_index([Flight(None), Flight(lambda: False)]) is None
+    assert _ready_index([]) is None
+
+
+def _ledger_record(**cols):
+    """A FlushLedger ring slot as the plane writes one: FIELDS order,
+    then the four internal stamps (t0, t_packed, clock gen, first
+    ready) that never reach a dump."""
+    from cometbft_tpu.verifyplane.plane import FlushLedger
+
+    base = dict.fromkeys(FlushLedger.FIELDS, 0)
+    base.update(path="fused", breaker="closed", tenants=(), **cols)
+    return [base[f] for f in FlushLedger.FIELDS] + [0, 0, 0, 0]
+
+
+def test_flush_ledger_summary_stamp_attribution():
+    """The summary's stamp block counts device-stamped and host-packed
+    flushes apart and sums the delta bytes the stamped ones staged."""
+    from cometbft_tpu.verifyplane.plane import (
+        STAMP_DEVICE,
+        STAMP_HOST,
+        FlushLedger,
+    )
+
+    led = FlushLedger()
+    led.record(_ledger_record(seq=1, rows=10240, stamp=STAMP_DEVICE,
+                              delta_bytes=819200))
+    led.record(_ledger_record(seq=2, rows=10240, stamp=STAMP_HOST))
+    led.record(_ledger_record(seq=3, rows=64, stamp=STAMP_DEVICE,
+                              delta_bytes=5120))
+    assert led.summary()["stamp"] == {"device": 2, "host": 1,
+                                      "delta_bytes": 824320}
+    assert [r["stamp"] for r in led.records()] == [
+        STAMP_DEVICE, STAMP_HOST, STAMP_DEVICE]
+
+
+def _disabled_flush_bookkeeping_us(k):
+    """One replay of the always-on accounting _stage/_finish_flight run
+    per flush with tracing off (four clock reads, the one FIELDS-ordered
+    scratch list that becomes the ring slot, the in-place stage fills,
+    the ring append), then of one disabled tracing.span() behind the
+    guard every flush-path hook uses. Returns (ledger us per flush,
+    span us per call)."""
+    from cometbft_tpu.libs import tracing
+    from cometbft_tpu.verifyplane.plane import (
+        PATH_HOST,
+        SPLIT_EXACT,
+        STAMP_HOST,
+        FlushLedger,
+    )
+
+    assert not tracing.enabled(), "measure the DISABLED path"
+    led = FlushLedger()
+    t_led = time.perf_counter()
+    for i in range(k):
+        t0 = tracing.monotonic_ns()
+        gen = tracing.clock_gen()
+        rec = [i, round(t0 / 1e6, 3), 64, 4,
+               round((t0 - t0) / 1e6, 3), 0.0, 0.0, 0.0, 0.0, 0,
+               PATH_HOST, STAMP_HOST, "closed", 0, 0, 64, 0, 0, 0, 1,
+               1, 0, 0, 0.0, 0.0, 0, 0.0, 0.0, (), SPLIT_EXACT,
+               t0, t0, gen, 0]
+        t1 = tracing.monotonic_ns()
+        rec[5] = round((t1 - t0) / 1e6, 3)
+        t2 = tracing.monotonic_ns()
+        rec[7] = round((t2 - t1) / 1e6, 3)
+        t3 = tracing.monotonic_ns()
+        rec[8] = round((t3 - t2) / 1e6, 3)
+        led.record(rec)
+    ledger_us = (time.perf_counter() - t_led) * 1e6 / k
+    assert len(rec) == len(FlushLedger.FIELDS) + 4, "replay drifted"
+    t_span = time.perf_counter()
+    for _ in range(k):
+        if tracing.enabled():
+            pass
+        with tracing.span("budget.noop", cat="budget"):
+            pass
+    return ledger_us, (time.perf_counter() - t_span) * 1e6 / k
+
+
+def test_disabled_flush_path_bookkeeping():
+    """What every flush pays with tracing off: the ledger's bookkeeping
+    (some 6 us on this sandbox) stays under 50 us, small beside a
+    flush that takes a millisecond or more, and one disabled span under
+    10 us. Best of 3: one reading on a shared host measures the
+    neighbours."""
+    rows = [_disabled_flush_bookkeeping_us(5_000) for _ in range(3)]
+    best_ledger = min(r[0] for r in rows)
+    best_span = min(r[1] for r in rows)
+    assert 0 < best_ledger < 50.0, f"flush ledger {best_ledger} us"
+    assert 0 < best_span < 10.0, f"disabled span {best_span} us"
+
+
 def test_ledger_n_dev_column_on_host_flushes(plane):
     """Every flush record carries the device fan-out column; host/
     single-device flushes stamp n_dev=1 and the summary's shard block

@@ -158,14 +158,6 @@ def test_catchup_report_diff_detects_synthetic_regression(
     assert "100 blocks applied" in out
     assert "valset" in out and "boundaries" in out.replace(
         "boundaries,", "boundaries")
-    # bench --json-out evidence files are a first-class input shape
-    wrapped = {"results": {"cfg18_smoke": {
-        "metric": "x", "value": 1.0,
-        "extra": {"catchup_dump": dump_a}}}}
-    w_path = tmp_path / "bench.json"
-    w_path.write_text(json.dumps(wrapped))
-    loaded = catchup_report.load_catchup(str(w_path))
-    assert loaded["counters"]["flushes"] == 10
     junk = tmp_path / "junk.json"
     junk.write_text(json.dumps({"nope": 1}))
     with pytest.raises(ValueError):

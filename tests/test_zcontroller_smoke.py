@@ -216,15 +216,6 @@ def test_controller_report_diff_detects_synthetic_regression(
     out = capsys.readouterr().out
     assert "admission_high_watermark" in out
     assert "decision timeline" in out
-    # bench --json-out evidence files are a first-class input shape
-    wrapped = {"results": {"cfg16_smoke": {
-        "metric": "x", "value": 1.0,
-        "extra": {"controller_dump": dump}}}}
-    w_path = tmp_path / "bench.json"
-    w_path.write_text(json.dumps(wrapped))
-    loaded = controller_report.load_controller(str(w_path))
-    assert loaded["state"]["decisions_total"] \
-        == dump["state"]["decisions_total"]
     junk = tmp_path / "junk.json"
     junk.write_text(json.dumps({"nope": 1}))
     with pytest.raises(ValueError):

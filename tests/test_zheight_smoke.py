@@ -13,6 +13,7 @@ import copy
 import json
 import sys
 import threading
+import time
 import urllib.request
 
 import pytest
@@ -540,25 +541,52 @@ def test_late_signer_split_on_live_network():
     assert tops and all("net_ms" in t and "sign_ms" in t for t in tops)
 
 
+def _height_ledger_bookkeeping_us(k):
+    """One replay of the exact per-transition sequence _set_step drives
+    with tracing off (on_step: clock read + step-slot lookup + in-place
+    stores, plus the once-per-height fsync anchor check), over a full
+    open -> steps -> next-height cycle so the ring append amortizes in
+    like production. Returns (us per transition, allocated blocks per
+    steady-state transition within one height)."""
+    from cometbft_tpu.consensus.heightledger import HeightLedger
+    from cometbft_tpu.libs import tracing
+
+    assert not tracing.enabled(), "measure the DISABLED path"
+    led = HeightLedger()
+    steps = (2, 3, 4, 6, 8)  # new_round/propose/prevote/precommit/commit
+    t0 = time.perf_counter()
+    h = 0
+    for i in range(k):
+        if i % len(steps) == 0:
+            h += 1
+        led.on_step(h, 0, steps[i % len(steps)])
+        led.note_wal_fsync_base(1234)
+    step_us = (time.perf_counter() - t0) * 1e6 / k
+    # no height open, no ring append: the scratch list absorbs every
+    # stamp in place (the clock's ints churn through the freelist)
+    led.on_step(h + 1, 0, 2)  # open once, off the measured window
+    blocks0 = sys.getallocatedblocks()
+    for i in range(1024):
+        led.on_step(h + 1, 0, steps[i % len(steps)])
+    return step_us, (sys.getallocatedblocks() - blocks0) / 1024
+
+
 def test_height_ledger_step_bookkeeping_budget():
     """ISSUE 13 acceptance: < 10 us per step transition with tracing
     OFF (best of 3 to dodge 1-core scheduler spikes; the typical
     number is < 1 us)."""
-    import bench
-
-    rows = [bench.height_ledger_bookkeeping_us(k=5_000)
-            for _ in range(3)]
-    best = min(r["step_transition_us"] for r in rows)
+    rows = [_height_ledger_bookkeeping_us(5_000) for _ in range(3)]
+    best = min(us for us, _ in rows)
     assert best < 10.0, f"step bookkeeping {best} us >= 10 us budget"
     # allocation-free in the FlushLedger sense: steady-state step
     # transitions hold the process block count flat (< 1 block/2 steps
     # tolerates freelist jitter; the real number is ~0.004)
-    assert min(r["steady_alloc_blocks_per_step"] for r in rows) < 0.5
+    assert min(alloc for _, alloc in rows) < 0.5
 
 
 def test_no_jax_import():
     """Host-only contract: nothing in this file (LocalNetwork
-    consensus, ledgers, incidents, RPC, height_report, the bench
-    helper) may pull jax into the process."""
+    consensus, ledgers, incidents, RPC, height_report, the budget
+    replay) may pull jax into the process."""
     if not _JAX_LOADED_BEFORE:
         assert "jax" not in sys.modules

@@ -1,7 +1,7 @@
 """Tier-1 guard for the light-client gateway RPC surface (ISSUE 8
 satellite): the lightgate_* routes end-to-end against an in-process
 node — host paths only, NO jax import, seconds not minutes. Late in
-the alphabet like test_zloadtime_smoke/test_zbench_smoke: by the time
+the alphabet like test_zloadtime_smoke: by the time
 this runs, the unit tests have localized any real breakage.
 """
 import json

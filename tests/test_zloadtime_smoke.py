@@ -3,9 +3,8 @@
 The full loadtime modes drive a live consensus net for tens of seconds;
 `--smoke` is the tier-1-safe slice — mempool + admission + a host-path
 verify plane only, no consensus, NO jax import, a couple of seconds.
-This file (late in the alphabet on purpose, like test_zbench_smoke)
-drives it through main() exactly like the CI invocation would, keeping
-the overload-verdict path (explicit OVERLOADED codes with retry hints)
+This file (late in the alphabet on purpose) drives it through main()
+exactly like the CI invocation would, keeping the overload-verdict path (explicit OVERLOADED codes with retry hints)
 continuously exercised.
 """
 import json

@@ -187,14 +187,6 @@ def test_tenant_report_diff_detects_synthetic_regression(
     out = capsys.readouterr().out
     assert "chain-a" in out and "chain-b" in out
     assert "2 tenants" in out
-    # bench --json-out evidence files are a first-class input shape
-    wrapped = {"results": {"cfg17_smoke": {
-        "metric": "x", "value": 1.0,
-        "extra": {"tenants_dump": dump}}}}
-    w_path = tmp_path / "bench.json"
-    w_path.write_text(json.dumps(wrapped))
-    loaded = tenant_report.load_tenants(str(w_path))
-    assert loaded["tenants"]["chain-a"]["rows"] == 100
     junk = tmp_path / "junk.json"
     junk.write_text(json.dumps({"nope": 1}))
     with pytest.raises(ValueError):

@@ -1,2 +1,2 @@
 """Operator/CI tooling (runnable scripts; importable from the repo
-root for bench.py and the test suite)."""
+root for chip_smoke.py and the test suite)."""

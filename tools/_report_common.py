@@ -6,8 +6,9 @@ file(s), ``--diff`` for an A->B delta table, a relative + absolute
 threshold pair, ``--json``, and a ``--fail-on-regression`` CI gate
 that must ERROR when wired without ``--diff`` (a gate without a
 comparison reads permanently green). This module is that shape, once —
-the per-tool files keep what is genuinely theirs (dump loading, figure
-aggregation, which metrics flag in which direction, table rendering).
+the per-tool files keep what is genuinely theirs (which keys make a
+dump theirs, figure aggregation, which metrics flag in which direction,
+table rendering).
 
 Three flag styles exist in the fleet and all three live here:
 
@@ -33,6 +34,18 @@ from __future__ import annotations
 
 import argparse
 import json
+
+
+def load_dump(path: str, route: str, *keys: str) -> dict:
+    """A saved ``curl $NODE<route>`` document: a JSON object that holds
+    every one of ``keys``. Anything else is refused by name, so a file
+    handed to the wrong tool never renders as an empty report."""
+    with open(path) as f:
+        doc = json.load(f)
+    if isinstance(doc, dict) and all(k in doc for k in keys):
+        return doc
+    raise ValueError(
+        f"{path}: not a {route} document (no {' / '.join(keys)})")
 
 
 def flag_directional(a: float, b: float, *, threshold_pct: float,

@@ -8,8 +8,7 @@ verified, read/verify/apply time, valset-boundary and warm-ahead
 flags, resume-skip counts — plus the run figures (blocks/sec,
 sigs/sec, boundary count, warm requests, resumes, and the time split
 between reading history, verifying commits, and applying blocks).
-Feed it a saved ``curl $NODE/dump_catchup`` file or a bench
---json-out evidence file with an embedded ``catchup_dump``.
+Feed it a saved ``curl $NODE/dump_catchup`` file.
 
 Differencing mirrors tenant_report --diff: figure delta rows with
 REGRESSED/improved flags past BOTH a relative and an absolute
@@ -28,7 +27,6 @@ Usage:
 """
 from __future__ import annotations
 
-import json
 import os
 import sys
 
@@ -36,31 +34,16 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 from tools._report_common import (  # noqa: E402 - after sys.path fix
-    build_parser, flag_directed, run_cli)
+    build_parser, flag_directed, load_dump, run_cli)
 
 DEFAULT_THRESHOLD_PCT = 25.0
 DEFAULT_THRESHOLD_ABS = 4.0
 
 
 def load_catchup(path: str) -> dict:
-    """Extract a catch-up dump from any supported shape: a
-    /dump_catchup document, a bench --json-out evidence file carrying
-    ``extra.catchup_dump``, or a bare {"records": ...} object."""
-    with open(path) as f:
-        doc = json.load(f)
-    if isinstance(doc, dict) and "records" in doc \
-            and "counters" in doc:
-        return doc
-    if isinstance(doc, dict) and "results" in doc:
-        for cfg in sorted(doc["results"]):
-            extra = (doc["results"][cfg] or {}).get("extra") or {}
-            cd = extra.get("catchup_dump")
-            if cd and cd.get("records") is not None:
-                return cd
-    raise ValueError(
-        f"{path}: no catch-up records found (want a /dump_catchup "
-        f"document or a bench --json-out file with an embedded "
-        f"catchup_dump)")
+    """Load a /dump_catchup document (or a bare {"records": ...,
+    "counters": ...} object)."""
+    return load_dump(path, "/dump_catchup", "records", "counters")
 
 
 def catchup_report(dump: dict) -> dict:

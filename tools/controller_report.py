@@ -8,8 +8,7 @@ actuator: configured base, clamp bounds, current value, displacement
 from base, move count, tighten/relax split; plus the decision timeline
 (who moved, which direction, what the trigger sensors read) and the
 SLO-violation accrual. Feed it a saved ``curl $NODE/dump_controller``
-file or a bench --json-out evidence file with an embedded
-``controller_dump``.
+file.
 
 Differencing mirrors device_report --diff: figure delta rows with
 REGRESSED/improved flags past BOTH a relative and an absolute
@@ -28,7 +27,6 @@ Usage:
 """
 from __future__ import annotations
 
-import json
 import os
 import sys
 
@@ -36,32 +34,16 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 from tools._report_common import (  # noqa: E402 - after sys.path fix
-    build_parser, flag_directional, run_cli)
+    build_parser, flag_directional, load_dump, run_cli)
 
 DEFAULT_THRESHOLD_PCT = 25.0
 DEFAULT_THRESHOLD_ABS = 4.0
 
 
 def load_controller(path: str) -> dict:
-    """Extract a controller dump from any supported shape: a
-    /dump_controller document, a bench --json-out evidence file
-    carrying ``extra.controller_dump``, or a bare {"decisions": ...,
-    "actuators": ...} object."""
-    with open(path) as f:
-        doc = json.load(f)
-    if isinstance(doc, dict) and "decisions" in doc \
-            and "actuators" in doc:
-        return doc
-    if isinstance(doc, dict) and "results" in doc:
-        for cfg in sorted(doc["results"]):
-            extra = (doc["results"][cfg] or {}).get("extra") or {}
-            cd = extra.get("controller_dump")
-            if cd and cd.get("decisions") is not None:
-                return cd
-    raise ValueError(
-        f"{path}: no controller records found (want a "
-        f"/dump_controller document or a bench --json-out file with "
-        f"an embedded controller_dump)")
+    """Load a /dump_controller document (or a bare {"decisions": ...,
+    "actuators": ...} object)."""
+    return load_dump(path, "/dump_controller", "decisions", "actuators")
 
 
 def controller_report(dump: dict) -> dict:

@@ -8,8 +8,7 @@ ms, steady-state recompiles (the round-5 regression class),
 persistent-cache hits; per family x device: resident bytes, pinned
 valset slots, headroom against the 65536-slot/chip budget; plus the
 flush ledger's device-time split (comp/h2d/dev ms, utilization) when
-the dump carries it. Feed it a saved ``curl $NODE/dump_devices`` file
-or a bench --json-out evidence file with an embedded ``device_dump``.
+the dump carries it. Feed it a saved ``curl $NODE/dump_devices`` file.
 
 Differencing mirrors trace_report --diff: counter/figure delta rows
 with REGRESSED/improved flags past BOTH a relative and an absolute
@@ -27,7 +26,6 @@ Usage:
 """
 from __future__ import annotations
 
-import json
 import os
 import sys
 from typing import List
@@ -36,32 +34,16 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 from tools._report_common import (  # noqa: E402 - after sys.path fix
-    build_parser, flag_directional, run_cli)
+    build_parser, flag_directional, load_dump, run_cli)
 
 DEFAULT_THRESHOLD_PCT = 25.0
 DEFAULT_THRESHOLD_ABS = 8.0
 
 
 def load_devices(path: str) -> dict:
-    """Extract a device dump from any supported shape: a /dump_devices
-    document, a bench --json-out evidence file carrying
-    ``extra.device_dump``, or a bare {"summary": ..., "compiles": ...}
-    object."""
-    with open(path) as f:
-        doc = json.load(f)
-    if isinstance(doc, dict) and "compiles" in doc \
-            and "summary" in doc:
-        return doc
-    if isinstance(doc, dict) and "results" in doc:
-        for cfg in sorted(doc["results"]):
-            extra = (doc["results"][cfg] or {}).get("extra") or {}
-            dd = extra.get("device_dump")
-            if dd and dd.get("compiles") is not None:
-                return dd
-    raise ValueError(
-        f"{path}: no device records found (want a /dump_devices "
-        f"document or a bench --json-out file with an embedded "
-        f"device_dump)")
+    """Load a /dump_devices document (or a bare {"summary": ...,
+    "compiles": ...} object)."""
+    return load_dump(path, "/dump_devices", "compiles", "summary")
 
 
 def device_report(dump: dict) -> dict:

@@ -6,9 +6,8 @@ report decomposes a FLUSH, this decomposes a BLOCK — proposal
 propagation vs prevote quorum vs precommit quorum vs persist vs apply,
 per height, percentile-summarized, with the verify-plane join and the
 chronically-late-signer table the DCN round reads. Feed it a saved
-``curl $NODE/dump_heights`` file, a bench ``--json-out`` evidence file
-(cfg9/cfg13 embed a trimmed dump under ``extra.height_dump``), or any
-JSON holding a ``heights`` list.
+``curl $NODE/dump_heights`` file or any JSON holding a ``heights``
+list.
 
 Differencing mirrors trace_report --diff: stage-delta rows with
 REGRESSED/improved/appeared/vanished flags on mean ms past BOTH a
@@ -24,7 +23,6 @@ Usage:
 """
 from __future__ import annotations
 
-import json
 import os
 import sys
 from typing import Dict, List, Optional
@@ -33,7 +31,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 from tools._report_common import (  # noqa: E402 - after sys.path fix
-    build_parser, flag_symmetric, run_cli)
+    build_parser, flag_symmetric, load_dump, run_cli)
 
 # per-record STAGE DELTAS derived from the cumulative timeline: each
 # row is "time spent inside this stage", so the table sums to the
@@ -51,24 +49,9 @@ DEFAULT_THRESHOLD_MS = 1.0
 
 
 def load_heights(path: str) -> dict:
-    """Extract {heights, late_signers, summary} from any supported
-    shape: a /dump_heights document, a bench --json-out evidence file
-    (first config carrying extra.height_dump wins), or a bare
-    {"heights": [...]} object."""
-    with open(path) as f:
-        doc = json.load(f)
-    if isinstance(doc, dict) and "heights" in doc:
-        return doc
-    if isinstance(doc, dict) and "results" in doc:
-        for cfg in sorted(doc["results"]):
-            extra = (doc["results"][cfg] or {}).get("extra") or {}
-            hd = extra.get("height_dump")
-            if hd and hd.get("heights"):
-                return hd
-    raise ValueError(
-        f"{path}: no height records found (want a /dump_heights "
-        f"document or a bench --json-out file with an embedded "
-        f"height_dump)")
+    """Load {heights, late_signers, summary}: a /dump_heights document
+    or a bare {"heights": [...]} object."""
+    return load_dump(path, "/dump_heights", "heights")
 
 
 def _pct(xs: List[float], q: float) -> float:
@@ -94,8 +77,7 @@ def _row(name: str, durs: List[float]) -> dict:
 
 def stage_report(dump: dict) -> dict:
     """Aggregate a height dump into the per-stage table + the
-    late-signer and attribution extras the text report prints and the
-    bench embeds."""
+    late-signer and attribution extras the text report prints."""
     recs = [r for r in dump.get("heights", [])]
     # only heights with a complete monotone timeline contribute to the
     # per-stage deltas (catch-up pushes and clock-domain-swapped

@@ -281,9 +281,8 @@ def run_inprocess(rate: float, duration: float, n_nodes: int = 4,
         if nodes[0].mempool.admission else None,
     }
     # per-height commit-latency attribution from node 0's always-on
-    # height ledger (trimmed: the bench evidence file must not carry
-    # 512 full records) — cfg9 embeds the height_report table so the
-    # sustained-load commit latency is baseline-comparable
+    # height ledger (trimmed: the printed document must not carry
+    # 512 full records), with the height_report table beside it
     try:
         from tools import height_report
 

@@ -25,7 +25,6 @@ Usage:
 """
 from __future__ import annotations
 
-import json
 import os
 import sys
 from typing import List
@@ -34,7 +33,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 from tools._report_common import (  # noqa: E402 - after sys.path fix
-    build_parser, flag_symmetric, run_cli)
+    build_parser, flag_symmetric, load_dump, run_cli)
 
 # aggregate health counters the diff flags on: bigger = sicker
 HEALTH_KEYS = ("blocked_puts", "full_drops", "throttle_stalls",
@@ -45,22 +44,9 @@ DEFAULT_THRESHOLD_ABS = 8.0
 
 
 def load_peers(path: str) -> dict:
-    """Extract {summary, peers, events} from any supported shape: a
-    /dump_peers document, a bench --json-out evidence file carrying
-    ``extra.peer_dump``, or a bare {"peers": [...]} object."""
-    with open(path) as f:
-        doc = json.load(f)
-    if isinstance(doc, dict) and "peers" in doc:
-        return doc
-    if isinstance(doc, dict) and "results" in doc:
-        for cfg in sorted(doc["results"]):
-            extra = (doc["results"][cfg] or {}).get("extra") or {}
-            pd = extra.get("peer_dump")
-            if pd and pd.get("peers") is not None:
-                return pd
-    raise ValueError(
-        f"{path}: no peer records found (want a /dump_peers document "
-        f"or a bench --json-out file with an embedded peer_dump)")
+    """Load {summary, peers, events}: a /dump_peers document or a bare
+    {"peers": [...]} object."""
+    return load_dump(path, "/dump_peers", "peers")
 
 
 def peer_report(dump: dict) -> dict:

@@ -8,8 +8,7 @@ verified rows (per lane), quota sheds, warm skips, cold-table
 evictions, HBM residency (bytes + tables), verify-wait percentiles,
 and the configured quotas; plus the registry-level figures (size,
 evictions, the retired-totals accumulator). Feed it a saved
-``curl $NODE/dump_tenants`` file or a bench --json-out evidence file
-with an embedded ``tenants_dump``.
+``curl $NODE/dump_tenants`` file.
 
 Differencing mirrors controller_report --diff: figure delta rows with
 REGRESSED/improved flags past BOTH a relative and an absolute
@@ -28,7 +27,6 @@ Usage:
 """
 from __future__ import annotations
 
-import json
 import os
 import sys
 
@@ -36,31 +34,16 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 from tools._report_common import (  # noqa: E402 - after sys.path fix
-    build_parser, flag_directional, run_cli)
+    build_parser, flag_directional, load_dump, run_cli)
 
 DEFAULT_THRESHOLD_PCT = 25.0
 DEFAULT_THRESHOLD_ABS = 4.0
 
 
 def load_tenants(path: str) -> dict:
-    """Extract a tenant dump from any supported shape: a /dump_tenants
-    document, a bench --json-out evidence file carrying
-    ``extra.tenants_dump``, or a bare {"tenants": ...} object."""
-    with open(path) as f:
-        doc = json.load(f)
-    if isinstance(doc, dict) and "tenants" in doc \
-            and "registry_size" in doc:
-        return doc
-    if isinstance(doc, dict) and "results" in doc:
-        for cfg in sorted(doc["results"]):
-            extra = (doc["results"][cfg] or {}).get("extra") or {}
-            td = extra.get("tenants_dump")
-            if td and td.get("tenants") is not None:
-                return td
-    raise ValueError(
-        f"{path}: no tenant records found (want a /dump_tenants "
-        f"document or a bench --json-out file with an embedded "
-        f"tenants_dump)")
+    """Load a /dump_tenants document (or a bare {"tenants": ...,
+    "registry_size": ...} object)."""
+    return load_dump(path, "/dump_tenants", "tenants", "registry_size")
 
 
 def tenant_report(dump: dict) -> dict:

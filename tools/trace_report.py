@@ -2,17 +2,16 @@
 critical-path tables — and DIFF two of them.
 
 The perf loop's before/after instrument: run a workload with tracing on
-(``bench.py --trace-out``, ``[tracing] enable``, or
-``curl $NODE/dump_traces``), feed the file here, and read where the
-wall time went per stage — pack vs device flight vs collect vs settle
-for the verify plane, per-step time for consensus, fsync cost for the
-WAL. BENCH_*.json embeds the same table via ``stage_report``.
+(``[tracing] enable``, then ``curl $NODE/dump_traces``), feed the
+file here, and read where the wall time went per stage — pack vs
+device flight vs collect vs settle for the verify plane, per-step time
+for consensus, fsync cost for the WAL.
 
 Differencing is the regression instrument (ISSUE 6 / ROADMAP open item
 1): ``--diff A.trace.json B.trace.json`` aligns the two stage tables
 and emits stage-delta and overlap-delta rows with regression flags, so
-"where did cfg2's 6.6 ms go" is one command instead of an eyeballing
-exercise.
+"where did the commit's 6.6 ms go" is one command instead of an
+eyeballing exercise.
 
 Traces with no verify-plane spans (blocksync-/consensus-only runs)
 fall back to a consensus-step table derived from the ``consensus.step``
@@ -154,8 +153,8 @@ def _row(name: str, durs: List[float]) -> dict:
 
 
 def stage_report(events: List[dict]) -> dict:
-    """Aggregate a trace into {stages, instants, plane} — the table the
-    bench embeds and main() pretty-prints.
+    """Aggregate a trace into {stages, instants, plane} — the table
+    main() pretty-prints.
 
     stages: per span name, count + total/mean/p50/max ms.
     instants: per instant name, count.
