@@ -328,6 +328,18 @@ def _verify_single(
 # Device batch_fn factories
 # --------------------------------------------------------------------------
 
+# Rows of one device pass of an ed25519 batch too large for one
+# (device_batch_fn). A larger batch is cut into chunks of exactly this
+# shape, each dispatched as soon as it is packed: the kernel sweeps
+# ceil(n / T) * T rows where the bucket ladder's next rung may be 16,384
+# for 6,667, and every pack but the first runs while the device
+# verifies. Smaller shortens what nothing hides (the first pack, and
+# the last chunk's pass, which the host waits out) and the padded tail;
+# larger means fewer packs and dispatches, each with a fixed cost (0.3
+# ms a dispatch). Swept 512 to 4,096 on a v5e over the 6,667 rows of a
+# 10,000-validator commit (PERF.md section 6, PR 31).
+COMMIT_CHUNK_ROWS = 1024
+
 
 def device_batch_fn(use_pallas: Optional[bool] = None,
                     cached: bool = False) -> Callable:
@@ -366,25 +378,39 @@ def device_batch_fn(use_pallas: Optional[bool] = None,
                 return ec.verify_batch_cached(pub_bytes, msgs, sigs)
         if use_pallas:
             from cometbft_tpu.ops import ed25519_pallas as kp
-
+        # up to one chunk: ONE pass padded by the bucket ladder, as ever;
+        # above it: chunks of ONE shape, the tail's too, each on the
+        # device while the host packs the next
+        if n > COMMIT_CHUNK_ROWS:
+            pad = COMMIT_CHUNK_ROWS
+        elif use_pallas:
             pad = kp.pad_to_tile(n)
-            with tracing.stage("ed25519.pack", rows=n, padded=pad):
-                rows = kp.pack_rows(
-                    ek.pack_batch(pub_bytes, msgs, sigs, pad_to=pad))
-            with tracing.stage("ed25519.dispatch", rows=n):
-                out = kp.verify_rows(rows)
         else:
             pad = ek.bucket_size(max(n, 1))
-            with tracing.stage("ed25519.pack", rows=n, padded=pad):
-                pb = ek.pack_batch(pub_bytes, msgs, sigs, pad_to=pad)
-            with tracing.stage("ed25519.dispatch", rows=n):
-                out = ek.verify_kernel(
-                    pb.ay, pb.asign, pb.ry, pb.rsign, pb.sdig, pb.hdig,
-                    pb.precheck,
-                )
-        # the device's time and the copy back, as the host waits it out
+        chunks = max(1, -(-n // pad))
+        outs = []
+        for k in range(chunks):
+            lo = k * pad
+            # flying: chunks of this call the device has not finished
+            # as this one's pack starts (0 past the first: it ran dry)
+            at = {"rows": min(n - lo, pad), "chunk": k, "chunks": chunks,
+                  "flying": sum(not o.is_ready() for o in outs)}
+            with tracing.stage("ed25519.pack", padded=pad, **at):
+                pb = ek.pack_batch(pub_bytes[lo:lo + pad],
+                                   msgs[lo:lo + pad], sigs[lo:lo + pad],
+                                   pad_to=pad)
+                if use_pallas:
+                    pb = kp.pack_rows(pb)
+            # returns while the device runs
+            with tracing.stage("ed25519.dispatch", **at):
+                outs.append(
+                    kp.verify_rows(pb) if use_pallas else
+                    ek.verify_kernel(pb.ay, pb.asign, pb.ry, pb.rsign,
+                                     pb.sdig, pb.hdig, pb.precheck))
+        # what the host still waits for once it has nothing left to do,
+        # and the copy back: every chunk's verdicts, in row order
         with tracing.stage("ed25519.fetch"):
-            valid = np.asarray(out)
+            valid = np.concatenate([np.asarray(o) for o in outs])
         return valid[:n]
 
     def fn(pubs, msgs, sigs):

@@ -143,6 +143,37 @@ def test_device_batch_fn_covered_by_breaker():
     assert cbatch.device_breaker().state == "open"
 
 
+def test_fault_in_a_later_chunk_is_one_fault_and_host_verdicts(
+        monkeypatch):
+    """A batch over validation.COMMIT_CHUNK_ROWS goes to the device in
+    chunks; a kernel that raises on the second leaves the whole call to
+    the breaker, once, and the host serves every row."""
+    import jax.numpy as jnp
+
+    from cometbft_tpu.ops import ed25519_kernel as ek
+    from cometbft_tpu.types import validation
+
+    pubs, msgs, sigs, exp = make_batch()
+    calls = []
+
+    def sick(ay, asign, ry, rsign, sdig, hdig, precheck):
+        calls.append(len(precheck))
+        if len(calls) == 2:
+            raise RuntimeError("device lost")
+        return jnp.asarray(precheck)  # wrong for rows 2 and 4: unused
+
+    monkeypatch.setattr(ek, "verify_kernel", sick)
+    monkeypatch.setattr(validation, "COMMIT_CHUNK_ROWS", 4)
+    cbatch.configure_breaker(1, 30.0)
+    brk = cbatch.device_breaker()
+    trips0, faults0 = brk.trips, brk.faults  # monotone across reset()
+    got = validation.device_batch_fn(use_pallas=False)(pubs, msgs, sigs)
+    np.testing.assert_array_equal(got, np.asarray(exp))
+    assert calls == [4, 4]
+    assert (brk.state, brk.trips - trips0, brk.faults - faults0) == (
+        "open", 1, 1)
+
+
 def test_breaker_config_knobs():
     from cometbft_tpu.config.config import Config, ConfigError
 

@@ -228,3 +228,108 @@ def test_vote_verify_roundtrip():
     other = PrivKey.generate(b"\x08" * 32)
     with pytest.raises(Exception):
         v.verify(CHAIN_ID, other.pub_key())
+
+
+# --------------------------------------------------------------------------
+# device_batch_fn over a batch larger than one chunk (COMMIT_CHUNK_ROWS)
+# --------------------------------------------------------------------------
+
+T = 64  # the XLA kernel's smallest bucket: the shape every test above
+#         compiles, so three chunks cost three small batches
+
+
+@pytest.fixture
+def chunked(monkeypatch):
+    monkeypatch.setattr(validation, "COMMIT_CHUNK_ROWS", T)
+
+
+@pytest.fixture
+def kernel_calls(monkeypatch):
+    """Stands in for the XLA kernel: a row passes its precheck. Returns
+    the row count of every batch the kernel was handed."""
+    import jax.numpy as jnp
+
+    from cometbft_tpu.ops import ed25519_kernel as ek
+
+    seen = []
+
+    def fake(ay, asign, ry, rsign, sdig, hdig, precheck):
+        seen.append(len(precheck))
+        return jnp.asarray(precheck)
+
+    monkeypatch.setattr(ek, "verify_kernel", fake)
+    return seen
+
+
+def make_rows(n, bad=()):
+    privs = [PrivKey.generate(i.to_bytes(2, "big") * 16) for i in range(n)]
+    msgs = [b"chunked-%d" % i for i in range(n)]
+    sigs = [p.sign(m) for p, m in zip(privs, msgs)]
+    for i in bad:
+        sigs[i] = sigs[i][:10] + bytes([sigs[i][10] ^ 1]) + sigs[i][11:]
+    return [p.pub_key() for p in privs], msgs, sigs
+
+
+def test_chunked_verdicts_match_the_oracle(chunked):
+    bad = (0, T - 1, T, 2 * T + 4)  # both ends of a chunk, and the tail's
+    pubs, msgs, sigs = make_rows(2 * T + 5, bad)
+    got = validation.device_batch_fn(use_pallas=False)(pubs, msgs, sigs)
+    want = validation.oracle_batch_fn()(pubs, msgs, sigs)
+    np.testing.assert_array_equal(got, want)
+    assert tuple(np.flatnonzero(~want)) == bad
+
+
+@pytest.mark.parametrize("name,mk_fn", BATCH_FNS)
+def test_chunked_blame_is_the_first_bad_collected_index(chunked, name,
+                                                        mk_fn):
+    """Two chunks hold one bad signature each: the earlier is blamed,
+    as the one-by-one fallback of the reference would stop there."""
+    vs, commit, bid = make_commit(n_vals=3 * T, invalid=(T + 6, 2 * T + 2))
+    with pytest.raises(validation.InvalidSignatureError) as ei:
+        validation.verify_commit_light(CHAIN_ID, vs, bid, HEIGHT, commit,
+                                       mk_fn())
+    assert ei.value.idx == T + 6
+
+
+@pytest.mark.parametrize("n,chunks", [
+    (T - 1, 1), (T, 1),          # the ladder's one padded batch
+    (T + 1, 2), (2 * T + 5, 3),  # every chunk T rows, the tail too
+    (5 * T, 5),   # the ladder would say 1,024
+    (1024 * T + 1, 1025),  # past the ladder's last rung (65,536)
+], ids=["below", "at", "one-over", "tail", "multiple", "past-the-ladder"])
+def test_every_chunk_has_one_shape_and_the_result_n_rows(
+        chunked, kernel_calls, n, chunks):
+    from cometbft_tpu.libs import tracing
+
+    pubs, msgs, sigs = make_rows(1)
+    tracing.set_clock(None)  # an empty stage ring
+    got = validation.device_batch_fn(use_pallas=False)(
+        pubs * n, msgs * n, sigs * n)
+    assert got.shape == (n,) and got.all()
+    assert kernel_calls == [T] * chunks
+    packs = [r[4] for r in tracing.stage_records()
+             if r[0] == "ed25519.pack"]
+    assert [p["padded"] for p in packs] == [T] * chunks
+    assert sum(p["rows"] for p in packs) == n
+
+
+def test_chunk_stages_pack_and_dispatch_in_turn_then_one_fetch(
+        chunked, kernel_calls):
+    from cometbft_tpu.libs import tracing
+
+    pubs, msgs, sigs = make_rows(1)
+    n = 2 * T + 5
+    tracing.set_clock(None)  # an empty stage ring
+    validation.device_batch_fn(use_pallas=False)(
+        pubs * n, msgs * n, sigs * n)
+    recs = tracing.stage_records()
+    assert [r[0] for r in recs] == (
+        ["ed25519.pack", "ed25519.dispatch"] * 3 + ["ed25519.fetch"])
+    for k, (p, d) in enumerate(zip(recs[0:6:2], recs[1:6:2])):
+        for args in (p[4], d[4]):
+            assert (args["chunk"], args["chunks"]) == (k, 3)
+            assert 0 <= args["flying"] <= k
+        assert p[4]["rows"] == d[4]["rows"] == (T if k < 2 else 5)
+        assert p[1] + p[2] <= d[1]  # packed, then dispatched
+    assert recs[0][4]["flying"] == 0  # nothing flies before the first
+    assert recs[-1][4] == {} and recs[5][1] + recs[5][2] <= recs[-1][1]
