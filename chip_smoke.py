@@ -11,10 +11,12 @@ or an operator calls, every path that puts signatures on the device:
            native/hostaccel.cpp is compiled in this run; Pallas runs
            under Mosaic, not the interpreter
   kernels  the ZIP-215 edge vectors (tools/tpu_differential.edge_cases)
-           through the three ed25519 kernels, sr25519 and secp256k1
-           vectors through theirs, and one ~1k-row mixed-key batch
-           through crypto.batch.verify_batch, row for row against the
-           pure-Python references
+           through the three ed25519 kernels, secp256k1 vectors
+           through theirs, sr25519 vectors through the node's batch_fn
+           as chunks of the served shape (1,024 rows), and one
+           interleaved mixed-key batch through that batch_fn (sr25519
+           chunks and an ed25519 pass in one queue), row for row
+           against the pure-Python references
   commit   Config().crypto.batch_fn() (what a node is assembled with)
            under validation.verify_commit_light on a seeded
            10,000-validator commit: accepted; tampered -> the host's
@@ -313,18 +315,27 @@ def leg_kernels():
         ed25519_cached,
         ed25519_kernel,
         ed25519_pallas,
-        sr25519_kernel,
     )
+    from cometbft_tpu.types import validation
 
     (ed_rows, ed_exp), (sr_rows, sr_exp), (sc_rows, sc_exp) = \
         _kernel_batches()
+    # sr25519 as the served commit check feeds it: more rows than
+    # validation.COMMIT_CHUNK_ROWS, so chunks of that one shape (1,024)
+    # through device_batch_fn's queue, fetched after the last dispatch
+    sr_rows, sr_exp = sr_rows * 5, sr_exp * 5
+    served = validation.device_batch_fn()
+
+    def sr25519_chunked(pubs, msgs, sigs):
+        return served([PubKey(p, "sr25519") for p in pubs], msgs, sigs)
+
     out = {}
     for name, fn, rows, exp in (
             ("ed25519_pallas", ed25519_pallas.verify_batch, ed_rows, ed_exp),
             ("ed25519_cached", ed25519_cached.verify_batch_cached,
              ed_rows, ed_exp),
             ("ed25519_kernel", ed25519_kernel.verify_batch, ed_rows, ed_exp),
-            ("sr25519_kernel", sr25519_kernel.verify_batch, sr_rows, sr_exp),
+            ("sr25519_kernel", sr25519_chunked, sr_rows, sr_exp),
             ("ecdsa_pallas", ecdsa_pallas.verify_batch, sc_rows, sc_exp)):
         pubs, msgs, sigs = (list(z) for z in zip(*rows))
         got = np.asarray(fn(pubs, msgs, sigs), np.bool_)
@@ -333,7 +344,9 @@ def leg_kernels():
               bad[:8].tolist())
         out[name] = {"rows": len(rows), "valid": int(got.sum())}
 
-    # the key-type grouping seam: one interleaved mixed-key batch
+    # the key-type grouping seam: one interleaved mixed-key batch through
+    # the node's batch_fn (ed25519 in one pass, sr25519 in two chunks,
+    # both in flight before either is fetched; secp256k1 in one pass)
     mixed = ([(PubKey(p, "ed25519"), m, s, e)
               for (p, m, s), e in zip(ed_rows, ed_exp)]
              + [(PubKey(p, "sr25519"), m, s, e)
@@ -343,11 +356,11 @@ def leg_kernels():
     order = np.random.RandomState(SEED).permutation(len(mixed))
     mixed = [mixed[i] for i in order]
     faults0 = cbatch.device_breaker().faults
-    got = cbatch.verify_batch([r[0] for r in mixed], [r[1] for r in mixed],
-                              [r[2] for r in mixed])
+    got = served([r[0] for r in mixed], [r[1] for r in mixed],
+                 [r[2] for r in mixed])
     bad = np.flatnonzero(np.asarray(got) != np.asarray(
         [r[3] for r in mixed]))
-    check(bad.size == 0, "crypto.batch.verify_batch disagrees at rows",
+    check(bad.size == 0, "device_batch_fn disagrees at mixed rows",
           bad[:8].tolist())
     check(cbatch.device_breaker().faults == faults0,
           "a kernel dispatch faulted and fell back to the host")

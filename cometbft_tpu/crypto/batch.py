@@ -6,8 +6,10 @@ further than the reference in two ways:
 - secp256k1 IS batchable here (the reference has no ECDSA batch path at
   all — batch.go:12-21 only dispatches ed25519/sr25519);
 - one mixed-key commit verifies in a single call: rows are grouped by key
-  type and each group goes to its kernel (the device pads per-group, so a
-  mixed batch costs two kernel dispatches, not a serial fallback).
+  type and each group goes to its kernel. A kernel may hand back its
+  verdicts not yet fetched (PendingVerdicts): every group is dispatched
+  before the first is fetched, so the device works on one key type's
+  rows while the host packs the other's.
 
 The batch_fn signature used across validation.py: fn(pubs, msgs, sigs)
 with pubs a sequence of crypto.keys.PubKey; returns (n,) bool validity —
@@ -15,8 +17,9 @@ the per-signature slice the blame path needs (types/validation.go:243).
 
 Degraded mode: every kernel dispatch runs under a circuit breaker. A
 device fault (XLA or Mosaic error, a lost device, an injected
-`crypto.device_dispatch` failpoint) is caught, logged, counted in the
-breaker's `faults`, and the batch re-verified on the host
+`crypto.device_dispatch` failpoint), raised at dispatch or when the
+verdicts are fetched, is caught, logged, counted in the breaker's
+`faults`, and that key type's rows re-verified on the host
 single-signature path — a sick TPU costs throughput, never consensus
 liveness. After `failure_threshold` consecutive faults the breaker
 OPENS and batches go straight to the host path; every `cooldown`
@@ -216,6 +219,24 @@ def _kernel_for(key_type: str) -> Callable:
     raise ValueError(f"no batch verifier for key type {key_type!r}")
 
 
+class PendingVerdicts:
+    """What a kernel returns in place of (n,) bool verdicts when it has
+    dispatched its rows and not waited for them: the device arrays of
+    its passes, in row order. `fetch()` waits, copies back and returns
+    the first `n` verdicts under the always-on stage `stage`;
+    verify_batch_direct calls it once, after the call's last dispatch."""
+
+    __slots__ = ("outs", "n", "stage")
+
+    def __init__(self, outs: Sequence, n: int, stage: str):
+        self.outs, self.n, self.stage = list(outs), n, stage
+
+    def fetch(self) -> np.ndarray:
+        with tracing.stage(self.stage):
+            return np.concatenate(
+                [np.asarray(o) for o in self.outs])[: self.n]
+
+
 def _host_verify_rows(pubs, msgs, sigs, idxs, valid) -> None:
     """Host fallback: per-row single verify via the reference-path
     PubKey.verify_signature (ed25519_ref and friends). Fills `valid`
@@ -264,6 +285,13 @@ def verify_batch_direct(
     """The direct (non-plane) batch verify: group rows by key type and
     dispatch each group to its kernel under the circuit breaker.
 
+    Every group is dispatched before any verdict is waited for: a kernel
+    that returns PendingVerdicts is fetched after the call's last
+    dispatch, in group order; one that returns the verdicts themselves
+    (numpy) is done when it returns. A fault at dispatch or at fetch
+    records one breaker failure and re-verifies THAT group on the host;
+    a success is recorded only with a group's verdicts in hand.
+
     kernels overrides the per-type kernel (e.g. the Pallas ed25519 path).
     breaker overrides the global device circuit breaker (tests)."""
     n = len(pubs)
@@ -272,38 +300,53 @@ def verify_batch_direct(
     groups: dict = defaultdict(list)
     for i, p in enumerate(pubs):
         groups[p.key_type].append(i)
+
+    def on_host(kt, idxs):
+        with tracing.span("crypto.batch.host", cat="crypto",
+                          key_type=kt, rows=len(idxs)):
+            _host_verify_rows(pubs, msgs, sigs, idxs, valid)
+
+    def faulted(kt, idxs):
+        brk.record_failure()
+        _log.exception(
+            "device batch verify failed for %s (%d sigs); "
+            "falling back to the host path", kt, len(idxs),
+        )
+        on_host(kt, idxs)
+
+    flying = []  # (key type, its rows' indices, PendingVerdicts)
     for kt, idxs in groups.items():
         if kt not in _BATCHABLE:
             # unknown type: per-row single verify; a type with no verifier
             # at all marks the row invalid instead of raising mid-batch
             _host_verify_rows(pubs, msgs, sigs, idxs, valid)
             continue
-        sub = None
-        if brk.allow():
-            kernel = (kernels or {}).get(kt) or _kernel_for(kt)
-            try:
-                fp.fail_point("crypto.device_dispatch")
-                with tracing.span("crypto.batch.device", cat="crypto",
-                                  key_type=kt, rows=len(idxs)):
-                    sub = kernel(
-                        [pubs[i].data for i in idxs],
-                        [msgs[i] for i in idxs],
-                        [sigs[i] for i in idxs],
-                    )
-                brk.record_success()
-            except Exception:  # noqa: BLE001 - device fault, not verdict
-                brk.record_failure()
-                _log.exception(
-                    "device batch verify failed for %s (%d sigs); "
-                    "falling back to the host path", kt, len(idxs),
-                )
-                sub = None
-        if sub is None:
-            with tracing.span("crypto.batch.host", cat="crypto",
+        if not brk.allow():
+            on_host(kt, idxs)
+            continue
+        kernel = (kernels or {}).get(kt) or _kernel_for(kt)
+        try:
+            fp.fail_point("crypto.device_dispatch")
+            with tracing.span("crypto.batch.device", cat="crypto",
                               key_type=kt, rows=len(idxs)):
-                _host_verify_rows(pubs, msgs, sigs, idxs, valid)
-        else:
+                sub = kernel(
+                    [pubs[i].data for i in idxs],
+                    [msgs[i] for i in idxs],
+                    [sigs[i] for i in idxs],
+                )
+            if isinstance(sub, PendingVerdicts):
+                flying.append((kt, idxs, sub))
+                continue
             valid[np.asarray(idxs)] = np.asarray(sub)
+            brk.record_success()
+        except Exception:  # noqa: BLE001 - device fault, not verdict
+            faulted(kt, idxs)
+    for kt, idxs, pending in flying:
+        try:
+            valid[np.asarray(idxs)] = pending.fetch()
+            brk.record_success()
+        except Exception:  # noqa: BLE001 - the fault surfaced at the wait
+            faulted(kt, idxs)
     return valid
 
 

@@ -238,6 +238,7 @@ def _below_p(b: np.ndarray) -> np.ndarray:
 def batch_challenges(msgs, pubs, r_encs) -> np.ndarray:
     """Merlin challenge scalars for a batch, vectorized by message length.
 
+    `pubs` and `r_encs` are n 32-byte strings each, or (n, 32) uint8.
     Returns (n, 64) uint8 of raw challenge bytes (reduce mod L happens in
     the nibble pack). Groups rows by len(msg): within a group the
     transcript op sequence is identical, so the batched STROBE applies.
@@ -247,6 +248,10 @@ def batch_challenges(msgs, pubs, r_encs) -> np.ndarray:
     n = len(msgs)
     out = np.zeros((n, 64), np.uint8)
     prefix = sr._signing_prefix()
+    pk_all, r_all = (
+        a if isinstance(a, np.ndarray) else
+        np.frombuffer(b"".join(a), np.uint8).reshape(n, 32)
+        for a in (pubs, r_encs))
     groups = {}
     for i, m in enumerate(msgs):
         groups.setdefault(len(m), []).append(i)
@@ -255,12 +260,7 @@ def batch_challenges(msgs, pubs, r_encs) -> np.ndarray:
         marr = np.frombuffer(
             b"".join(msgs[i] for i in idxs), np.uint8
         ).reshape(len(idxs), ln) if ln else np.empty((len(idxs), 0), np.uint8)
-        parr = np.frombuffer(
-            b"".join(pubs[i] for i in idxs), np.uint8
-        ).reshape(len(idxs), 32)
-        rarr = np.frombuffer(
-            b"".join(r_encs[i] for i in idxs), np.uint8
-        ).reshape(len(idxs), 32)
+        parr, rarr = pk_all[idxs], r_all[idxs]
         ch = None
         if use_native and ln > 0:
             # whole transcripts in one C call (the numpy BatchStrobe
@@ -283,56 +283,62 @@ def batch_challenges(msgs, pubs, r_encs) -> np.ndarray:
     return out
 
 
+_ZERO32 = b"\x00" * 32
+
+
 def pack_batch_sr(pubkeys, msgs, sigs, pad_to=None,
                   power5=None, counted=None, commit_ids=None, thresh=None):
-    """sr25519 rows -> compact packed array (ed25519_pallas layout).
+    """sr25519 rows -> compact packed array (ed25519_pallas layout),
+    padded to `pad_to` rows (a served commit's chunk shape) or the
+    bucket ladder's rung for n.
 
     C_AY carries the pubkey's ristretto s-encoding limbs, C_RY the
     signature R's, C_S8 the s-scalar byte digits, C_H4 the merlin
-    challenge k's nibble digits.
+    challenge k's nibble digits. A row that fails a host precheck (a
+    length, the marker bit, a canonicality rule) is packed as zeros
+    with its precheck flag down.
     """
+    from cometbft_tpu import native
+    from cometbft_tpu.libs import tracing
     from cometbft_tpu.ops import ed25519_kernel as ek
 
     n = len(pubkeys)
     pad = pad_to or kp.pad_to_tile(n)
-    P = F25519.p
     a_l = np.zeros((pad, NLIMBS), np.int32)
     r_l = np.zeros((pad, NLIMBS), np.int32)
     sdig = np.zeros((pad, 64), np.int32)
     hdig = np.zeros((pad, 64), np.int32)
     precheck = np.zeros((pad,), np.int32)
 
-    r_encs = [bytes(s[:32]) if len(s) == 64 else b"\x00" * 32 for s in sigs]
-    chal = batch_challenges(
-        [bytes(m) for m in msgs], [bytes(p) for p in pubkeys], r_encs
-    )
-    # one vectorized pass over the whole batch (the per-row bigint loop
-    # with its 64-step nibble split was the dominant host cost of the
-    # mixed 10k commit — ~0.5 s for 5k rows)
-    lenok = np.array(
-        [len(pubkeys[i]) == 32 and len(sigs[i]) == 64
-         and bool(sigs[i][63] & 0x80) for i in range(n)],
-        np.bool_,
-    )
     if n:
-        pk_arr = np.zeros((n, 32), np.uint8)
-        r_arr = np.zeros((n, 32), np.uint8)
-        s_arr = np.zeros((n, 32), np.uint8)
-        for i in np.flatnonzero(lenok):
-            pk_arr[i] = np.frombuffer(bytes(pubkeys[i]), np.uint8)
-            sig = np.frombuffer(bytes(sigs[i]), np.uint8)
-            r_arr[i] = sig[:32]
-            s_arr[i] = sig[32:]
+        pubkeys = [bytes(p) for p in pubkeys]
+        sigs = [bytes(s) for s in sigs]
+        lenok = np.ones((n,), np.bool_)
+        if not (all(len(p) == 32 for p in pubkeys)
+                and all(len(s) == 64 for s in sigs)):
+            # malformed rows: zeros in their place, rejected by `lenok`
+            lenok = np.array([len(p) == 32 and len(s) == 64
+                              for p, s in zip(pubkeys, sigs)], np.bool_)
+            pubkeys = [p if len(p) == 32 else _ZERO32 for p in pubkeys]
+            sigs = [s if len(s) == 64 else _ZERO32 * 2 for s in sigs]
+        pk_arr = np.frombuffer(b"".join(pubkeys), np.uint8).reshape(
+            n, 32).copy()
+        sig_arr = np.frombuffer(b"".join(sigs), np.uint8).reshape(n, 64)
+        r_arr = sig_arr[:, :32].copy()
+        s_arr = sig_arr[:, 32:].copy()
+        lenok &= (s_arr[:, 31] & 0x80) != 0  # schnorrkel's marker bit
         s_arr[:, 31] &= 0x7F
+        # the merlin / STROBE / keccak transcripts, one native call
+        with tracing.stage("sr25519.challenge", rows=n):
+            chal = batch_challenges([bytes(m) for m in msgs], pubkeys,
+                                    r_arr)
         # canonicality prechecks, vectorized: encodings < p and even,
         # s < L (same semantics as the reference's decode rejections)
         ok = (lenok & _below_p(pk_arr) & _below_p(r_arr)
               & ((pk_arr[:, 0] & 1) == 0) & ((r_arr[:, 0] & 1) == 0)
               & ek.s_below_l(s_arr))
         # k = challenge mod L: native batch reduce, bigint fallback
-        from cometbft_tpu import native
-
-        k_red = native.batch_reduce_mod_l(chal[:n])
+        k_red = native.batch_reduce_mod_l(chal)
         if k_red is None:
             k_red = np.zeros((n, 32), np.uint8)
             for i in range(n):
@@ -358,7 +364,12 @@ def pack_batch_sr(pubkeys, msgs, sigs, pad_to=None,
 
 
 def verify_batch(pubkeys, msgs, sigs) -> np.ndarray:
-    """Batch verify; (n,) bool. Drop-in for crypto/batch dispatch."""
+    """Batch verify in ONE pass padded by the bucket ladder; (n,) bool.
+    What crypto/batch reaches where no kernel was installed for
+    sr25519 (the verify plane's dispatcher, a bare verify_batch call);
+    the served commit check installs its own, which feeds the same
+    pack_batch_sr / verify_rows as chunks of one shape
+    (types/validation.device_batch_fn)."""
     n = len(pubkeys)
     rows = pack_batch_sr(pubkeys, msgs, sigs)
     return np.asarray(verify_rows(rows))[:n]

@@ -16,6 +16,7 @@ bit-for-bit and its early-break path in outcome.
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, List, Optional
 
 import numpy as np
@@ -328,17 +329,56 @@ def _verify_single(
 # Device batch_fn factories
 # --------------------------------------------------------------------------
 
-# Rows of one device pass of an ed25519 batch too large for one
-# (device_batch_fn). A larger batch is cut into chunks of exactly this
-# shape, each dispatched as soon as it is packed: the kernel sweeps
+# Rows of one device pass of a batch too large for one (device_batch_fn),
+# whatever its key type. A larger batch is cut into chunks of exactly
+# this shape, each dispatched as soon as it is packed: the kernel sweeps
 # ceil(n / T) * T rows where the bucket ladder's next rung may be 16,384
 # for 6,667, and every pack but the first runs while the device
 # verifies. Smaller shortens what nothing hides (the first pack, and
 # the last chunk's pass, which the host waits out) and the padded tail;
 # larger means fewer packs and dispatches, each with a fixed cost (0.3
-# ms a dispatch). Swept 512 to 4,096 on a v5e over the 6,667 rows of a
-# 10,000-validator commit (PERF.md section 6, PR 31).
+# ms a dispatch). Swept 512 to 4,096 on a v5e over the 6,667 ed25519
+# rows of a 10,000-validator commit (PERF.md section 6, PR 31).
 COMMIT_CHUNK_ROWS = 1024
+
+
+def _verify_chunked(kind, queue: list, pub_bytes, msgs, sigs):
+    """The crypto/batch kernel of one key type, `kind` = (name, pack,
+    run, one_pass): cuts its rows into chunks, packs each on the host
+    (`pack(pubs, msgs, sigs, pad)`) and hands it to the device
+    (`run(packed)`, which returns while the device works), and returns
+    the verdicts NOT YET FETCHED (cbatch.PendingVerdicts).
+
+    Up to COMMIT_CHUNK_ROWS rows it is ONE pass padded to `one_pass(n)`
+    rows, as ever; above, chunks of exactly COMMIT_CHUNK_ROWS, the
+    tail's too, each on the device while the host packs the next.
+    `queue` holds every pass this CALL of the batch_fn has dispatched,
+    of any key type: a mixed commit's second group packs while the
+    first group's chunks run. Stages `<name>.pack` / `.dispatch` per
+    chunk, `<name>.fetch` once (PendingVerdicts.fetch)."""
+    from cometbft_tpu.crypto import batch as cbatch
+
+    name, pack, run, one_pass = kind
+    n = len(pub_bytes)
+    pad = COMMIT_CHUNK_ROWS if n > COMMIT_CHUNK_ROWS else one_pass(n)
+    chunks = max(1, -(-n // pad))
+    outs = []
+    for k in range(chunks):
+        lo = k * pad
+        # flying: passes of this call the device has not finished as
+        # this one's pack starts (0 past the first: it ran dry)
+        at = {"rows": min(n - lo, pad), "chunk": k, "chunks": chunks,
+              "flying": sum(not o.is_ready() for o in queue)}
+        with tracing.stage(name + ".pack", padded=pad, **at):
+            packed = pack(pub_bytes[lo:lo + pad], msgs[lo:lo + pad],
+                          sigs[lo:lo + pad], pad)
+        # returns while the device runs
+        with tracing.stage(name + ".dispatch", **at):
+            outs.append(run(packed))
+        queue.append(outs[-1])
+    # what the host still waits for once nothing is left to dispatch,
+    # and the copy back, are verify_batch_direct's to ask for
+    return cbatch.PendingVerdicts(outs, n, name + ".fetch")
 
 
 def device_batch_fn(use_pallas: Optional[bool] = None,
@@ -348,75 +388,74 @@ def device_batch_fn(use_pallas: Optional[bool] = None,
     Returns fn(pubs: [PubKey], msgs, sigs) -> (n,) bool validity, with
     rows grouped by key type (crypto/batch.py dispatch): ed25519 via the
     Pallas kernel on TPU backends / XLA-composed kernel elsewhere
-    (interpret-mode Pallas on CPU is far slower than the XLA path),
-    secp256k1 via the ECDSA kernel. The voting-power tally stays host-
-    side here because VerifyCommit's early-break collection is inherently
-    sequential; the fused device tally serves the streaming paths
-    (blocksync replay) where whole commits are verified unconditionally.
+    (interpret-mode Pallas on CPU is far slower than the XLA path) and
+    sr25519 via its Pallas kernel, both fed as fixed-shape chunks
+    through one in-flight queue a call (_verify_chunked: every chunk of
+    both key types is dispatched before the first verdict is waited
+    for); secp256k1 via the ECDSA kernel in one pass. The voting-power
+    tally stays host-side here because VerifyCommit's early-break
+    collection is inherently sequential; the fused device tally serves
+    the streaming paths (blocksync replay) where whole commits are
+    verified unconditionally.
     """
     from cometbft_tpu.crypto import batch as cbatch
     from cometbft_tpu.ops import ed25519_kernel as ek
 
     if use_pallas is None:
         use_pallas = cbatch._accel_backend()
+    if use_pallas:
+        from cometbft_tpu.ops import ed25519_pallas as kp
 
-    def ed25519_verify(pub_bytes, msgs, sigs):
-        n = len(pub_bytes)
-        if use_pallas and cached and n >= 128:
-            # Cached-valset kernel (opt-in): ~3x the general kernel's
-            # steady-state throughput, but the window table is keyed on
-            # the EXACT pubkey list — callers must present a stable
-            # list (the full valset in order) or every call pays a
-            # table rebuild. The batch paths that guarantee stability
-            # (blocksync StreamVerifier) use it; the
-            # per-commit subset lists verify_commit_light produces
-            # would thrash the LRU, so the default stays general.
-            from cometbft_tpu.ops import ed25519_cached as ec
+        ed_kind = ("ed25519",
+                   lambda p, m, s, pad: kp.pack_rows(
+                       ek.pack_batch(p, m, s, pad_to=pad)),
+                   lambda rows: kp.verify_rows(rows), kp.pad_to_tile)
+    else:
+        ed_kind = ("ed25519",
+                   lambda p, m, s, pad: ek.pack_batch(p, m, s, pad_to=pad),
+                   lambda pb: ek.verify_kernel(
+                       pb.ay, pb.asign, pb.ry, pb.rsign, pb.sdig, pb.hdig,
+                       pb.precheck),
+                   lambda n: ek.bucket_size(max(n, 1)))
 
-            # packs, runs and fetches inside the one call
-            with tracing.stage("ed25519.dispatch", rows=n):
-                return ec.verify_batch_cached(pub_bytes, msgs, sigs)
-        if use_pallas:
-            from cometbft_tpu.ops import ed25519_pallas as kp
-        # up to one chunk: ONE pass padded by the bucket ladder, as ever;
-        # above it: chunks of ONE shape, the tail's too, each on the
-        # device while the host packs the next
-        if n > COMMIT_CHUNK_ROWS:
-            pad = COMMIT_CHUNK_ROWS
-        elif use_pallas:
-            pad = kp.pad_to_tile(n)
-        else:
-            pad = ek.bucket_size(max(n, 1))
-        chunks = max(1, -(-n // pad))
-        outs = []
-        for k in range(chunks):
-            lo = k * pad
-            # flying: chunks of this call the device has not finished
-            # as this one's pack starts (0 past the first: it ran dry)
-            at = {"rows": min(n - lo, pad), "chunk": k, "chunks": chunks,
-                  "flying": sum(not o.is_ready() for o in outs)}
-            with tracing.stage("ed25519.pack", padded=pad, **at):
-                pb = ek.pack_batch(pub_bytes[lo:lo + pad],
-                                   msgs[lo:lo + pad], sigs[lo:lo + pad],
-                                   pad_to=pad)
-                if use_pallas:
-                    pb = kp.pack_rows(pb)
-            # returns while the device runs
-            with tracing.stage("ed25519.dispatch", **at):
-                outs.append(
-                    kp.verify_rows(pb) if use_pallas else
-                    ek.verify_kernel(pb.ay, pb.asign, pb.ry, pb.rsign,
-                                     pb.sdig, pb.hdig, pb.precheck))
-        # what the host still waits for once it has nothing left to do,
-        # and the copy back: every chunk's verdicts, in row order
-        with tracing.stage("ed25519.fetch"):
-            valid = np.concatenate([np.asarray(o) for o in outs])
-        return valid[:n]
+    def srk():  # first used by a batch that holds an sr25519 row
+        from cometbft_tpu.ops import sr25519_kernel
+
+        return sr25519_kernel
+
+    # sr25519 has the one kernel: Pallas, interpreted on a CPU backend
+    sr_kind = ("sr25519",
+               lambda p, m, s, pad: srk().pack_batch_sr(p, m, s, pad_to=pad),
+               lambda rows: srk().verify_rows(rows),
+               lambda n: srk().kp.pad_to_tile(n))
+
+    def ed25519_cached(pub_bytes, msgs, sigs):
+        # Cached-valset kernel (opt-in): ~3x the general kernel's
+        # steady-state throughput, but the window table is keyed on
+        # the EXACT pubkey list — callers must present a stable
+        # list (the full valset in order) or every call pays a
+        # table rebuild. The batch paths that guarantee stability
+        # (blocksync StreamVerifier) use it; the
+        # per-commit subset lists verify_commit_light produces
+        # would thrash the LRU, so the default stays general.
+        from cometbft_tpu.ops import ed25519_cached as ec
+
+        # packs, runs and fetches inside the one call
+        with tracing.stage("ed25519.dispatch", rows=len(pub_bytes)):
+            return ec.verify_batch_cached(pub_bytes, msgs, sigs)
 
     def fn(pubs, msgs, sigs):
-        return cbatch.verify_batch(
-            pubs, msgs, sigs, kernels={"ed25519": ed25519_verify}
-        )
+        queue = []  # this call's passes, of either key type
+        ed = functools.partial(_verify_chunked, ed_kind, queue)
+
+        def ed25519_verify(pub_bytes, msgs, sigs):
+            if use_pallas and cached and len(pub_bytes) >= 128:
+                return ed25519_cached(pub_bytes, msgs, sigs)
+            return ed(pub_bytes, msgs, sigs)
+
+        return cbatch.verify_batch(pubs, msgs, sigs, kernels={
+            "ed25519": ed25519_verify,
+            "sr25519": functools.partial(_verify_chunked, sr_kind, queue)})
 
     return fn
 

@@ -90,3 +90,24 @@ def checked_valset_roots(monkeypatch):
 
     monkeypatch.setattr(ValidatorSet, "hash", checked)
     return roots
+
+
+# ---------------------------------------------------------------------------
+# The benchmark's plain reference (benchmarks/reference/: OpenSSL and
+# plain integers, nothing of the program) also referees tier-1 tests of
+# what it referees on the chip. Imported as benchmarks/run.py imports
+# it, from its own directory, when a test first asks.
+
+
+@pytest.fixture(scope="session")
+def plain_reference():
+    import sys
+    from types import SimpleNamespace
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from reference import plain, schnorrkel
+
+    return SimpleNamespace(plain=plain, schnorrkel=schnorrkel)

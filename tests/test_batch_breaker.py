@@ -174,6 +174,83 @@ def test_fault_in_a_later_chunk_is_one_fault_and_host_verdicts(
         "open", 1, 1)
 
 
+class _Verdicts:
+    """A pass's verdicts as verify_batch_direct sees a device array:
+    fetched by np.asarray, which is where a fault of the wait shows."""
+
+    def __init__(self, valid, log, tag, sick=False):
+        self.valid, self.log, self.tag, self.sick = valid, log, tag, sick
+
+    def __array__(self, dtype=None, copy=None):
+        self.log.append(("fetch", self.tag))
+        if self.sick:
+            raise RuntimeError("device lost")
+        return np.asarray(self.valid)
+
+
+@pytest.mark.parametrize("where", ["dispatch", "fetch"])
+@pytest.mark.parametrize("sick", ["ed25519", "sr25519"])
+def test_a_pending_groups_fault_is_its_own(monkeypatch, sick, where):
+    """Kernels that return their verdicts not yet fetched
+    (PendingVerdicts): both groups are dispatched before either is
+    fetched; a fault at dispatch or at the fetch re-verifies only THAT
+    group on the host and counts one breaker fault, and the other
+    group's verdicts are the device's."""
+    from cometbft_tpu.crypto import sr25519_ref as sr
+    from cometbft_tpu.crypto.keys import Sr25519PrivKey
+
+    pubs, msgs, sigs, exp = make_batch()
+    sk = Sr25519PrivKey.generate(b"\x31" * 32)
+    for i in range(3):
+        pubs.append(sk.pub_key())
+        msgs.append(b"pending-%d" % i)
+        sigs.append(sk.sign(b"pending-%d" % (i if i != 1 else 9)))
+        exp.append(i != 1)
+    order = [6, 0, 1, 7, 2, 3, 8, 4, 5]  # sr25519 first, interleaved
+    pubs, msgs, sigs, exp = ([x[i] for i in order]
+                             for x in (pubs, msgs, sigs, exp))
+    log = []
+
+    def kernel(kt, oracle):
+        def run(pub_bytes, ms, ss):
+            log.append(("dispatch", kt))
+            if kt == sick and where == "dispatch":
+                raise RuntimeError("device lost")
+            valid = [oracle(p, m, s) for p, m, s in zip(pub_bytes, ms, ss)]
+            half = len(valid) // 2  # two passes, in row order
+            return cbatch.PendingVerdicts(
+                [_Verdicts(valid[:half], log, kt),
+                 _Verdicts(valid[half:], log, kt,
+                           kt == sick and where == "fetch")],
+                len(valid), kt + ".fetch")
+        return run
+
+    on_host = []
+    real = cbatch._host_verify_rows
+    monkeypatch.setattr(
+        cbatch, "_host_verify_rows",
+        lambda p, m, s, idxs, valid: (on_host.append(list(idxs)),
+                                      real(p, m, s, idxs, valid))[1])
+    brk = cbatch.CircuitBreaker(failure_threshold=5)
+    got = cbatch.verify_batch(
+        pubs, msgs, sigs, breaker=brk,
+        kernels={"ed25519": kernel("ed25519", ed.verify),
+                 "sr25519": kernel("sr25519", sr.verify)})
+    np.testing.assert_array_equal(got, np.asarray(exp))
+    assert brk.faults == 1 and brk.state == "closed"
+    assert on_host == [[i for i, p in enumerate(pubs)
+                        if p.key_type == sick]]
+    # every dispatch of the call, then the fetches in group order
+    assert log[:2] == [("dispatch", "sr25519"), ("dispatch", "ed25519")]
+    fetched = [kt for kind, kt in log[2:] if kind == "fetch"]
+    assert len(log) == 2 + len(fetched)
+    healthy = "ed25519" if sick == "sr25519" else "sr25519"
+    assert fetched == {
+        "dispatch": [healthy] * 2,
+        # the sick group's first pass came back, its second raised
+        "fetch": ["sr25519"] * 2 + ["ed25519"] * 2}[where]
+
+
 def test_breaker_config_knobs():
     from cometbft_tpu.config.config import Config, ConfigError
 

@@ -148,6 +148,109 @@ def test_kernel_rejects_bad_encodings():
     assert not exp[1] and not exp[2] and not exp[4]
 
 
+def _pack_batch_sr_row_by_row(pubkeys, msgs, sigs, pad_to=None):
+    """pack_batch_sr as it stood before the served commit check fed it
+    chunks (ISSUE 33), kept as the reference of the vectorised intake:
+    a Python loop over the well-formed rows."""
+    from cometbft_tpu import native
+    from cometbft_tpu.ops import ed25519_kernel as ek
+    from cometbft_tpu.ops import ed25519_pallas as kp
+    from cometbft_tpu.ops import sr25519_kernel as srk
+    from cometbft_tpu.ops.field import NLIMBS, F25519
+
+    n = len(pubkeys)
+    pad = pad_to or kp.pad_to_tile(n)
+    a_l = np.zeros((pad, NLIMBS), np.int32)
+    r_l = np.zeros((pad, NLIMBS), np.int32)
+    sdig = np.zeros((pad, 64), np.int32)
+    hdig = np.zeros((pad, 64), np.int32)
+    precheck = np.zeros((pad,), np.int32)
+    r_encs = [bytes(s[:32]) if len(s) == 64 else b"\x00" * 32 for s in sigs]
+    chal = srk.batch_challenges(
+        [bytes(m) for m in msgs], [bytes(p) for p in pubkeys], r_encs)
+    lenok = np.array(
+        [len(pubkeys[i]) == 32 and len(sigs[i]) == 64
+         and bool(sigs[i][63] & 0x80) for i in range(n)], np.bool_)
+    pk_arr = np.zeros((n, 32), np.uint8)
+    r_arr = np.zeros((n, 32), np.uint8)
+    s_arr = np.zeros((n, 32), np.uint8)
+    for i in np.flatnonzero(lenok):
+        pk_arr[i] = np.frombuffer(bytes(pubkeys[i]), np.uint8)
+        sig = np.frombuffer(bytes(sigs[i]), np.uint8)
+        r_arr[i] = sig[:32]
+        s_arr[i] = sig[32:]
+    s_arr[:, 31] &= 0x7F
+    ok = (lenok & srk._below_p(pk_arr) & srk._below_p(r_arr)
+          & ((pk_arr[:, 0] & 1) == 0) & ((r_arr[:, 0] & 1) == 0)
+          & ek.s_below_l(s_arr))
+    k_red = native.batch_reduce_mod_l(chal[:n])
+    if k_red is None:
+        k_red = np.zeros((n, 32), np.uint8)
+        for i in range(n):
+            k_red[i] = np.frombuffer(
+                (int.from_bytes(bytes(chal[i]), "little")
+                 % ed.L).to_bytes(32, "little"), np.uint8)
+    bad = ~ok
+    for arr in (pk_arr, r_arr, s_arr, k_red):
+        arr[bad] = 0
+    a_l[:n] = F25519.from_bytes_le(pk_arr)
+    r_l[:n] = F25519.from_bytes_le(r_arr)
+    sdig[:n] = ek.nibbles(s_arr)
+    hdig[:n] = ek.nibbles(k_red)
+    precheck[:n] = ok.astype(np.int32)
+    pb = kp._PB(a_l, np.zeros((pad,), np.int32), r_l,
+                np.zeros((pad,), np.int32), sdig, hdig, precheck)
+    pb.n = n
+    return kp.pack_rows(pb)
+
+
+def _damaged(n):
+    """Honest rows, and among them every kind the host prechecks
+    reject: a flipped bit (passes them), no marker, s >= L, encodings
+    not below p or odd, a short and an empty signature, messages of
+    two lengths."""
+    pubs, msgs, sigs = _fixture(n, bad=(2,))
+    msgs[3] += b"-longer"
+    sigs[3] = Sr25519PrivKey.generate(b"\x04" * 32).sign(msgs[3])
+    sigs[5] = sigs[5][:63] + bytes([sigs[5][63] & 0x7F])
+    sigs[6] = sigs[6][:32] + (ed.L + 5).to_bytes(32, "little")[:31] + \
+        bytes([0x80 | (ed.L + 5).to_bytes(32, "little")[31]])
+    sigs[7] = (rist.P + 4).to_bytes(32, "little") + sigs[7][32:]
+    sigs[8] = b"\x03" + sigs[8][1:]
+    pubs[9] = (rist.P + 2).to_bytes(32, "little")
+    pubs[10] = b"\x05" + pubs[10][1:]
+    sigs[11] = sigs[11][:40]
+    sigs[12] = b""
+    return pubs, msgs, sigs
+
+
+_SHAPES = [(0, None), (1, None), (40, None), (40, 128), (130, 256),
+           (128, 128)]
+
+
+@pytest.mark.parametrize("rows,n,pad_to", [
+    (rows, n, pad_to) for rows in ("honest", "damaged")
+    for n, pad_to in _SHAPES if rows == "honest" or n >= 13])
+def test_pack_batch_sr_packs_what_it_always_packed(rows, n, pad_to):
+    """Byte for byte, whatever the padding: the vectorised intake of
+    well-formed rows against the row-by-row loop it replaced."""
+    from cometbft_tpu.ops import ed25519_pallas as kp
+    from cometbft_tpu.ops import sr25519_kernel as srk
+
+    pubs, msgs, sigs = _damaged(n) if rows == "damaged" else _fixture(n)
+    got = srk.pack_batch_sr(pubs, msgs, sigs, pad_to=pad_to)
+    want = _pack_batch_sr_row_by_row(pubs, msgs, sigs, pad_to=pad_to)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert got.shape[1] == (pad_to or kp.pad_to_tile(n))
+    np.testing.assert_array_equal(got, want)
+    live = (got[kp.C_FLAGS, :n] >> 2) & 1
+    if rows == "damaged":  # only the prechecks' rejections are flagged
+        assert sorted(np.flatnonzero(live == 0)) == [5, 6, 7, 8, 9, 10,
+                                                     11, 12]
+    else:
+        assert live.all()
+
+
 def _mixed_fixture():
     from cometbft_tpu.crypto.keys import PrivKey
 
@@ -218,3 +321,32 @@ def test_mixed_batch_dispatch_grouping(monkeypatch):
     # one kernel lookup per key-type group, both groups routed
     assert sorted(routed) == sorted([ED25519_KEY_TYPE,
                                      SR25519_KEY_TYPE])
+
+
+@pytest.mark.slow  # the interpreted sr25519 kernel compiles for ~85 s
+# at its one 128-row tile; tests/test_validation.py keeps the chunked
+# seam quick behind a host stand-in
+def test_served_chunks_through_the_true_kernel(monkeypatch):
+    """A mixed batch through validation.device_batch_fn with the true
+    sr25519 kernel: more sr25519 rows than one chunk (patched to the
+    kernel's tile, 128, so two chunks of ONE compiled shape), a bad row
+    in each chunk and one among the ed25519 rows."""
+    from cometbft_tpu.libs import tracing
+    from cometbft_tpu.types import validation
+
+    monkeypatch.setattr(validation, "COMMIT_CHUNK_ROWS", 128)
+    pubs, msgs, sigs, exp = _mixed_fixture()
+    sr_pubs, sr_msgs, sr_sigs = _fixture(16, bad=(3,))
+    from cometbft_tpu.crypto.keys import PubKey
+
+    pubs += [PubKey(p, SR25519_KEY_TYPE) for p in sr_pubs] * 9
+    msgs += sr_msgs * 9
+    sigs += sr_sigs * 9
+    exp = np.concatenate([exp, np.tile(np.arange(16) != 3, 9)])
+    tracing.set_clock(None)  # an empty stage ring
+    got = validation.device_batch_fn(use_pallas=False)(pubs, msgs, sigs)
+    assert (got == exp).all()
+    packs = [r[4] for r in tracing.stage_records()
+             if r[0] == "sr25519.pack"]
+    assert [(p["rows"], p["padded"]) for p in packs] == [(128, 128),
+                                                         (20, 128)]

@@ -333,3 +333,218 @@ def test_chunk_stages_pack_and_dispatch_in_turn_then_one_fetch(
         assert p[1] + p[2] <= d[1]  # packed, then dispatched
     assert recs[0][4]["flying"] == 0  # nothing flies before the first
     assert recs[-1][4] == {} and recs[5][1] + recs[5][2] <= recs[-1][1]
+
+
+# --------------------------------------------------------------------------
+# device_batch_fn over a commit of two key types: one queue for both
+# --------------------------------------------------------------------------
+
+
+class LazyVerdicts:
+    """What a kernel's dispatch hands back, as the chunk loop sees a
+    jax.Array: not ready until fetched. `log` gets one entry a fetch."""
+
+    def __init__(self, valid, log, tag):
+        self.valid, self.log, self.tag = np.asarray(valid), log, tag
+        self.fetched = False
+
+    def is_ready(self):
+        return self.fetched
+
+    def __array__(self, dtype=None, copy=None):
+        self.fetched = True
+        self.log.append(("fetch", self.tag))
+        return self.valid
+
+
+@pytest.fixture
+def sr_standin(monkeypatch):
+    """A host stand-in for the sr25519 kernel (the interpreted Pallas
+    kernel compiles for 85 s): the true pack runs, and `verify_rows`
+    answers for the rows that pack was given with sr25519_ref's
+    verdicts, padded to the packed width and not ready until fetched.
+    Returns the log: ("dispatch", "sr25519", padded rows) and
+    ("fetch", "sr25519"), in order."""
+    from cometbft_tpu.crypto import sr25519_ref as sr
+    from cometbft_tpu.ops import sr25519_kernel as srk
+
+    log, packed, memo = [], {}, {}
+    real_pack = srk.pack_batch_sr
+
+    def verdict(*row):
+        if row not in memo:
+            memo[row] = sr.verify(*row)
+        return memo[row]
+
+    def pack(pubs, msgs, sigs, pad_to=None):
+        rows = real_pack(pubs, msgs, sigs, pad_to=pad_to)
+        packed[id(rows)] = (rows, [verdict(*r)
+                                   for r in zip(pubs, msgs, sigs)])
+        return rows
+
+    def verify_rows(rows):
+        kept, valid = packed.pop(id(rows))
+        assert kept is rows
+        width = rows.shape[1]
+        log.append(("dispatch", "sr25519", width))
+        return LazyVerdicts(valid + [False] * (width - len(valid)), log,
+                            "sr25519")
+
+    monkeypatch.setattr(srk, "pack_batch_sr", pack)
+    monkeypatch.setattr(srk, "verify_rows", verify_rows)
+    return log
+
+
+@pytest.fixture
+def ed_standin(monkeypatch, sr_standin):
+    """The XLA ed25519 kernel's stand-in in the same log: a row passes
+    its precheck, not ready until fetched."""
+    from cometbft_tpu.ops import ed25519_kernel as ek
+
+    def fake(ay, asign, ry, rsign, sdig, hdig, precheck):
+        sr_standin.append(("dispatch", "ed25519", len(precheck)))
+        return LazyVerdicts(np.asarray(precheck) != 0, sr_standin,
+                            "ed25519")
+
+    monkeypatch.setattr(ek, "verify_kernel", fake)
+    return sr_standin
+
+
+def make_mixed_commit(n_vals=48, invalid=(), absent=()):
+    """make_commit over a set whose validators hold ed25519 and sr25519
+    keys in turn (by seed; the set's own order interleaves them). Also
+    returns what the benchmark's plain reference is given: pubs, key
+    types, powers, sign-bytes and signatures (None = absent) by index."""
+    from cometbft_tpu.crypto.keys import Sr25519PrivKey
+
+    privs = [(Sr25519PrivKey if i % 2 else PrivKey).generate(
+        bytes([i + 1]) * 32) for i in range(n_vals)]
+    vs = ValidatorSet([Validator(p.pub_key(), 100) for p in privs])
+    by_addr = {p.pub_key().address(): p for p in privs}
+    bid = BlockID(b"\xab" * 32, PartSetHeader(2, b"\xcd" * 32))
+    sigs, plain = [], []
+    for idx, v in enumerate(vs.validators):
+        ts = Timestamp(1700000000 + idx, idx)
+        sb = canonical.canonical_vote_bytes(
+            CHAIN_ID, canonical.PRECOMMIT_TYPE, HEIGHT, 2, bid, ts)
+        sig = by_addr[v.address].sign(sb)
+        if idx in invalid:
+            sig = sig[:10] + bytes([sig[10] ^ 1]) + sig[11:]
+        if idx in absent:
+            sigs.append(CommitSig.absent())
+        else:
+            sigs.append(CommitSig(BLOCK_ID_FLAG_COMMIT, v.address, ts, sig))
+        plain.append((v.pub_key.data, v.pub_key.key_type, 100, sb,
+                      None if idx in absent else sig))
+    return vs, Commit(HEIGHT, 2, bid, sigs), bid, plain
+
+
+def first_of(plain, key_type, after=0):
+    return next(i for i, row in enumerate(plain)
+                if row[1] == key_type and i >= after)
+
+
+@pytest.mark.parametrize("case", ["ok", "bad-ed25519", "bad-sr25519",
+                                  "both-bad", "not-enough-power"])
+def test_mixed_commit_outcome_is_the_plain_references(
+        sr_standin, plain_reference, case):
+    """A 48-validator commit of both key types through
+    verify_commit_light and device_batch_fn, against
+    benchmarks/reference/schnorrkel.verify_commit_light: the blame is
+    the COMMIT's index, whichever group holds the bad row."""
+    _, _, _, plain = make_mixed_commit()
+    ed_at, sr_at = first_of(plain, "ed25519"), first_of(plain, "sr25519")
+    kw = {"ok": {}, "bad-ed25519": {"invalid": (ed_at,)},
+          "bad-sr25519": {"invalid": (sr_at,)},
+          # the sr25519 row comes first in the commit, second in the
+          # batch's groups: the commit's order decides
+          "both-bad": {"invalid": (first_of(plain, "ed25519", sr_at + 1),
+                                   sr_at)},
+          "not-enough-power": {"absent": tuple(range(0, 48, 3))}}[case]
+    vs, commit, bid, plain = make_mixed_commit(**kw)
+    want = plain_reference.schnorrkel.verify_commit_light(*zip(*plain))
+    try:
+        validation.verify_commit_light(
+            CHAIN_ID, vs, bid, HEIGHT, commit,
+            validation.device_batch_fn(use_pallas=False))
+        got = ("ok",)
+    except validation.InvalidSignatureError as e:
+        got = ("invalid_signature", e.idx)
+    except validation.NotEnoughPowerError as e:
+        got = ("not_enough_power", e.needed)
+    assert got == want
+    assert got[0] == {"ok": "ok", "not-enough-power": "not_enough_power"
+                      }.get(case, "invalid_signature")
+    if case == "both-bad":
+        assert got[1] == sr_at
+    # one pass a key type (33 rows examined, 16 or 17 of each type),
+    # each padded by the ladder as before; no pass where power lacked
+    assert [e for e in sr_standin if e[0] == "dispatch"] == (
+        [] if case == "not-enough-power" else [("dispatch", "sr25519", 128)])
+
+
+def test_mixed_chunks_share_one_queue(chunked, ed_standin):
+    """Both groups are cut into chunks of the one shape; every chunk of
+    both is dispatched before the first verdict is fetched; `flying`
+    counts the chunks of either key type."""
+    from cometbft_tpu.crypto.keys import Sr25519PrivKey
+    from cometbft_tpu.libs import tracing
+
+    ed_pub, ed_msg, ed_sig = (x[0] for x in make_rows(1))
+    sk = Sr25519PrivKey.generate(b"\x21" * 32)
+    n_ed, n_sr = 2 * T + 5, T + 3
+    pubs = [ed_pub] * n_ed + [sk.pub_key()] * n_sr
+    msgs = [ed_msg] * n_ed + [b"queue"] * n_sr
+    sigs = [ed_sig] * n_ed + [sk.sign(b"queue")] * n_sr
+    # interleaved as a commit's rows are; groups keep the batch's order
+    order = np.random.RandomState(5).permutation(n_ed + n_sr)
+    tracing.set_clock(None)  # an empty stage ring
+    got = validation.device_batch_fn(use_pallas=False)(
+        [pubs[i] for i in order], [msgs[i] for i in order],
+        [sigs[i] for i in order])
+    assert got.shape == (n_ed + n_sr,) and got.all()
+    first = pubs[order[0]].key_type  # groups go in order of appearance
+    second = "sr25519" if first == "ed25519" else "ed25519"
+    chunks = {"ed25519": 3, "sr25519": 2}
+    assert ed_standin == (
+        [("dispatch", first, T)] * chunks[first]
+        + [("dispatch", second, T)] * chunks[second]
+        + [("fetch", first)] * chunks[first]
+        + [("fetch", second)] * chunks[second])
+    recs = [r for r in tracing.stage_records()
+            if r[0] != "sr25519.challenge"]
+    names = [r[0] for r in recs]
+    assert names == (
+        [first + ".pack", first + ".dispatch"] * chunks[first]
+        + [second + ".pack", second + ".dispatch"] * chunks[second]
+        + [first + ".fetch", second + ".fetch"])
+    packs = [r[4] for r in recs if r[0].endswith(".pack")]
+    assert [p["flying"] for p in packs] == [0, 1, 2, 3, 4]
+    assert [p["padded"] for p in packs] == [T] * 5
+    assert [(p["chunk"], p["chunks"]) for p in packs] == (
+        [(k, chunks[first]) for k in range(chunks[first])]
+        + [(k, chunks[second]) for k in range(chunks[second])])
+    rows = {"ed25519": [T, T, 5], "sr25519": [T, 3]}
+    assert [p["rows"] for p in packs] == rows[first] + rows[second]
+    # the merlin transcripts: a stage inside each sr25519 pack
+    chal = [r for r in tracing.stage_records()
+            if r[0] == "sr25519.challenge"]
+    sr_packs = [r for r in recs if r[0] == "sr25519.pack"]
+    assert len(chal) == len(sr_packs) == 2
+    for c, p in zip(chal, sr_packs):
+        assert p[1] <= c[1] and c[1] + c[2] <= p[1] + p[2]
+        assert c[4]["rows"] == p[4]["rows"]
+
+
+def test_an_ed25519_commit_records_the_stages_it_always_did(kernel_calls):
+    from cometbft_tpu.libs import tracing
+
+    vs, commit, bid = make_commit(n_vals=6)
+    tracing.set_clock(None)  # an empty stage ring
+    validation.verify_commit_light(
+        CHAIN_ID, vs, bid, HEIGHT, commit,
+        validation.device_batch_fn(use_pallas=False))
+    assert [r[0] for r in tracing.stage_records()] == [
+        "commit.collect", "commit.sign_bytes", "ed25519.pack",
+        "ed25519.dispatch", "ed25519.fetch", "commit.batch_fn",
+        "commit.verify"]
