@@ -47,6 +47,7 @@ from cometbft_tpu.crypto.keys import (
 from cometbft_tpu.libs import failpoints as fp
 from cometbft_tpu.libs import tracing
 from cometbft_tpu.libs.staging import StagingPool
+from cometbft_tpu.types.canonical import TemplateRows
 
 _log = logging.getLogger(__name__)
 
@@ -331,7 +332,9 @@ def verify_batch_direct(
                               key_type=kt, rows=len(idxs)):
                 sub = kernel(
                     [pubs[i].data for i in idxs],
-                    [msgs[i] for i in idxs],
+                    # a commit's lazy rows stay lazy: no bytes here
+                    msgs.take(idxs) if isinstance(msgs, TemplateRows)
+                    else [msgs[i] for i in idxs],
                     [sigs[i] for i in idxs],
                 )
             if isinstance(sub, PendingVerdicts):

@@ -32,6 +32,7 @@ import numpy as np
 from cometbft_tpu.crypto import ed25519_ref as ref
 from cometbft_tpu.ops import curve25519 as curve
 from cometbft_tpu.ops.field import F25519, NLIMBS
+from cometbft_tpu.types import canonical
 
 F = F25519
 
@@ -223,6 +224,36 @@ def pack_batch(
     return PackedBatch(n, padded, ay, asign, ry, rsign, sdig, hdig, precheck)
 
 
+def pack_templated(pubkeys: Sequence[bytes], msgs: Sequence[bytes],
+                   sigs: Sequence[bytes], pad_to: Optional[int] = None):
+    """pack_batch for messages that may be a canonical.TemplateRows
+    (a commit's sign-bytes as templates and a timestamp a row):
+    returns (PackedBatch, templated).
+
+    Where they are, the native library loads and every key is 32 and
+    every signature 64 bytes long, the rows' sign-bytes are built
+    inside the SHA-512 that hashes them (native.ed25519_pack_commits)
+    and never exist as Python objects: templated is True. Anything
+    else is pack_batch over the list of bytes, with its screens and
+    its numpy fallback, and templated False. The arrays are the same
+    either way (tests/test_sign_template.py)."""
+    from cometbft_tpu import native
+
+    n = len(pubkeys)
+    padded = pad_to if pad_to is not None else bucket_size(max(n, 1))
+    if isinstance(msgs, canonical.TemplateRows):
+        if (n and set(map(len, pubkeys)) == {32}
+                and set(map(len, sigs)) == {64}):
+            packed = native.ed25519_pack_commits(
+                b"".join(pubkeys), b"".join(sigs),
+                [t.template for t in msgs.templates],
+                msgs.tmpl, msgs.secs, msgs.nanos, padded)
+            if packed is not None:
+                return PackedBatch(n, padded, *packed), True
+        msgs = list(msgs)
+    return pack_batch(pubkeys, msgs, sigs, pad_to=padded), False
+
+
 # --------------------------------------------------------------------------
 # Device kernel
 # --------------------------------------------------------------------------
@@ -319,7 +350,7 @@ def verify_batch(pubkeys, msgs, sigs) -> np.ndarray:
     The device-side analog of crypto/ed25519/ed25519.go:236 Verify()'s
     per-signature valid slice (the blame path of types/validation.go:243
     needs exactly this)."""
-    pb = pack_batch(pubkeys, msgs, sigs)
+    pb, _ = pack_templated(pubkeys, msgs, sigs)
     valid = verify_kernel(
         pb.ay, pb.asign, pb.ry, pb.rsign, pb.sdig, pb.hdig, pb.precheck
     )

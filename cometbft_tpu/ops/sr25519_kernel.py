@@ -56,6 +56,7 @@ from cometbft_tpu.ops.ed25519_pallas import (
 )
 from cometbft_tpu.ops.field import NLIMBS, F25519
 from cometbft_tpu.ops.field_lf import const_col, interpret_mode
+from cometbft_tpu.types import canonical
 
 
 def rist_decode(s, d_col, sqrt_m1_col):
@@ -235,9 +236,28 @@ def _below_p(b: np.ndarray) -> np.ndarray:
 
 
 
+def _length_groups(msgs):
+    """(row indices, their messages as one (k, ln) uint8 matrix) for
+    each message length `ln`: rows of a canonical.SignRows matrix are
+    selected by length, a list of bytes is joined a group."""
+    if isinstance(msgs, canonical.SignRows):
+        for ln in np.unique(msgs.lens):
+            idxs = np.flatnonzero(msgs.lens == ln)
+            yield idxs, msgs.mat[idxs, :ln]
+        return
+    groups = {}
+    for i, m in enumerate(msgs):
+        groups.setdefault(len(m), []).append(i)
+    for ln, idxs in groups.items():
+        yield idxs, np.frombuffer(
+            b"".join(msgs[i] for i in idxs), np.uint8
+        ).reshape(len(idxs), ln)
+
+
 def batch_challenges(msgs, pubs, r_encs) -> np.ndarray:
     """Merlin challenge scalars for a batch, vectorized by message length.
 
+    `msgs` is n byte strings or a canonical.SignRows matrix of n rows;
     `pubs` and `r_encs` are n 32-byte strings each, or (n, 32) uint8.
     Returns (n, 64) uint8 of raw challenge bytes (reduce mod L happens in
     the nibble pack). Groups rows by len(msg): within a group the
@@ -252,17 +272,11 @@ def batch_challenges(msgs, pubs, r_encs) -> np.ndarray:
         a if isinstance(a, np.ndarray) else
         np.frombuffer(b"".join(a), np.uint8).reshape(n, 32)
         for a in (pubs, r_encs))
-    groups = {}
-    for i, m in enumerate(msgs):
-        groups.setdefault(len(m), []).append(i)
     use_native = native.available()
-    for ln, idxs in groups.items():
-        marr = np.frombuffer(
-            b"".join(msgs[i] for i in idxs), np.uint8
-        ).reshape(len(idxs), ln) if ln else np.empty((len(idxs), 0), np.uint8)
+    for idxs, marr in _length_groups(msgs):
         parr, rarr = pk_all[idxs], r_all[idxs]
         ch = None
-        if use_native and ln > 0:
+        if use_native and marr.shape[1] > 0:
             # whole transcripts in one C call (the numpy BatchStrobe
             # below paid ~70 ms of python/numpy op dispatch per 5k-row
             # commit — the round-4 mixed-commit host bottleneck); BatchStrobe
@@ -328,10 +342,16 @@ def pack_batch_sr(pubkeys, msgs, sigs, pad_to=None,
         s_arr = sig_arr[:, 32:].copy()
         lenok &= (s_arr[:, 31] & 0x80) != 0  # schnorrkel's marker bit
         s_arr[:, 31] &= 0x7F
+        # merlin hashes the message bytes: a commit's lazy rows become
+        # one matrix (no bytes object a row) unless the caller expanded
+        # them already, anything else a list
+        if isinstance(msgs, canonical.TemplateRows):
+            msgs = msgs.expand()
+        elif not isinstance(msgs, canonical.SignRows):
+            msgs = [bytes(m) for m in msgs]
         # the merlin / STROBE / keccak transcripts, one native call
         with tracing.stage("sr25519.challenge", rows=n):
-            chal = batch_challenges([bytes(m) for m in msgs], pubkeys,
-                                    r_arr)
+            chal = batch_challenges(msgs, pubkeys, r_arr)
         # canonicality prechecks, vectorized: encodings < p and even,
         # s < L (same semantics as the reference's decode rejections)
         ok = (lenok & _below_p(pk_arr) & _below_p(r_arr)

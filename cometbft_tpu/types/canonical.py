@@ -9,6 +9,7 @@ TestVoteSignBytesTestVectors — replicated in tests/test_canonical.py.
 """
 from __future__ import annotations
 
+from collections import abc
 from typing import Optional, Sequence
 
 import numpy as np
@@ -115,6 +116,8 @@ def _vec_uvarint(vals: np.ndarray):
         out[:, j] = (x & np.uint64(0x7F)).astype(np.uint8)
         x >>= np.uint64(7)
         cont = x != 0
+        if not cont.any():  # every row ended: the rest stays zero
+            break
         out[:, j] |= cont.astype(np.uint8) << 7
         lens += cont.astype(np.int32)
     return out, lens
@@ -133,6 +136,13 @@ class SignRows:
 
     def __len__(self) -> int:
         return self.mat.shape[0]
+
+    def __getitem__(self, i):
+        """rows[lo:hi]: that run of the rows (a chunk of them), as
+        views; rows[i]: row i's bytes."""
+        if isinstance(i, slice):
+            return SignRows(self.mat[i], self.lens[i])
+        return self.row(i)
 
     def row(self, i: int) -> bytes:
         return self.mat[i, : self.lens[i]].tobytes()
@@ -329,41 +339,106 @@ class VoteRowTemplate:
         P, S = self._pre_arr.size, self._suf_arr.size
         sb, sl = _vec_uvarint(secs)
         nb, nl = _vec_uvarint(nanos)
-        s_nz = secs != 0
-        n_nz = nanos != 0
-        sfl = np.where(s_nz, sl + 1, 0)      # field-1 bytes (tag + varint)
-        nfl = np.where(n_nz, nl + 1, 0)      # field-2 bytes
+        sfl = np.where(secs != 0, sl + 1, 0)  # field-1 bytes (tag + varint)
+        nfl = np.where(nanos != 0, nl + 1, 0)  # field-2 bytes
         ts_len = sfl + nfl                   # Timestamp body (< 128)
         body_len = P + 2 + ts_len + S        # + tag(5) + 1-byte msg len
         ob, ol = _vec_uvarint(body_len)
         total = ol + body_len
         mat = np.zeros((n, int(total.max()) if n else 0), np.uint8)
-        r = np.arange(n)
-        for j in range(int(ol.max()) if n else 0):
-            m = ol > j
-            mat[m, j] = ob[m, j]
-        off = ol.astype(np.int64)
-        if P:
-            mat[r[:, None], off[:, None] + np.arange(P)] = self._pre_arr
-        off += P
-        mat[r, off] = self.TS_TAG
-        mat[r, off + 1] = ts_len.astype(np.uint8)
-        off += 2
-        if s_nz.any():
-            mat[r[s_nz], off[s_nz]] = 0x08   # tag(1, VARINT)
-            for j in range(int(sl[s_nz].max())):
-                m = s_nz & (sl > j)
-                mat[r[m], off[m] + 1 + j] = sb[m, j]
-        off = off + sfl
-        if n_nz.any():
-            mat[r[n_nz], off[n_nz]] = 0x10   # tag(2, VARINT)
-            for j in range(int(nl[n_nz].max())):
-                m = n_nz & (nl > j)
-                mat[r[m], off[m] + 1 + j] = nb[m, j]
-        off = off + nfl
-        if S:
-            mat[r[:, None], off[:, None] + np.arange(S)] = self._suf_arr
+        # Rows of one LAYOUT (the widths of the two timestamp fields,
+        # which fix the length prefix's too) hold every part at the
+        # same columns: each layout's rows are filled by column slices.
+        # A commit's clustered timestamps make a handful of layouts.
+        layout = sfl * 16 + nfl              # each at most 11
+        for key in np.unique(layout):
+            rows = np.flatnonzero(layout == key)
+            a, b = divmod(int(key), 16)
+            o = int(ol[rows[0]])
+            blk = np.empty((rows.size, o + P + 2 + a + b + S), np.uint8)
+            blk[:, :o] = ob[rows, :o]
+            blk[:, o:o + P] = self._pre_arr
+            q = o + P
+            blk[:, q] = self.TS_TAG
+            blk[:, q + 1] = a + b
+            q += 2
+            if a:
+                blk[:, q] = 0x08             # tag(1, VARINT)
+                blk[:, q + 1:q + a] = sb[rows, :a - 1]
+            q += a
+            if b:
+                blk[:, q] = 0x10             # tag(2, VARINT)
+                blk[:, q + 1:q + b] = nb[rows, :b - 1]
+            blk[:, q + b:] = self._suf_arr
+            mat[rows, :blk.shape[1]] = blk
         return SignRows(mat, total)
+
+
+class TemplateRows(abc.Sequence):
+    """The sign-bytes of many votes that never became Python objects:
+    a few VoteRowTemplates (a commit's two: for-block, nil), an int32
+    template index a row and the int64 (secs, nanos) a row. DeltaRows
+    over more than one template, and a ``Sequence[bytes]``: row i IS
+    ``templates[tmpl[i]].bytes_for(Timestamp(secs[i], nanos[i]))``.
+
+    What knows the type hashes the rows in C from these arrays
+    (ops/ed25519_kernel.pack_templated -> native.ed25519_pack_commits) or
+    from the ``expand()``ed matrix (ops/sr25519_kernel); a slice and
+    ``take`` give the same type and make no bytes. Anything else reads
+    it as the list it stands for: iterating patches each template's
+    rows once (``tolist``), ``rows[i]`` builds the one row."""
+
+    __slots__ = ("templates", "tmpl", "secs", "nanos")
+
+    def __init__(self, templates: Sequence["VoteRowTemplate"],
+                 tmpl: np.ndarray, secs: np.ndarray, nanos: np.ndarray):
+        self.templates = tuple(templates)
+        self.tmpl = tmpl
+        self.secs = secs
+        self.nanos = nanos
+
+    def __len__(self) -> int:
+        return int(self.tmpl.shape[0])
+
+    def __getitem__(self, i):
+        if isinstance(i, slice):
+            return TemplateRows(self.templates, self.tmpl[i],
+                                self.secs[i], self.nanos[i])
+        return self.templates[self.tmpl[i]].bytes_for(
+            Timestamp(int(self.secs[i]), int(self.nanos[i])))
+
+    def __iter__(self):
+        return iter(self.tolist())
+
+    def take(self, idxs) -> "TemplateRows":
+        """The rows at `idxs`, in that order (a fancy-indexed copy)."""
+        at = np.asarray(idxs, np.intp)
+        return TemplateRows(self.templates, self.tmpl[at], self.secs[at],
+                            self.nanos[at])
+
+    def expand(self) -> SignRows:
+        """The rows as one zero-padded matrix: one patch_rows a
+        template that has rows."""
+        used = np.unique(self.tmpl)
+        if used.size == 1:
+            return self.templates[int(used[0])].patch_rows(self.secs,
+                                                           self.nanos)
+        parts = []
+        for t in used:
+            where = np.flatnonzero(self.tmpl == t)
+            parts.append((where, self.templates[int(t)].patch_rows(
+                self.secs[where], self.nanos[where])))
+        n = len(self)
+        mat = np.zeros((n, max((p.mat.shape[1] for _, p in parts),
+                               default=0)), np.uint8)
+        lens = np.zeros(n, np.int64)
+        for where, p in parts:
+            mat[where, :p.mat.shape[1]] = p.mat
+            lens[where] = p.lens
+        return SignRows(mat, lens)
+
+    def tolist(self) -> list:
+        return self.expand().tolist()
 
 
 def canonical_proposal_bytes(

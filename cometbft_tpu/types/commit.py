@@ -7,6 +7,7 @@ verifier exploits (all sign-bytes share structure, SURVEY.md §2.2).
 """
 from __future__ import annotations
 
+import operator
 from dataclasses import dataclass, field
 from typing import List, Optional
 
@@ -24,6 +25,12 @@ BLOCK_ID_FLAG_NIL = 3
 
 class CommitError(Exception):
     pass
+
+
+# what sign_rows reads of each CommitSig, at C speed
+_ROW_FLAG = operator.attrgetter("flag")
+_ROW_SECS = operator.attrgetter("timestamp.seconds")
+_ROW_NANOS = operator.attrgetter("timestamp.nanos")
 
 
 @dataclass
@@ -138,36 +145,34 @@ class Commit:
             self._sb_tmpl = tmpl
         return tmpl[1], tmpl[2]
 
+    def sign_rows(self, chain_id: str,
+                  idxs: Optional[List[int]] = None
+                  ) -> canonical.TemplateRows:
+        """`vote_sign_bytes` of many signatures with no bytes built: the
+        commit's two templates, and the template index (nil or not) and
+        timestamp of each row at `idxs`, as the lazy Sequence[bytes]
+        that is byte-equal to
+        [self.vote_sign_bytes(chain_id, i) for i in idxs]. Nothing of a
+        row is kept on the commit: the templates are all it caches."""
+        import numpy as np
+
+        sigs = self.signatures
+        rows = sigs if idxs is None else [sigs[i] for i in idxs]
+        n = len(rows)
+        flags = np.fromiter(map(_ROW_FLAG, rows), np.int64, n)
+        return canonical.TemplateRows(
+            self.sign_bytes_template(chain_id),
+            (flags != BLOCK_ID_FLAG_COMMIT).astype(np.int32),
+            np.fromiter(map(_ROW_SECS, rows), np.int64, n),
+            np.fromiter(map(_ROW_NANOS, rows), np.int64, n))
+
     def sign_bytes_rows(self, chain_id: str,
                         idxs: Optional[List[int]] = None) -> List[bytes]:
         """Vectorized `vote_sign_bytes` for many signatures at once: the
         per-row Python encode loop of the verification paths becomes two
         numpy template patches (for-block rows + nil rows). Byte-equal to
-        [self.vote_sign_bytes(chain_id, i) for i in idxs] — the template-
-        packing hot path of types/validation.py."""
-        import numpy as np
-
-        if idxs is None:
-            idxs = range(len(self.signatures))
-        idxs = list(idxs)
-        tmpl_b, tmpl_n = self.sign_bytes_template(chain_id)
-        sigs = self.signatures
-        nil = np.asarray(
-            [not sigs[i].is_commit() for i in idxs], np.bool_
-        )
-        secs = np.asarray([sigs[i].timestamp.seconds for i in idxs],
-                          np.int64)
-        nanos = np.asarray([sigs[i].timestamp.nanos for i in idxs],
-                           np.int64)
-        out: List[bytes] = [b""] * len(idxs)
-        for tmpl, mask in ((tmpl_b, ~nil), (tmpl_n, nil)):
-            where = np.flatnonzero(mask)
-            if where.size == 0:
-                continue
-            rows = tmpl.patch_rows(secs[where], nanos[where]).tolist()
-            for k, row in zip(where, rows):
-                out[int(k)] = row
-        return out
+        [self.vote_sign_bytes(chain_id, i) for i in idxs]."""
+        return self.sign_rows(chain_id, idxs).tolist()
 
     def validate_basic(self) -> None:
         """block.go:893-917."""

@@ -17,11 +17,12 @@ bit-for-bit and its early-break path in outcome.
 from __future__ import annotations
 
 import functools
-from typing import Callable, List, Optional
+from typing import Callable, List, Optional, Sequence
 
 import numpy as np
 
 from cometbft_tpu.libs import tracing
+from cometbft_tpu.types import canonical
 from cometbft_tpu.types.commit import (
     BLOCK_ID_FLAG_COMMIT,
     Commit,
@@ -59,10 +60,11 @@ def _should_batch_verify(commit: Commit) -> bool:
     return len(commit.signatures) >= BATCH_VERIFY_THRESHOLD
 
 
-# Template packing (the zero-copy hot path): batch verification builds
-# its sign-bytes via Commit.sign_bytes_rows (vectorized numpy template
-# patching) instead of the per-vote encode loop. The toggle exists for
-# the legacy/differential path only — bytes are identical either way
+# Template packing (the zero-copy hot path): batch verification hands
+# its batch_fn the commit's sign-bytes as Commit.sign_rows (the lazy
+# canonical.TemplateRows: two templates and a timestamp a row) instead
+# of the per-vote encode loop's list. The toggle exists for the
+# legacy/differential path only — bytes are identical either way
 # (tests/test_sign_template.py property fuzz + the simnet determinism
 # scenario), so flipping it must never change behavior.
 _TEMPLATE_PACK = True
@@ -81,11 +83,14 @@ def template_packing_enabled() -> bool:
     return _TEMPLATE_PACK
 
 
-def _commit_msgs(chain_id: str, commit: Commit, idxs) -> List[bytes]:
-    """Sign-bytes for the collected signature indices: one vectorized
-    template patch per commit, or the legacy per-vote encode loop."""
+def _commit_msgs(chain_id: str, commit: Commit, idxs) -> Sequence[bytes]:
+    """Sign-bytes for the collected signature indices: the commit's
+    templates and each row's timestamp as a lazy Sequence[bytes] that
+    builds no bytes until something iterates it (what knows the type
+    hashes them in C: ops/ed25519_kernel.pack_templated), or the list
+    of the legacy per-vote encode loop."""
     if _TEMPLATE_PACK:
-        return commit.sign_bytes_rows(chain_id, idxs)
+        return commit.sign_rows(chain_id, idxs)
     return [commit.vote_sign_bytes(chain_id, i) for i in idxs]
 
 
@@ -280,8 +285,10 @@ def _verify_batch(
     if tallied <= voting_power_needed:
         raise NotEnoughPowerError(tallied, voting_power_needed)
 
-    # sign-bytes built AFTER collection: one vectorized template patch
-    # over the collected rows (template packing), or the legacy loop
+    # what the collected rows signed, gathered AFTER collection: their
+    # timestamps and nil flags beside the commit's templates (template
+    # packing: the bytes are built where they are hashed, if the
+    # batch_fn knows the type), or the legacy loop's bytes
     with tracing.stage("commit.sign_bytes", rows=len(idxs)):
         msgs = _commit_msgs(chain_id, commit, idxs)
     with tracing.stage("commit.batch_fn", rows=len(pubs)):
@@ -344,8 +351,12 @@ COMMIT_CHUNK_ROWS = 1024
 
 def _verify_chunked(kind, queue: list, pub_bytes, msgs, sigs):
     """The crypto/batch kernel of one key type, `kind` = (name, pack,
-    run, one_pass): cuts its rows into chunks, packs each on the host
-    (`pack(pubs, msgs, sigs, pad)`) and hands it to the device
+    run, one_pass, prepare): reads the group's messages once
+    (`prepare(msgs)`: what every chunk's pack will be handed runs of),
+    cuts its rows into chunks, packs each on the host
+    (`pack(pubs, msgs, sigs, pad)` -> the packed rows, and whether the
+    chunk's sign-bytes never existed as Python objects: the pack
+    stage's `templated` arg, 1 or 0) and hands it to the device
     (`run(packed)`, which returns while the device works), and returns
     the verdicts NOT YET FETCHED (cbatch.PendingVerdicts).
 
@@ -358,8 +369,9 @@ def _verify_chunked(kind, queue: list, pub_bytes, msgs, sigs):
     chunk, `<name>.fetch` once (PendingVerdicts.fetch)."""
     from cometbft_tpu.crypto import batch as cbatch
 
-    name, pack, run, one_pass = kind
+    name, pack, run, one_pass, prepare = kind
     n = len(pub_bytes)
+    msgs = prepare(msgs)
     pad = COMMIT_CHUNK_ROWS if n > COMMIT_CHUNK_ROWS else one_pass(n)
     chunks = max(1, -(-n // pad))
     outs = []
@@ -369,9 +381,11 @@ def _verify_chunked(kind, queue: list, pub_bytes, msgs, sigs):
         # this one's pack starts (0 past the first: it ran dry)
         at = {"rows": min(n - lo, pad), "chunk": k, "chunks": chunks,
               "flying": sum(not o.is_ready() for o in queue)}
-        with tracing.stage(name + ".pack", padded=pad, **at):
-            packed = pack(pub_bytes[lo:lo + pad], msgs[lo:lo + pad],
-                          sigs[lo:lo + pad], pad)
+        with tracing.stage(name + ".pack", padded=pad, **at) as st:
+            packed, templated = pack(pub_bytes[lo:lo + pad],
+                                     msgs[lo:lo + pad],
+                                     sigs[lo:lo + pad], pad)
+            st.args["templated"] = int(templated)
         # returns while the device runs
         with tracing.stage(name + ".dispatch", **at):
             outs.append(run(packed))
@@ -406,17 +420,21 @@ def device_batch_fn(use_pallas: Optional[bool] = None,
     if use_pallas:
         from cometbft_tpu.ops import ed25519_pallas as kp
 
-        ed_kind = ("ed25519",
-                   lambda p, m, s, pad: kp.pack_rows(
-                       ek.pack_batch(p, m, s, pad_to=pad)),
-                   lambda rows: kp.verify_rows(rows), kp.pad_to_tile)
+        rows_of, one_pass = kp.pack_rows, kp.pad_to_tile
+        run_ed = lambda rows: kp.verify_rows(rows)  # noqa: E731
     else:
-        ed_kind = ("ed25519",
-                   lambda p, m, s, pad: ek.pack_batch(p, m, s, pad_to=pad),
-                   lambda pb: ek.verify_kernel(
-                       pb.ay, pb.asign, pb.ry, pb.rsign, pb.sdig, pb.hdig,
-                       pb.precheck),
-                   lambda n: ek.bucket_size(max(n, 1)))
+        rows_of = lambda pb: pb  # noqa: E731
+        run_ed = lambda pb: ek.verify_kernel(  # noqa: E731
+            pb.ay, pb.asign, pb.ry, pb.rsign, pb.sdig, pb.hdig,
+            pb.precheck)
+        one_pass = lambda n: ek.bucket_size(max(n, 1))  # noqa: E731
+
+    def pack_ed(p, m, s, pad):
+        pb, templated = ek.pack_templated(p, m, s, pad_to=pad)
+        return rows_of(pb), templated
+
+    # its pack builds a commit's lazy rows where it hashes them: as is
+    ed_kind = ("ed25519", pack_ed, run_ed, one_pass, lambda m: m)
 
     def srk():  # first used by a batch that holds an sr25519 row
         from cometbft_tpu.ops import sr25519_kernel
@@ -425,9 +443,20 @@ def device_batch_fn(use_pallas: Optional[bool] = None,
 
     # sr25519 has the one kernel: Pallas, interpreted on a CPU backend
     sr_kind = ("sr25519",
-               lambda p, m, s, pad: srk().pack_batch_sr(p, m, s, pad_to=pad),
+               lambda p, m, s, pad: (
+                   srk().pack_batch_sr(p, m, s, pad_to=pad),
+                   isinstance(m, canonical.SignRows)),
                lambda rows: srk().verify_rows(rows),
-               lambda n: srk().kp.pad_to_tile(n))
+               lambda n: srk().kp.pad_to_tile(n),
+               # merlin hashes the message bytes themselves: a commit's
+               # lazy rows become ONE matrix before the group's first
+               # chunk, and each chunk hashes its rows of it (a
+               # patch_rows a chunk costs 0.35 ms more a chunk). Here and
+               # not in a closure around _verify_chunked: that form read
+               # 4.7 s more in a process's first sr25519 dispatch
+               # (PERF.md section 6, PR 34)
+               lambda m: (m.expand() if isinstance(m, canonical.TemplateRows)
+                          else m))
 
     def ed25519_cached(pub_bytes, msgs, sigs):
         # Cached-valset kernel (opt-in): ~3x the general kernel's
@@ -442,16 +471,15 @@ def device_batch_fn(use_pallas: Optional[bool] = None,
 
         # packs, runs and fetches inside the one call
         with tracing.stage("ed25519.dispatch", rows=len(pub_bytes)):
-            return ec.verify_batch_cached(pub_bytes, msgs, sigs)
+            return ec.verify_batch_cached(pub_bytes, list(msgs), sigs)
 
     def fn(pubs, msgs, sigs):
         queue = []  # this call's passes, of either key type
-        ed = functools.partial(_verify_chunked, ed_kind, queue)
 
         def ed25519_verify(pub_bytes, msgs, sigs):
             if use_pallas and cached and len(pub_bytes) >= 128:
                 return ed25519_cached(pub_bytes, msgs, sigs)
-            return ed(pub_bytes, msgs, sigs)
+            return _verify_chunked(ed_kind, queue, pub_bytes, msgs, sigs)
 
         return cbatch.verify_batch(pubs, msgs, sigs, kernels={
             "ed25519": ed25519_verify,
@@ -475,44 +503,25 @@ def commit_packed_batch(chain_id: str, commit: Commit, keys, idxs=None,
                         pad_to: Optional[int] = None):
     """Zero-copy staging of a commit's signatures for the device
     verifier: commit -> PackedBatch without ever materializing per-row
-    Python sign-bytes.
+    Python sign-bytes: the served path's own pack
+    (ops/ed25519_kernel.pack_templated over _commit_msgs) for one
+    commit in one pass.
 
     keys[i] is validator i's 32-byte ed25519 pubkey (valset order). The
     native path assembles sign-bytes in C from the commit's (pre, suf)
     templates + per-row timestamps (ed25519_pack_commits); the fallback
-    patches the numpy templates (Commit.sign_bytes_rows) and feeds
-    pack_batch. Both are byte-identical to the legacy per-vote path.
+    patches the numpy templates and feeds pack_batch. Both are
+    byte-identical to the legacy per-vote path.
 
     Returns (PackedBatch, row_idxs) with row k of the batch holding
     commit-signature row_idxs[k]."""
-    from cometbft_tpu import native
     from cometbft_tpu.ops import ed25519_kernel as ek
 
     sigs_all = commit.signatures
     if idxs is None:
         idxs = [i for i, cs in enumerate(sigs_all)
                 if cs.for_block() and i < len(keys)]
-    pubs = [keys[i] for i in idxs]
-    sigs = [sigs_all[i].signature for i in idxs]
-    n = len(idxs)
-    padded = pad_to if pad_to is not None else ek.bucket_size(max(n, 1))
-    if (native.available() and n
-            and all(len(p) == 32 for p in pubs)
-            and all(len(s) == 64 for s in sigs)):
-        tmpl_b, tmpl_n = commit.sign_bytes_template(chain_id)
-        secs = np.asarray([sigs_all[i].timestamp.seconds for i in idxs],
-                          np.int64)
-        nanos = np.asarray([sigs_all[i].timestamp.nanos for i in idxs],
-                           np.int64)
-        nil = np.asarray(
-            [not sigs_all[i].is_commit() for i in idxs], np.int32
-        )
-        packed = native.ed25519_pack_commits(
-            b"".join(pubs), b"".join(sigs),
-            [tmpl_b.template, tmpl_n.template], nil,
-            secs, nanos, padded,
-        )
-        if packed is not None:
-            return ek.PackedBatch(n, padded, *packed), idxs
-    msgs = _commit_msgs(chain_id, commit, idxs)
-    return ek.pack_batch(pubs, msgs, sigs, pad_to=padded), idxs
+    packed, _ = ek.pack_templated(
+        [keys[i] for i in idxs], _commit_msgs(chain_id, commit, idxs),
+        [sigs_all[i].signature for i in idxs], pad_to=pad_to)
+    return packed, idxs

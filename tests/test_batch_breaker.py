@@ -264,3 +264,64 @@ def test_breaker_config_knobs():
     cfg.crypto.breaker_failure_threshold = 0
     with pytest.raises(ConfigError):
         cfg.validate_basic()
+
+
+@pytest.mark.parametrize("where", ["dispatch", "fetch"])
+def test_a_fault_re_verifies_a_commits_lazy_rows_from_their_bytes(
+        monkeypatch, where):
+    """The served commit check hands its batch_fn lazy sign-bytes
+    (canonical.TemplateRows: templates and a timestamp a row). A
+    device fault at dispatch or at the fetch re-verifies the group on
+    the host from the REAL bytes of its rows, so the verdict and the
+    blame are the oracle's."""
+    from cometbft_tpu.types import canonical, validation
+    from cometbft_tpu.types.block_id import BlockID, PartSetHeader
+    from cometbft_tpu.types.commit import (BLOCK_ID_FLAG_COMMIT, Commit,
+                                           CommitSig)
+    from cometbft_tpu.types.timestamp import Timestamp
+    from cometbft_tpu.types.validator import Validator, ValidatorSet
+
+    chain, height = "breaker-chain", 7
+    privs = {p.pub_key().address(): p for p in
+             (PrivKey.generate(bytes([i + 40]) * 32) for i in range(9))}
+    vs = ValidatorSet([Validator(p.pub_key(), 10) for p in privs.values()])
+    bid = BlockID(b"\x42" * 32, PartSetHeader(1, b"\x43" * 32))
+    sigs = []
+    for idx, v in enumerate(vs.validators):
+        ts = Timestamp(1_700_000_000 + idx, 1000 * idx)
+        sig = privs[v.address].sign(canonical.canonical_vote_bytes(
+            chain, canonical.PRECOMMIT_TYPE, height, 0, bid, ts))
+        if idx == 3:
+            sig = sig[:7] + bytes([sig[7] ^ 1]) + sig[8:]
+        sigs.append(CommitSig(BLOCK_ID_FLAG_COMMIT, v.address, ts, sig))
+    commit = Commit(height, 0, bid, sigs)
+    handed, on_host = [], []
+
+    def kernel(pub_bytes, msgs, sigs):
+        handed.append(msgs)
+        if where == "dispatch":
+            raise RuntimeError("device lost")
+        return cbatch.PendingVerdicts(
+            [_Verdicts([True] * len(sigs), [], "ed25519", sick=True)],
+            len(sigs), "ed25519.fetch")
+
+    real = cbatch._host_verify_rows
+
+    def host(pubs, msgs, sigs, idxs, valid):
+        on_host.append((msgs, [msgs[i] for i in idxs]))
+        return real(pubs, msgs, sigs, idxs, valid)
+
+    monkeypatch.setattr(cbatch, "_host_verify_rows", host)
+    brk = cbatch.CircuitBreaker(failure_threshold=5)
+    with pytest.raises(validation.InvalidSignatureError) as ei:
+        validation.verify_commit_light(
+            chain, vs, bid, height, commit,
+            lambda p, m, s: cbatch.verify_batch(
+                p, m, s, kernels={"ed25519": kernel}, breaker=brk))
+    assert ei.value.idx == 3 and brk.faults == 1
+    # the kernel was handed the group's rows still lazy; the host read
+    # the bytes those rows stand for
+    assert [type(m) for m in handed] == [canonical.TemplateRows]
+    (msgs, read), = on_host
+    assert type(msgs) is canonical.TemplateRows
+    assert read == [commit.vote_sign_bytes(chain, i) for i in range(7)]

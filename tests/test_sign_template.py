@@ -625,3 +625,166 @@ def test_staging_pool_exhaustion_aliases_oldest():
     # resident footprint never grows past slots x shape
     assert p.stats()["resident_bytes"] == 3 * 4 * 8
     assert p.nbytes() == 3 * 4 * 8
+
+
+# --------------------------------------------------------------------------
+# The lazy row sequence (canonical.TemplateRows: what the served commit
+# check hands its batch_fn) and the pack that hashes it in C
+# --------------------------------------------------------------------------
+
+ROWS_CHAIN = "rows-chain"
+
+
+def _fuzz_commit(seed, n=40):
+    """A commit whose rows mix for-block, nil and absent flags under
+    timestamps of every varint width, zero and negative seconds and
+    nanos among them. Keys and signatures are random bytes of the right
+    length: nothing here verifies one."""
+    rng = random.Random(seed)
+    bid = BlockID(b"\x77" * 32, PartSetHeader(3, b"\x88" * 32))
+    sigs = []
+    for idx in range(n):
+        flag = rng.choice([BLOCK_ID_FLAG_COMMIT] * 4
+                          + [BLOCK_ID_FLAG_NIL] * 2
+                          + [BLOCK_ID_FLAG_ABSENT])
+        if flag == BLOCK_ID_FLAG_ABSENT:
+            sigs.append(CommitSig(BLOCK_ID_FLAG_ABSENT))
+            continue
+        ts = Timestamp(rng.choice(FUZZ_SECS), rng.choice(FUZZ_NANOS))
+        sigs.append(CommitSig(flag, rng.randbytes(20), ts,
+                              rng.randbytes(64)))
+    return Commit(11, 1, bid, sigs)
+
+
+# a view of the rows at `idxs`, and the indices it leaves
+ROW_VIEWS = {
+    "all": lambda rows, idxs: (rows, idxs),
+    "slice": lambda rows, idxs: (rows[3:17], idxs[3:17]),
+    "chunk-past-the-end": lambda rows, idxs: (rows[32:96], idxs[32:96]),
+    "stepped-slice": lambda rows, idxs: (rows[1::3], idxs[1::3]),
+    "empty-slice": lambda rows, idxs: (rows[5:5], []),
+    "take": lambda rows, idxs: (rows.take([7, 1, 11, 3, 3]),
+                                [idxs[k] for k in (7, 1, 11, 3, 3)]),
+    "take-of-a-slice": lambda rows, idxs: (
+        rows[5:25].take([0, 19, 4]), [idxs[5], idxs[24], idxs[9]]),
+    "take-none": lambda rows, idxs: (rows.take([]), []),
+}
+
+
+@pytest.mark.parametrize("view", sorted(ROW_VIEWS))
+@pytest.mark.parametrize("select", ["every-row", "sub-selection"])
+@pytest.mark.parametrize("seed", [1, 2])
+def test_template_rows_are_the_per_vote_bytes(seed, select, view):
+    """Commit.sign_rows, and every slice and `take` of it, IS the list
+    [commit.vote_sign_bytes(chain, i) for i in idxs]: by index, by
+    iteration, as a list, and as the expanded matrix."""
+    from collections import abc
+
+    commit = _fuzz_commit(seed)
+    idxs = list(range(len(commit.signatures)))
+    if select == "sub-selection":
+        idxs = random.Random(seed).sample(idxs, 30)
+    rows, idxs = ROW_VIEWS[view](commit.sign_rows(ROWS_CHAIN, idxs), idxs)
+    want = [commit.vote_sign_bytes(ROWS_CHAIN, i) for i in idxs]
+    assert isinstance(rows, canonical.TemplateRows)
+    assert isinstance(rows, abc.Sequence)
+    assert len(rows) == len(want)
+    assert [rows[k] for k in range(len(rows))] == want
+    assert list(rows) == want and rows.tolist() == want
+    assert [m for m in rows] == want
+    grown = [b"first"]
+    grown += rows  # blocksync/pipeline._template_msgs extends a list
+    assert grown == [b"first"] + want
+    mat = rows.expand()
+    assert isinstance(mat, canonical.SignRows) and len(mat) == len(want)
+    assert [mat.row(k) for k in range(len(mat))] == want
+    assert [int(ln) for ln in mat.lens] == [len(m) for m in want]
+    for k, m in enumerate(want):  # right-padded with zeros
+        assert not mat.mat[k, len(m):].any()
+    # a run of the matrix is a chunk's rows (the sr25519 pack's input)
+    run = mat[2:9]
+    assert isinstance(run, canonical.SignRows)
+    assert [run[k] for k in range(len(run))] == want[2:9] == list(run)
+    with pytest.raises(IndexError):
+        rows[len(want)]
+    if want:
+        assert rows[-1] == want[-1]
+        assert want[0] in rows and rows.index(want[-1]) == want.index(
+            want[-1])
+    # the rows hold arrays and templates: nothing of a row on the commit
+    assert set(vars(commit)) <= {"height", "round", "block_id",
+                                 "signatures", "_sb_tmpl", "_sb_enc"}
+
+
+@pytest.mark.parametrize("on", [True, False])
+def test_commit_msgs_is_lazy_rows_or_the_legacy_list(on):
+    """validation._commit_msgs: the lazy rows with template packing on,
+    the per-vote loop's plain list with it off; the same bytes."""
+    commit = _fuzz_commit(5)
+    idxs = [i for i, cs in enumerate(commit.signatures)
+            if not cs.is_absent()]
+    prev = tv.set_template_packing(on)
+    try:
+        msgs = tv._commit_msgs(ROWS_CHAIN, commit, idxs)
+    finally:
+        tv.set_template_packing(prev)
+    assert type(msgs) is (canonical.TemplateRows if on else list)
+    assert list(msgs) == [commit.vote_sign_bytes(ROWS_CHAIN, i)
+                          for i in idxs]
+
+
+@pytest.fixture(params=["native", "no-native"])
+def native_lib(request, monkeypatch):
+    """Runs a test with the native library and with it monkeypatched
+    away; True where it is there."""
+    from cometbft_tpu import native
+
+    if request.param == "no-native":
+        monkeypatch.setattr(native, "_load", lambda: None)
+    elif not native.available():
+        pytest.skip("no native library here")
+    return request.param == "native"
+
+
+@pytest.mark.parametrize("case", ["well-formed", "one-row", "short-key",
+                                  "long-signature", "no-rows"])
+def test_pack_templated_is_pack_batch_over_the_bytes(native_lib, case):
+    """ops/ed25519_kernel.pack_templated over the lazy rows, padded to
+    a chunk shape, gives the arrays of pack_batch over the bytes they
+    stand for. Templated (no bytes made) only with the native library
+    and every key 32, every signature 64 bytes long; a malformed length
+    sends the chunk down pack_batch's list route, screens and all."""
+    from cometbft_tpu.ops import ed25519_kernel as ek
+
+    commit = _fuzz_commit(9, n=70)
+    idxs = [i for i, cs in enumerate(commit.signatures)
+            if not cs.is_absent()]
+    idxs = {"one-row": idxs[:1], "no-rows": []}.get(case, idxs)
+    rng = random.Random(3)
+    pubs = [rng.randbytes(32) for _ in idxs]
+    sigs = [commit.signatures[i].signature[:63] + b"\x00" for i in idxs]
+    if case == "short-key":
+        pubs[4] = pubs[4][:31]
+    if case == "long-signature":
+        sigs[-1] = sigs[-1] + b"\x00"
+    rows = commit.sign_rows(ROWS_CHAIN, idxs)
+    want = ek.pack_batch(pubs, [commit.vote_sign_bytes(ROWS_CHAIN, i)
+                                for i in idxs], sigs, pad_to=64)
+    got, templated = ek.pack_templated(pubs, rows, sigs, pad_to=64)
+    assert templated is (native_lib
+                         and case in ("well-formed", "one-row"))
+    assert (got.n, got.padded) == (want.n, want.padded) == (len(idxs), 64)
+    for name in ek.PackedBatch._fields[2:]:
+        np.testing.assert_array_equal(
+            np.asarray(getattr(got, name)), np.asarray(getattr(want, name)),
+            err_msg=name)
+    if case in ("short-key", "long-signature"):
+        bad = 4 if case == "short-key" else len(idxs) - 1
+        assert not got.precheck[bad] and got.precheck[:len(idxs)].sum() > 0
+    # a plain list is pack_batch's, never templated; no pad_to is the
+    # bucket ladder's rung, as pack_batch's
+    lst, templated = ek.pack_templated(pubs, list(rows), sigs)
+    assert templated is False
+    assert lst.padded == ek.pack_batch(pubs, list(rows), sigs).padded
+    np.testing.assert_array_equal(lst.hdig[:len(idxs)],
+                                  want.hdig[:len(idxs)])

@@ -350,3 +350,77 @@ def test_served_chunks_through_the_true_kernel(monkeypatch):
              if r[0] == "sr25519.pack"]
     assert [(p["rows"], p["padded"]) for p in packs] == [(128, 128),
                                                          (20, 128)]
+
+
+# --------------------------------------------------------------------------
+# A commit's lazy sign-bytes (canonical.TemplateRows): merlin needs the
+# bytes, so the pack hashes them from the expanded matrix by length group
+# --------------------------------------------------------------------------
+
+
+def _lazy_rows(n):
+    """n sr25519 rows whose messages are a commit's sign-bytes under
+    two templates and timestamps of several varint widths (so several
+    message lengths), as TemplateRows; signed by the bytes."""
+    from cometbft_tpu.types import canonical
+    from cometbft_tpu.types.block_id import BlockID, PartSetHeader
+
+    bid = BlockID(b"\x51" * 32, PartSetHeader(2, b"\x52" * 32))
+    tmpls = [canonical.VoteRowTemplate("sr-rows", canonical.PRECOMMIT_TYPE,
+                                       12, 1, b) for b in (bid, None)]
+    secs = np.asarray([(0, 1, 300, 1_700_000_000, -5)[i % 5]
+                       for i in range(n)], np.int64)
+    nanos = np.asarray([(0, 7, 999_999_999)[i % 3] for i in range(n)],
+                       np.int64)
+    rows = canonical.TemplateRows(
+        tmpls, (np.arange(n) % 7 == 2).astype(np.int32), secs, nanos)
+    ks = [Sr25519PrivKey.generate(bytes([i + 1]) * 32) for i in range(4)]
+    msgs = list(rows)
+    pubs = [ks[i % 4].pub_key().data for i in range(n)]
+    sigs = [ks[i % 4].sign(m) for i, m in enumerate(msgs)]
+    return pubs, rows, msgs, sigs
+
+
+@pytest.fixture(params=["native", "no-native"])
+def native_lib(request, monkeypatch):
+    from cometbft_tpu import native
+
+    if request.param == "no-native":
+        monkeypatch.setattr(native, "_load", lambda: None)
+    elif not native.available():
+        pytest.skip("no native library here")
+
+
+@pytest.mark.parametrize("n,pad_to,damage", [
+    (1, None, None), (23, None, None), (23, 128, None),
+    (23, 128, "short-signature"), (23, 128, "short-key"),
+    (0, 128, None)])
+def test_pack_batch_sr_over_lazy_rows_is_the_pack_over_their_bytes(
+        native_lib, n, pad_to, damage):
+    """pack_batch_sr handed TemplateRows packs what it packs from the
+    list of bytes they stand for, with the native transcripts and with
+    the numpy BatchStrobe; and the challenges over the expanded matrix
+    are those over the list, whatever the rows' lengths."""
+    from cometbft_tpu.ops import sr25519_kernel as srk
+
+    pubs, rows, msgs, sigs = _lazy_rows(n)
+    if damage == "short-signature":
+        sigs[5] = sigs[5][:40]
+    if damage == "short-key":
+        pubs[6] = pubs[6][:31]
+    assert len({len(m) for m in msgs}) >= min(n, 3)
+    got = srk.pack_batch_sr(pubs, rows, sigs, pad_to=pad_to)
+    want = srk.pack_batch_sr(pubs, msgs, sigs, pad_to=pad_to)
+    np.testing.assert_array_equal(got, want)
+    if n and damage is None:
+        r_encs = [s[:32] for s in sigs]
+        np.testing.assert_array_equal(
+            srk.batch_challenges(rows.expand(), pubs, r_encs),
+            srk.batch_challenges(msgs, pubs, r_encs))
+        # a slice is a chunk's rows: of the lazy rows, or of the matrix
+        # the served call expands a group's rows into before its first
+        for chunk in (rows[3:9], rows.expand()[3:9]):
+            np.testing.assert_array_equal(
+                srk.pack_batch_sr(pubs[3:9], chunk, sigs[3:9], pad_to=128),
+                srk.pack_batch_sr(pubs[3:9], msgs[3:9], sigs[3:9],
+                                  pad_to=128))

@@ -410,7 +410,7 @@ def ed_standin(monkeypatch, sr_standin):
     return sr_standin
 
 
-def make_mixed_commit(n_vals=48, invalid=(), absent=()):
+def make_mixed_commit(n_vals=48, invalid=(), absent=(), nil=()):
     """make_commit over a set whose validators hold ed25519 and sr25519
     keys in turn (by seed; the set's own order interleaves them). Also
     returns what the benchmark's plain reference is given: pubs, key
@@ -426,14 +426,17 @@ def make_mixed_commit(n_vals=48, invalid=(), absent=()):
     for idx, v in enumerate(vs.validators):
         ts = Timestamp(1700000000 + idx, idx)
         sb = canonical.canonical_vote_bytes(
-            CHAIN_ID, canonical.PRECOMMIT_TYPE, HEIGHT, 2, bid, ts)
+            CHAIN_ID, canonical.PRECOMMIT_TYPE, HEIGHT, 2,
+            None if idx in nil else bid, ts)
         sig = by_addr[v.address].sign(sb)
         if idx in invalid:
             sig = sig[:10] + bytes([sig[10] ^ 1]) + sig[11:]
         if idx in absent:
             sigs.append(CommitSig.absent())
         else:
-            sigs.append(CommitSig(BLOCK_ID_FLAG_COMMIT, v.address, ts, sig))
+            sigs.append(CommitSig(
+                BLOCK_ID_FLAG_NIL if idx in nil else BLOCK_ID_FLAG_COMMIT,
+                v.address, ts, sig))
         plain.append((v.pub_key.data, v.pub_key.key_type, 100, sb,
                       None if idx in absent else sig))
     return vs, Commit(HEIGHT, 2, bid, sigs), bid, plain
@@ -548,3 +551,133 @@ def test_an_ed25519_commit_records_the_stages_it_always_did(kernel_calls):
         "commit.collect", "commit.sign_bytes", "ed25519.pack",
         "ed25519.dispatch", "ed25519.fetch", "commit.batch_fn",
         "commit.verify"]
+
+
+# --------------------------------------------------------------------------
+# The served call hands its batch_fn lazy rows (canonical.TemplateRows):
+# every chunk's pack builds the sign-bytes where it hashes them
+# --------------------------------------------------------------------------
+
+VARIANTS = {
+    "full": lambda vs, bid, commit, fn: validation.verify_commit(
+        CHAIN_ID, vs, bid, HEIGHT, commit, fn),
+    "light": lambda vs, bid, commit, fn: validation.verify_commit_light(
+        CHAIN_ID, vs, bid, HEIGHT, commit, fn),
+    "trusting": lambda vs, bid, commit, fn:
+        validation.verify_commit_light_trusting(CHAIN_ID, vs, commit,
+                                                (2, 3), fn),
+}
+_COMMITS = {}  # the fixtures of the test below, signed once
+
+
+def outcome_of(call):
+    try:
+        call()
+    except validation.InvalidSignatureError as e:
+        return ("invalid_signature", e.idx)
+    except validation.NotEnoughPowerError as e:
+        return ("not_enough_power", e.needed)
+    return ("ok",)
+
+
+def chunked_commit(keys, case):
+    """A commit of 2 T + 8 ed25519 rows (a light check examines 2 T - 37:
+    two chunks; a full one all: three), or of some 2 T rows of each key
+    type, with nil rows (verify_commit's second template) and, in the
+    "bad" case, a flipped signature in a later chunk of each group."""
+    if (keys, case) not in _COMMITS:
+        nil = (3, T + 2)
+        if keys == "ed25519":
+            bad = (T, T + 6) if case == "bad" else ()
+            vs, commit, bid = make_commit(n_vals=2 * T + 8, invalid=bad,
+                                          nil=nil)
+        else:
+            _, _, _, plain = make_mixed_commit(n_vals=250)
+            by_type = {kt: [i for i, row in enumerate(plain)
+                            if row[1] == kt and i not in nil]
+                       for kt in ("ed25519", "sr25519")}
+            # a row of each group's SECOND chunk as a light check cuts it
+            bad = ((by_type["sr25519"][T + 3], by_type["ed25519"][T + 1])
+                   if case == "bad" else ())
+            vs, commit, bid, _ = make_mixed_commit(n_vals=250, invalid=bad,
+                                                   nil=nil)
+        _COMMITS[keys, case] = (vs, commit, bid, bad)
+    return _COMMITS[keys, case]
+
+
+@pytest.mark.parametrize("case", ["ok", "bad"])
+@pytest.mark.parametrize("variant", sorted(VARIANTS))
+@pytest.mark.parametrize("keys", ["ed25519", "mixed"])
+def test_chunked_outcome_is_the_oracles_and_every_pack_is_templated(
+        chunked, sr_standin, keys, variant, case):
+    """verify_commit, _light and _light_trusting over a batch of several
+    chunks a key type give the oracle's outcome and blame index, and
+    every chunk's pack records `templated` 1: its sign-bytes were never
+    Python objects."""
+    from cometbft_tpu import native
+    from cometbft_tpu.libs import tracing
+
+    vs, commit, bid, bad = chunked_commit(keys, case)
+    want = outcome_of(lambda: VARIANTS[variant](
+        vs, bid, commit, validation.oracle_batch_fn()))
+    seen = []
+    inner = validation.device_batch_fn(use_pallas=False)
+
+    def batch_fn(pubs, msgs, sigs):
+        seen.append(msgs)
+        return inner(pubs, msgs, sigs)
+
+    tracing.set_clock(None)  # an empty stage ring
+    got = outcome_of(lambda: VARIANTS[variant](vs, bid, commit, batch_fn))
+    assert got == want
+    assert got == (("invalid_signature", min(bad)) if bad else ("ok",))
+    assert [type(m) for m in seen] == [canonical.TemplateRows]
+    packs = [r for r in tracing.stage_records() if r[0].endswith(".pack")]
+    by_name = {}
+    for r in packs:
+        by_name.setdefault(r[0], []).append(r[4])
+    assert sorted(by_name) == (["ed25519.pack"] if keys == "ed25519" else
+                               ["ed25519.pack", "sr25519.pack"])
+    for name, args in by_name.items():
+        assert len(args) >= 2 and args[0]["chunks"] == len(args)
+        assert all(a["padded"] == T for a in args)
+        # ed25519 builds them in C; sr25519 hashes them from a matrix
+        lazy = native.available() or name == "sr25519.pack"
+        assert [a["templated"] for a in args] == [int(lazy)] * len(args)
+    assert sum(a["rows"] for a in sum(by_name.values(), [])) == len(seen[0])
+    # the full check reached the nil rows' template
+    assert (1 in seen[0].tmpl) == (variant == "full")
+
+
+@pytest.mark.parametrize("kind", ["list", "malformed-key",
+                                  "malformed-signature"])
+def test_a_chunk_that_cannot_be_templated_packs_its_bytes(chunked, kind):
+    """A plain list of bytes, or lazy rows beside a key or signature of
+    the wrong length, go down pack_batch's list route: `templated` 0 on
+    that chunk and the oracle's verdicts."""
+    from cometbft_tpu.libs import tracing
+
+    vs, commit, bid, _ = chunked_commit("ed25519", "ok")
+    idxs = [i for i in range(T + 9) if commit.signatures[i].for_block()]
+    pubs = [vs.validators[i].pub_key for i in idxs]
+    sigs = [commit.signatures[i].signature for i in idxs]
+    rows = commit.sign_rows(CHAIN_ID, idxs)
+    lazy = [1, 1]  # two chunks
+    if kind == "list":
+        rows, lazy = list(rows), [0, 0]
+    elif kind == "malformed-key":
+        pubs[2] = type(pubs[2])(pubs[2].data[:31])
+        lazy = [0, 1]
+    else:
+        sigs[T + 1] = sigs[T + 1][:63]
+        lazy = [1, 0]
+    want = validation.oracle_batch_fn()(pubs, rows, sigs)
+    assert want.sum() == len(idxs) - (kind != "list")
+    tracing.set_clock(None)  # an empty stage ring
+    got = validation.device_batch_fn(use_pallas=False)(pubs, rows, sigs)
+    np.testing.assert_array_equal(got, want)
+    from cometbft_tpu import native
+
+    assert [r[4]["templated"] for r in tracing.stage_records()
+            if r[0] == "ed25519.pack"] == (
+        lazy if native.available() else [0, 0])
