@@ -12,15 +12,22 @@ or an operator calls, every path that puts signatures on the device:
            under Mosaic, not the interpreter
   kernels  the ZIP-215 edge vectors (tools/tpu_differential.edge_cases)
            through the three ed25519 kernels, secp256k1 vectors
-           through theirs, sr25519 vectors through the node's batch_fn
-           as chunks of the served shape (1,024 rows), and one
+           through theirs in one pass (the plane's and bare callers'
+           way), sr25519 and secp256k1 vectors through the node's
+           batch_fn as chunks of the served shape (1,024 rows), and one
            interleaved mixed-key batch through that batch_fn (sr25519
-           chunks and an ed25519 pass in one queue), row for row
-           against the pure-Python references
+           and secp256k1 chunks and an ed25519 pass in one queue), row
+           for row against the pure-Python references
   commit   Config().crypto.batch_fn() (what a node is assembled with)
            under validation.verify_commit_light on a seeded
            10,000-validator commit: accepted; tampered -> the host's
            blame index; under 2/3 -> NotEnoughPowerError
+  light    light.client.Client with that batch_fn, one skipping step
+           (verify_non_adjacent: the trusting check by address, then
+           the 2/3 check) over a 96-validator secp256k1 chain of
+           unequal powers with a third of its seats changed: stored;
+           a signature flipped where only the second check looks ->
+           ErrInvalidHeader with the host's index
   stream   blocksync.pipeline.make_stream_verifier() over 64 blocks of
            1,000-validator commits with one bad block and one validator
            set change, per job against catchup.HostCommitVerifier
@@ -143,8 +150,9 @@ def _block_id(h: int):
                    PartSetHeader(2, bytes([h % 241 + 3]) * 32))
 
 
-def _commit(vs, by_addr, height: int):
-    """Every validator's real precommit for block `height`."""
+def _commit(vs, by_addr, height: int, bid=None):
+    """Every validator's real precommit for block `height` (for `bid`
+    where a header names one)."""
     from cometbft_tpu.types import canonical
     from cometbft_tpu.types.commit import (
         BLOCK_ID_FLAG_COMMIT,
@@ -153,7 +161,7 @@ def _commit(vs, by_addr, height: int):
     )
     from cometbft_tpu.types.timestamp import Timestamp
 
-    bid = _block_id(height)
+    bid = bid or _block_id(height)
     sigs = []
     for idx, v in enumerate(vs.validators):
         ts = Timestamp(1_700_000_000 + 7 * height + idx % 5, idx)
@@ -326,8 +334,13 @@ def leg_kernels():
     sr_rows, sr_exp = sr_rows * 5, sr_exp * 5
     served = validation.device_batch_fn()
 
-    def sr25519_chunked(pubs, msgs, sigs):
-        return served([PubKey(p, "sr25519") for p in pubs], msgs, sigs)
+    def chunked(key_type):
+        return lambda pubs, msgs, sigs: served(
+            [PubKey(p, key_type) for p in pubs], msgs, sigs)
+
+    # secp256k1 both ways: the one padded pass crypto/batch gives the
+    # plane and bare callers, then two chunks of the served shape
+    sc_served = (sc_rows * 5, sc_exp * 5)
 
     out = {}
     for name, fn, rows, exp in (
@@ -335,8 +348,9 @@ def leg_kernels():
             ("ed25519_cached", ed25519_cached.verify_batch_cached,
              ed_rows, ed_exp),
             ("ed25519_kernel", ed25519_kernel.verify_batch, ed_rows, ed_exp),
-            ("sr25519_kernel", sr25519_chunked, sr_rows, sr_exp),
-            ("ecdsa_pallas", ecdsa_pallas.verify_batch, sc_rows, sc_exp)):
+            ("sr25519_kernel", chunked("sr25519"), sr_rows, sr_exp),
+            ("ecdsa_pallas", ecdsa_pallas.verify_batch, sc_rows, sc_exp),
+            ("ecdsa_chunked", chunked("secp256k1"), *sc_served)):
         pubs, msgs, sigs = (list(z) for z in zip(*rows))
         got = np.asarray(fn(pubs, msgs, sigs), np.bool_)
         bad = np.flatnonzero(got != np.asarray(exp))
@@ -345,8 +359,9 @@ def leg_kernels():
         out[name] = {"rows": len(rows), "valid": int(got.sum())}
 
     # the key-type grouping seam: one interleaved mixed-key batch through
-    # the node's batch_fn (ed25519 in one pass, sr25519 in two chunks,
-    # both in flight before either is fetched; secp256k1 in one pass)
+    # the node's batch_fn (ed25519 in one pass, sr25519 and secp256k1 in
+    # two chunks each, all in flight before any is fetched)
+    sc_rows, sc_exp = sc_served
     mixed = ([(PubKey(p, "ed25519"), m, s, e)
               for (p, m, s), e in zip(ed_rows, ed_exp)]
              + [(PubKey(p, "sr25519"), m, s, e)
@@ -411,6 +426,99 @@ def leg_commit(data, host):
     data["row_valid10k"] = row_valid
     return {"validators": len(pubs), "outcomes": got,
             "host_reference": "verify_commit_light(batch_fn=None)"}
+
+
+def _light_chain(tampered=None):
+    """Two light blocks 1,000 heights apart over 96 secp256k1
+    validators of unequal power; 32 seats change hands between them.
+    `tampered` flips that row's signature in the second block."""
+    import hashlib
+    from dataclasses import replace
+
+    from cometbft_tpu.crypto.keys import Secp256k1PrivKey
+    from cometbft_tpu.light import verifier as lv
+    from cometbft_tpu.types.block import Header
+    from cometbft_tpu.types.block_id import BlockID, PartSetHeader
+    from cometbft_tpu.types.commit import Commit
+    from cometbft_tpu.types.timestamp import Timestamp
+    from cometbft_tpu.types.validator import Validator, ValidatorSet
+
+    privs = [Secp256k1PrivKey.generate(
+        bytes([SEED, 7]) + i.to_bytes(4, "big") + b"\x3c" * 26)
+        for i in range(128)]
+    blocks = {}
+    for height, seats in ((1_000, privs[:96]), (2_000, privs[32:])):
+        vs = ValidatorSet([
+            Validator(p.pub_key(), 500 + (37 * privs.index(p)) % 1001)
+            for p in seats])
+        by_addr = {p.pub_key().address(): p for p in seats}
+        header = Header(
+            chain_id=CHAIN, height=height,
+            time=Timestamp(1_700_000_000 + 7 * height, 0),
+            last_block_id=_block_id(height - 1),
+            validators_hash=vs.hash(), next_validators_hash=vs.hash(),
+            proposer_address=vs.validators[0].address,
+            app_hash=bytes([height % 251]) * 32)
+        h = header.hash()
+        bid = BlockID(h, PartSetHeader(1, hashlib.sha256(h).digest()))
+        commit, _ = _commit(vs, by_addr, height, bid)
+        if tampered is not None and height == 2_000:
+            commit = _tampered(commit, [tampered])
+        blocks[height] = lv.LightBlock(lv.SignedHeader(header, commit), vs)
+    return blocks
+
+
+def leg_light():
+    from cometbft_tpu.config.config import Config
+    from cometbft_tpu.crypto import batch as cbatch
+    from cometbft_tpu.light import client as lc
+    from cometbft_tpu.libs import tracing
+    from cometbft_tpu.types import validation as tv
+    from cometbft_tpu.types.timestamp import Timestamp
+
+    now = Timestamp(1_700_000_000 + 7 * 2_000 + 60, 0)
+    faults0 = cbatch.device_breaker().faults
+
+    def step(blocks, batch_fn):
+        c = lc.Client(CHAIN, lc.Provider(CHAIN, blocks.get), witnesses=[],
+                      skipping=True, batch_fn=batch_fn)
+        c.trust_light_block(blocks[1_000])
+        try:
+            c.verify_light_block_at_height(2_000, now=now)
+        except lc.LightClientError as e:
+            return (type(e).__name__, _outcome(e.__cause__))
+        return ("stored", c.verifications)
+
+    served = Config().crypto.batch_fn()
+    good = _light_chain()
+    tracing.set_clock(None)  # an empty stage ring
+    got = step(good, served)
+    check(got == ("stored", 1), "the light step was not stored", got)
+    names = [r[0] for r in tracing.stage_records()]
+    check(all(names.count(n) == 1 for n in (
+        "light.step", "light.trusting", "light.new_set")),
+        "the light step's stages", names)
+    # the rows each check collected, by the stage ring. Every validator
+    # signs, so the second check's are the commit's first packs[1]; the
+    # first check passes over the rows the old set does not know
+    packs = [r[4]["rows"] for r in tracing.stage_records()
+             if r[0] == "secp256k1.pack"]
+    check(len(packs) == 2, "one pass a check", packs)
+    old, new = (good[h].validator_set for h in (1_000, 2_000))
+    known = [i for i, v in enumerate(new.validators)
+             if old.has_address(v.address)]
+    at = packs[1] - 2  # after the first check's last row, before the
+    check(known[packs[0] - 1] < at, "no row between the checks' ends",
+          known[packs[0] - 1], packs)  # second's: only that one sees it
+    bad = _light_chain(tampered=at)
+    got = step(bad, served)
+    want = step(bad, tv.oracle_batch_fn())
+    check(got == want == ("ErrInvalidHeader", ("invalid_signature", at)),
+          "the tampered light step", got, want)
+    check(cbatch.device_breaker().faults == faults0,
+          "the light step faulted and fell back to the host")
+    return {"validators": 96, "seats_changed": 32, "checks_rows": packs,
+            "tampered_at": at, "outcome": got}
 
 
 def leg_stream(data, host):
@@ -801,6 +909,7 @@ def main() -> int:
                           "host_reference_workers": workers}), flush=True)
         run.leg("kernels", leg_kernels)
         run.leg("commit", lambda: leg_commit(data, host))
+        run.leg("light", leg_light)
         run.leg("stream", lambda: leg_stream(data, host))
         run.leg("plane", lambda: leg_plane(data, host))
         run.leg("node", leg_node)

@@ -15,6 +15,7 @@ from __future__ import annotations
 import threading
 from typing import Callable, Dict, List, Optional, Tuple
 
+from cometbft_tpu.libs import tracing
 from cometbft_tpu.light.verifier import (
     DEFAULT_TRUST_LEVEL,
     ErrNewValSetCantBeTrusted,
@@ -241,20 +242,27 @@ class Client:
         # on a device flush) runs UNLOCKED so concurrent verifications
         # coalesce into shared plane flushes
         self._count_verification()
-        if new.height == trusted.height + 1:
-            verify_adjacent(
-                self.chain_id, trusted.signed_header, new.signed_header,
-                new.validator_set, self.trusting_period, now,
-                self.max_clock_drift, self.batch_fn,
-            )
-        else:
-            verify_non_adjacent(
-                self.chain_id, trusted.signed_header,
-                trusted.validator_set,  # vals at trusted height sign h+1..
-                new.signed_header, new.validator_set,
-                self.trusting_period, now, self.max_clock_drift,
-                self.trust_level, self.batch_fn,
-            )
+        adjacent = new.height == trusted.height + 1
+        # one always-on stage a step (libs/tracing.stage); the checks'
+        # own stages (light.trusting, light.new_set) nest in it
+        with tracing.stage("light.step", adjacent=int(adjacent),
+                           height=new.height):
+            if adjacent:
+                verify_adjacent(
+                    self.chain_id, trusted.signed_header,
+                    new.signed_header, new.validator_set,
+                    self.trusting_period, now, self.max_clock_drift,
+                    self.batch_fn,
+                )
+            else:
+                verify_non_adjacent(
+                    self.chain_id, trusted.signed_header,
+                    # vals at trusted height sign h+1..
+                    trusted.validator_set,
+                    new.signed_header, new.validator_set,
+                    self.trusting_period, now, self.max_clock_drift,
+                    self.trust_level, self.batch_fn,
+                )
 
     def _verify_sequential(self, trusted: LightBlock, target: LightBlock,
                            now: Timestamp) -> None:

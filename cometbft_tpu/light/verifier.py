@@ -16,6 +16,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Callable, Optional, Tuple
 
+from cometbft_tpu.libs import tracing
 from cometbft_tpu.types.block import Header
 from cometbft_tpu.types.commit import Commit
 from cometbft_tpu.types.timestamp import Timestamp
@@ -157,12 +158,14 @@ def verify_non_adjacent(
         raise ErrInvalidHeader("untrusted vals hash != header vals hash")
 
     # 1/3+ of the OLD (trusted) set must have signed the new header
-    # (light/verifier.go:58)
+    # (light/verifier.go:58); always-on stages around the two checks
+    # split a step's time between them and what the client does around
     try:
-        verify_commit_light_trusting(
-            chain_id, trusted_next_vals, untrusted.commit,
-            trust_level, batch_fn,
-        )
+        with tracing.stage("light.trusting", height=untrusted.height):
+            verify_commit_light_trusting(
+                chain_id, trusted_next_vals, untrusted.commit,
+                trust_level, batch_fn,
+            )
     except NotEnoughPowerError as e:
         raise ErrNewValSetCantBeTrusted(str(e)) from e
     except VerificationError as e:
@@ -170,10 +173,11 @@ def verify_non_adjacent(
 
     # 2/3+ of the NEW set must have signed it (light/verifier.go:73)
     try:
-        verify_commit_light(
-            chain_id, untrusted_vals, untrusted.commit.block_id,
-            untrusted.height, untrusted.commit, batch_fn,
-        )
+        with tracing.stage("light.new_set", height=untrusted.height):
+            verify_commit_light(
+                chain_id, untrusted_vals, untrusted.commit.block_id,
+                untrusted.height, untrusted.commit, batch_fn,
+            )
     except VerificationError as e:
         raise ErrInvalidHeader(str(e)) from e
 
@@ -205,10 +209,11 @@ def verify_adjacent(
     if untrusted_vals.hash() != untrusted.header.validators_hash:
         raise ErrInvalidHeader("untrusted vals hash != header vals hash")
     try:
-        verify_commit_light(
-            chain_id, untrusted_vals, untrusted.commit.block_id,
-            untrusted.height, untrusted.commit, batch_fn,
-        )
+        with tracing.stage("light.new_set", height=untrusted.height):
+            verify_commit_light(
+                chain_id, untrusted_vals, untrusted.commit.block_id,
+                untrusted.height, untrusted.commit, batch_fn,
+            )
     except VerificationError as e:
         raise ErrInvalidHeader(str(e)) from e
 

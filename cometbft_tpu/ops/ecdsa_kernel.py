@@ -27,6 +27,7 @@ from cometbft_tpu.crypto import secp256k1_ref as ref
 from cometbft_tpu.ops import secp256k1 as curve
 from cometbft_tpu.ops.ed25519_kernel import bucket_size, nibbles
 from cometbft_tpu.ops.field import FSECP
+from cometbft_tpu.types import canonical
 
 F = FSECP
 
@@ -49,12 +50,21 @@ def pack_batch(
     sigs: Sequence[bytes],
     pad_to: Optional[int] = None,
 ) -> PackedEcdsaBatch:
-    """Stage (pubkey33, msg, sig64) triples into device-ready arrays.
+    """Stage (pubkey33, msg, sig64) triples into device-ready arrays,
+    padded to `pad_to` rows (a served commit's chunk shape) or the
+    bucket ladder's rung for n.
 
     Malformed rows (bad lengths/prefix, x >= P, r/s out of range, high-S)
-    get precheck=False and zeroed payloads."""
+    get precheck=False and zeroed payloads. `msgs` may be a commit's
+    lazy rows (canonical.TemplateRows) or the matrix they expand to
+    (canonical.SignRows, or a run of it): SHA-256 then reads each row
+    where it lies in the matrix, and no bytes object a row is made."""
     n = len(pubkeys)
     assert len(msgs) == n and len(sigs) == n
+    if isinstance(msgs, canonical.TemplateRows):
+        msgs = msgs.expand()
+    if isinstance(msgs, canonical.SignRows):
+        msgs = [row[:ln] for row, ln in zip(msgs.mat, msgs.lens.tolist())]
     padded = pad_to if pad_to is not None else bucket_size(max(n, 1))
     assert padded >= n
 

@@ -234,7 +234,10 @@ def base_dev():
 
 
 @jax.jit
-def _verify_rows(rows, base):
+def _verify_rows_secp(rows, base):
+    # a name of its own: a device trace names the Mosaic program after
+    # it, apart from ed25519_pallas's `_verify_rows` and the sr25519
+    # kernel's `_verify_rows_sr`
     B = rows.shape[1]
     assert B % B_TILE == 0
     grid = (B // B_TILE,)
@@ -260,7 +263,7 @@ def _verify_rows(rows, base):
 
 
 def verify_rows(rows):
-    return _verify_rows(rows, base_dev())
+    return _verify_rows_secp(rows, base_dev())
 
 
 def pack_rows(pb: ek.PackedEcdsaBatch) -> np.ndarray:

@@ -349,6 +349,15 @@ def _verify_single(
 COMMIT_CHUNK_ROWS = 1024
 
 
+def _rows_matrix(msgs):
+    """A commit's lazy rows as the ONE matrix they expand to (the
+    `prepare` of a kind whose pack hashes the message bytes themselves);
+    anything else as it is."""
+    if isinstance(msgs, canonical.TemplateRows):
+        return msgs.expand()
+    return msgs
+
+
 def _verify_chunked(kind, queue: list, pub_bytes, msgs, sigs):
     """The crypto/batch kernel of one key type, `kind` = (name, pack,
     run, one_pass, prepare): reads the group's messages once
@@ -402,11 +411,12 @@ def device_batch_fn(use_pallas: Optional[bool] = None,
     Returns fn(pubs: [PubKey], msgs, sigs) -> (n,) bool validity, with
     rows grouped by key type (crypto/batch.py dispatch): ed25519 via the
     Pallas kernel on TPU backends / XLA-composed kernel elsewhere
-    (interpret-mode Pallas on CPU is far slower than the XLA path) and
-    sr25519 via its Pallas kernel, both fed as fixed-shape chunks
-    through one in-flight queue a call (_verify_chunked: every chunk of
-    both key types is dispatched before the first verdict is waited
-    for); secp256k1 via the ECDSA kernel in one pass. The voting-power
+    (interpret-mode Pallas on CPU is far slower than the XLA path),
+    sr25519 via its Pallas kernel and secp256k1 via the ECDSA kernel
+    (Pallas on TPU backends, XLA-composed elsewhere), all three fed as
+    fixed-shape chunks through one in-flight queue a call
+    (_verify_chunked: every chunk of every key type is dispatched
+    before the first verdict is waited for). The voting-power
     tally stays host-side here because VerifyCommit's early-break
     collection is inherently sequential; the fused device tally serves
     the streaming paths (blocksync replay) where whole commits are
@@ -455,8 +465,36 @@ def device_batch_fn(use_pallas: Optional[bool] = None,
                # not in a closure around _verify_chunked: that form read
                # 4.7 s more in a process's first sr25519 dispatch
                # (PERF.md section 6, PR 34)
-               lambda m: (m.expand() if isinstance(m, canonical.TemplateRows)
-                          else m))
+               _rows_matrix)
+
+    def eck():  # first used by a batch that holds a secp256k1 row
+        from cometbft_tpu.ops import ecdsa_kernel
+
+        return ecdsa_kernel
+
+    if use_pallas:
+        def ecp():
+            from cometbft_tpu.ops import ecdsa_pallas
+
+            return ecdsa_pallas
+
+        secp_rows_of = lambda pb: ecp().pack_rows(pb)  # noqa: E731
+        run_secp = lambda rows: ecp().verify_rows(rows)  # noqa: E731
+        secp_one_pass = lambda n: ecp().pad_to_tile(n)  # noqa: E731
+    else:
+        secp_rows_of = rows_of
+        run_secp = lambda pb: eck().verify_kernel(  # noqa: E731
+            pb.qx, pb.qparity, pb.u1dig, pb.u2dig, pb.xr1, pb.xr2,
+            pb.precheck)
+        secp_one_pass = one_pass
+
+    # SHA-256 reads a message's bytes where they lie in the group's
+    # one matrix, as merlin does
+    secp_kind = ("secp256k1",
+                 lambda p, m, s, pad: (
+                     secp_rows_of(eck().pack_batch(p, m, s, pad_to=pad)),
+                     isinstance(m, canonical.SignRows)),
+                 run_secp, secp_one_pass, _rows_matrix)
 
     def ed25519_cached(pub_bytes, msgs, sigs):
         # Cached-valset kernel (opt-in): ~3x the general kernel's
@@ -474,7 +512,7 @@ def device_batch_fn(use_pallas: Optional[bool] = None,
             return ec.verify_batch_cached(pub_bytes, list(msgs), sigs)
 
     def fn(pubs, msgs, sigs):
-        queue = []  # this call's passes, of either key type
+        queue = []  # this call's passes, of any key type
 
         def ed25519_verify(pub_bytes, msgs, sigs):
             if use_pallas and cached and len(pub_bytes) >= 128:
@@ -483,7 +521,9 @@ def device_batch_fn(use_pallas: Optional[bool] = None,
 
         return cbatch.verify_batch(pubs, msgs, sigs, kernels={
             "ed25519": ed25519_verify,
-            "sr25519": functools.partial(_verify_chunked, sr_kind, queue)})
+            "sr25519": functools.partial(_verify_chunked, sr_kind, queue),
+            "secp256k1": functools.partial(_verify_chunked, secp_kind,
+                                           queue)})
 
     return fn
 

@@ -520,3 +520,170 @@ def test_proxy_rides_mounted_gateway():
         gw2.stop()
         set_global_gateway(None)
         legacy.httpd.server_close()
+
+
+# --------------------------------------------------------------------------
+# a skipping step over secp256k1 validators of unequal power, against the
+# benchmark's plain reference (benchmarks/reference/ecdsa.py)
+# --------------------------------------------------------------------------
+
+SECP_H0, SECP_H1 = 10, 510
+SECP_NOW = Timestamp(T0 + SECP_H1 + 60, 0)
+
+
+def secp_chain(changed, tampered=None, doubled=None):
+    """Two light blocks 500 heights apart over 48 secp256k1 validators
+    of unequal power; `changed` seats go to newcomers in the second.
+    `tampered` flips that row's signature in the second block's commit;
+    `doubled` = (i, j) puts row i's vote in row j's place too. Returns
+    ({height: LightBlock}, what the plain reference takes of the two
+    blocks)."""
+    from cometbft_tpu.crypto.keys import Secp256k1PrivKey
+
+    privs = [Secp256k1PrivKey.generate(
+        i.to_bytes(2, "big") + b"\x6b" * 30) for i in range(96)]
+    power = {p.pub_key().address(): 500 + (37 * i) % 1001
+             for i, p in enumerate(privs)}
+    blocks, plain = {}, {}
+    for h, seats in ((SECP_H0, privs[:48]),
+                     (SECP_H1, privs[changed:48 + changed])):
+        by_addr = {p.pub_key().address(): p for p in seats}
+        vs = ValidatorSet([Validator(p.pub_key(), power[a])
+                           for a, p in by_addr.items()])
+        header = Header(
+            chain_id=CHAIN_ID, height=h, time=Timestamp(T0 + h, 0),
+            last_block_id=BlockID(), validators_hash=vs.hash(),
+            next_validators_hash=vs.hash(),
+            proposer_address=vs.validators[0].address,
+            app_hash=b"\x01" * 32)
+        bid = BlockID(header.hash(), PartSetHeader(1, header.hash()))
+        rows = []
+        for idx, v in enumerate(vs.validators):
+            ts = Timestamp(T0 + h, idx)
+            sb = canonical.canonical_vote_bytes(
+                CHAIN_ID, canonical.PRECOMMIT_TYPE, h, 0, bid, ts)
+            sig = by_addr[v.address].sign(sb)
+            if h == SECP_H1 and idx == tampered:
+                sig = sig[:9] + bytes([sig[9] ^ 1]) + sig[10:]
+            rows.append((v.pub_key.data, sb, CommitSig(
+                BLOCK_ID_FLAG_COMMIT, v.address, ts, sig)))
+        if h == SECP_H1 and doubled:
+            rows[doubled[1]] = rows[doubled[0]]
+        blocks[h] = lv.LightBlock(lv.SignedHeader(header, Commit(
+            h, 0, bid, [cs for _, _, cs in rows])), vs)
+        plain[h] = {
+            "height": h, "time_ns": header.time.to_ns(),
+            "pubs": [pub for pub, _, _ in rows],
+            "powers": [v.voting_power for v in vs.validators],
+            "msgs": [sb for _, sb, _ in rows],
+            "sigs": [cs.signature for _, _, cs in rows]}
+    return blocks, plain
+
+
+def secp_step(blocks):
+    """One `verify_light_block_at_height` of the second block from the
+    trusted first, through the device path's batch_fn (the XLA ECDSA
+    kernel, one 64-row pass a check): (outcome in the reference's
+    words, the stage ring's names)."""
+    from cometbft_tpu.libs import tracing
+
+    c = lc.Client(CHAIN_ID, lc.Provider(CHAIN_ID, blocks.get),
+                  witnesses=[], skipping=True, trust_level=(1, 3),
+                  batch_fn=validation.device_batch_fn(use_pallas=False))
+    c.trust_light_block(blocks[SECP_H0])
+    tracing.set_clock(None)  # an empty stage ring
+    try:
+        c.verify_light_block_at_height(SECP_H1, now=SECP_NOW)
+        out = ("ok",)
+    except lv.ErrInvalidHeader as e:
+        cause = e.__cause__
+        if isinstance(cause, validation.InvalidSignatureError):
+            out = ("invalid_header", "invalid_signature", cause.idx)
+        elif isinstance(cause, validation.NotEnoughPowerError):
+            out = ("invalid_header", "not_enough_power", cause.needed)
+        else:
+            out = ("invalid_header", "double_vote", str(cause)[17:])
+    except lc.NoSuchBlockError:
+        out = ("bisects",)  # ErrNewValSetCantBeTrusted: the pivot is asked
+    stored = c.store.get(SECP_H1) is not None
+    assert stored == (out == ("ok",))
+    return out, [r for r in tracing.stage_records()
+                 if r[0].startswith("light.")]
+
+
+def between_the_checks(plain_reference, plain):
+    """A commit index only the new-set check examines."""
+    ecdsa = plain_reference.ecdsa
+    old, new = plain[SECP_H0], plain[SECP_H1]
+    trusting = ecdsa.trusting_rows(
+        {ecdsa.address(k): (k, p)
+         for k, p in zip(old["pubs"], old["powers"])},
+        [ecdsa.address(k) for k in new["pubs"]], new["sigs"])[0]
+    light = ecdsa.light_rows(new["powers"], new["sigs"])[0]
+    assert trusting[-1] + 1 < light[-1]
+    return trusting, light[-1] - 1
+
+
+@pytest.mark.parametrize("case", ["accepted", "refused-by-the-new-set",
+                                  "too-few-old-seats", "double-vote"])
+def test_a_secp256k1_skip_ends_as_the_plain_reference_says(
+        plain_reference, case):
+    ecdsa = plain_reference.ecdsa
+    kw = {"changed": 40 if case == "too-few-old-seats" else 5}
+    _, plain = secp_chain(**kw)
+    if case == "refused-by-the-new-set":
+        kw["tampered"] = between_the_checks(plain_reference, plain)[1]
+    if case == "double-vote":
+        rows = between_the_checks(plain_reference, plain)[0]
+        kw["doubled"] = (rows[1], rows[2])
+    blocks, plain = secp_chain(**kw)
+    want = ecdsa.verify_non_adjacent(plain[SECP_H0], plain[SECP_H1],
+                                     SECP_NOW.to_ns(), 1e6)
+    got, stages = secp_step(blocks)
+    first = {"accepted": "ok", "too-few-old-seats": "cant_be_trusted"}
+    assert want[0] == first.get(case, "invalid_header")
+    # too few of the old seats signed: the client goes on to bisect
+    assert got == (("bisects",) if case == "too-few-old-seats" else want)
+    if case == "refused-by-the-new-set":
+        assert want == ("invalid_header", "invalid_signature",
+                        kw["tampered"])
+    if case == "double-vote":
+        assert want[:2] == ("invalid_header", "double_vote")
+    # the step and the checks it reached, each closed on its way out
+    names = [r[0] for r in stages]
+    reached = {"accepted": 2, "refused-by-the-new-set": 2,
+               "too-few-old-seats": 1, "double-vote": 1}[case]
+    assert names == ["light.trusting", "light.new_set"][:reached] + [
+        "light.step"]
+    assert stages[-1][4] == {"adjacent": 0, "height": SECP_H1}
+    for name, t0, dur, _, _ in stages[:-1]:
+        assert stages[-1][1] <= t0 and t0 + dur <= (stages[-1][1]
+                                                     + stages[-1][2])
+
+
+def test_too_few_old_seats_cannot_be_trusted(plain_reference):
+    """`verify_non_adjacent` itself, where the client above bisects:
+    ErrNewValSetCantBeTrusted, with the power the reference names."""
+    blocks, plain = secp_chain(changed=40)
+    want = plain_reference.ecdsa.verify_non_adjacent(
+        plain[SECP_H0], plain[SECP_H1], SECP_NOW.to_ns(), 1e6)
+    old, new = blocks[SECP_H0], blocks[SECP_H1]
+    with pytest.raises(lv.ErrNewValSetCantBeTrusted) as ei:
+        lv.verify_non_adjacent(
+            CHAIN_ID, old.signed_header, old.validator_set,
+            new.signed_header, new.validator_set, 1e6, SECP_NOW,
+            batch_fn=validation.device_batch_fn(use_pallas=False))
+    assert want == ("cant_be_trusted", ei.value.__cause__.needed)
+
+
+def test_an_adjacent_step_records_the_new_set_check_alone():
+    from cometbft_tpu.libs import tracing
+
+    keys = keys_for(1, 4)
+    chain = LightChain({h: keys for h in range(1, 3)})
+    c = make_client(chain)
+    tracing.set_clock(None)
+    c.verify_light_block_at_height(2, now=NOW)
+    recs = [r for r in tracing.stage_records() if r[0].startswith("light.")]
+    assert [r[0] for r in recs] == ["light.new_set", "light.step"]
+    assert recs[-1][4] == {"adjacent": 1, "height": 2}

@@ -681,3 +681,235 @@ def test_a_chunk_that_cannot_be_templated_packs_its_bytes(chunked, kind):
     assert [r[4]["templated"] for r in tracing.stage_records()
             if r[0] == "ed25519.pack"] == (
         lazy if native.available() else [0, 0])
+
+
+# --------------------------------------------------------------------------
+# device_batch_fn over secp256k1 rows: the ECDSA kernel fed as chunks
+# --------------------------------------------------------------------------
+
+
+def secp_privs(n):
+    from cometbft_tpu.crypto.keys import Secp256k1PrivKey
+
+    return [Secp256k1PrivKey.generate(
+        (i + 1).to_bytes(2, "big") + b"\x5e" * 30) for i in range(n)]
+
+
+def make_secp_rows(n, bad=()):
+    privs = secp_privs(8)
+    msgs = [b"chunked-secp-%d" % i for i in range(n)]
+    sigs = [privs[i % 8].sign(m) for i, m in enumerate(msgs)]
+    for i in bad:
+        sigs[i] = sigs[i][:10] + bytes([sigs[i][10] ^ 1]) + sigs[i][11:]
+    return [privs[i % 8].pub_key() for i in range(n)], msgs, sigs
+
+
+def make_secp_commit(n_vals, invalid=(), every=1):
+    """make_commit over secp256k1 validators of unequal power (the set's
+    order is by power). With `every` = 2 the validators take ed25519
+    and secp256k1 keys in turn."""
+    secp = secp_privs(n_vals)
+    privs = [secp[i] if i % every == 0
+             else PrivKey.generate(i.to_bytes(2, "big") * 16)
+             for i in range(n_vals)]
+    vs = ValidatorSet([Validator(p.pub_key(), 500 + (37 * i) % 1001)
+                       for i, p in enumerate(privs)])
+    by_addr = {p.pub_key().address(): p for p in privs}
+    bid = BlockID(b"\xab" * 32, PartSetHeader(2, b"\xcd" * 32))
+    sigs = []
+    for idx, v in enumerate(vs.validators):
+        ts = Timestamp(1700000000 + idx % 5, idx)
+        sig = by_addr[v.address].sign(canonical.canonical_vote_bytes(
+            CHAIN_ID, canonical.PRECOMMIT_TYPE, HEIGHT, 2, bid, ts))
+        if idx in invalid:
+            sig = sig[:10] + bytes([sig[10] ^ 1]) + sig[11:]
+        sigs.append(CommitSig(BLOCK_ID_FLAG_COMMIT, v.address, ts, sig))
+    return vs, Commit(HEIGHT, 2, bid, sigs), bid
+
+
+@pytest.fixture
+def ecdsa_standin(monkeypatch):
+    """A host stand-in for the XLA ECDSA kernel (2.5 s a 64-row pass on
+    a CPU): the true pack runs, and `verify_kernel` answers for the rows
+    that pack was given with secp256k1_ref's verdicts, padded to the
+    packed width and not ready until fetched. Returns the log:
+    ("dispatch", "secp256k1", padded rows) and ("fetch", "secp256k1")."""
+    from cometbft_tpu.crypto import secp256k1_ref as sc
+    from cometbft_tpu.ops import ecdsa_kernel as eck
+
+    log, packed = [], {}
+    real_pack = eck.pack_batch
+
+    def pack(pubs, msgs, sigs, pad_to=None):
+        pb = real_pack(pubs, msgs, sigs, pad_to=pad_to)
+        rows = msgs.tolist() if hasattr(msgs, "tolist") else list(msgs)
+        packed[id(pb.qx)] = (pb, [sc.verify(p, bytes(m), s)
+                                  for p, m, s in zip(pubs, rows, sigs)])
+        return pb
+
+    def verify_kernel(qx, qparity, u1dig, u2dig, xr1, xr2, precheck):
+        pb, valid = packed.pop(id(qx))
+        # what the reference refuses before the curve, the pack does too
+        assert list(pb.precheck[:len(valid)]) >= valid
+        log.append(("dispatch", "secp256k1", len(precheck)))
+        return LazyVerdicts(valid + [False] * (len(precheck) - len(valid)),
+                            log, "secp256k1")
+
+    monkeypatch.setattr(eck, "pack_batch", pack)
+    monkeypatch.setattr(eck, "verify_kernel", verify_kernel)
+    return log
+
+
+def test_secp256k1_chunks_through_the_real_kernel_match_the_oracle(
+        chunked):
+    """Two chunks of the one shape through the XLA ECDSA kernel itself
+    (the shape tests/test_secp256k1.py compiles): verdict for verdict
+    the host oracle's, the bad rows at a chunk's both ends."""
+    from cometbft_tpu.libs import tracing
+
+    bad = (0, T - 1, T, T + 4)
+    pubs, msgs, sigs = make_secp_rows(T + 5, bad)
+    tracing.set_clock(None)  # an empty stage ring
+    got = validation.device_batch_fn(use_pallas=False)(pubs, msgs, sigs)
+    want = validation.oracle_batch_fn()(pubs, msgs, sigs)
+    np.testing.assert_array_equal(got, want)
+    assert tuple(np.flatnonzero(~want)) == bad
+    recs = tracing.stage_records()
+    assert [r[0] for r in recs] == (
+        ["secp256k1.pack", "secp256k1.dispatch"] * 2 + ["secp256k1.fetch"])
+    assert [(r[4]["rows"], r[4]["padded"], r[4]["chunk"], r[4]["chunks"])
+            for r in recs[0:4:2]] == [(T, T, 0, 2), (5, T, 1, 2)]
+    assert [r[4]["templated"] for r in recs[0:4:2]] == [0, 0]  # a list
+
+
+@pytest.mark.parametrize("n,chunks", [(T - 1, 1), (T, 1), (T + 1, 2),
+                                      (3 * T + 5, 4)],
+                         ids=["below", "at", "one-over", "tail"])
+def test_every_secp256k1_chunk_has_one_shape(chunked, ecdsa_standin, n,
+                                             chunks):
+    from cometbft_tpu.libs import tracing
+
+    pubs, msgs, sigs = make_secp_rows(8)
+    reps = -(-n // 8)
+    tracing.set_clock(None)
+    got = validation.device_batch_fn(use_pallas=False)(
+        (pubs * reps)[:n], (msgs * reps)[:n], (sigs * reps)[:n])
+    assert got.shape == (n,) and got.all()
+    # every pass dispatched, then every verdict fetched
+    assert ecdsa_standin == ([("dispatch", "secp256k1", T)] * chunks
+                             + [("fetch", "secp256k1")] * chunks)
+    packs = [r[4] for r in tracing.stage_records()
+             if r[0] == "secp256k1.pack"]
+    assert [p["padded"] for p in packs] == [T] * chunks
+    assert [p["flying"] for p in packs] == list(range(chunks))
+    assert sum(p["rows"] for p in packs) == n
+
+
+SECP_VARIANTS = {
+    "light": lambda vs, bid, commit, fn: validation.verify_commit_light(
+        CHAIN_ID, vs, bid, HEIGHT, commit, fn),
+    "trusting": lambda vs, bid, commit, fn:
+        validation.verify_commit_light_trusting(CHAIN_ID, vs, commit,
+                                                (1, 3), fn),
+}
+
+
+@pytest.mark.parametrize("case", ["ok", "bad"])
+@pytest.mark.parametrize("variant", sorted(SECP_VARIANTS))
+def test_a_chunked_secp256k1_commit_gives_the_oracles_outcome(
+        chunked, ecdsa_standin, variant, case):
+    """A commit of secp256k1 validators of unequal power through both
+    checks of a light step: the outcome and the blame (the COMMIT's
+    index, in a later chunk) are the host oracle's, the rows reach the
+    pack as the ONE matrix the commit's lazy rows expand to."""
+    from cometbft_tpu.libs import tracing
+
+    n = 6 * T  # a light check examines some 3.2 T rows, trusting 1.4 T
+    bad = (T + 6, T + 9) if case == "bad" else ()
+    vs, commit, bid = make_secp_commit(n, invalid=bad)
+    want = outcome_of(lambda: SECP_VARIANTS[variant](
+        vs, bid, commit, validation.oracle_batch_fn()))
+    tracing.set_clock(None)
+    got = outcome_of(lambda: SECP_VARIANTS[variant](
+        vs, bid, commit, validation.device_batch_fn(use_pallas=False)))
+    assert got == want
+    assert got == (("invalid_signature", T + 6) if bad else ("ok",))
+    packs = [r[4] for r in tracing.stage_records()
+             if r[0] == "secp256k1.pack"]
+    assert len(packs) == packs[0]["chunks"] >= 2
+    assert all(p["padded"] == T and p["templated"] == 1 for p in packs)
+    # unequal powers: the examined prefix is not n * 2 // 3 + 1 rows
+    examined = sum(p["rows"] for p in packs)
+    assert examined < (n * 2 // 3 if variant == "light" else n // 3)
+
+
+def test_a_secp256k1_dispatch_fault_is_reverified_on_the_host(
+        chunked, monkeypatch):
+    """The second chunk's dispatch raises: that group is verified again
+    on the host (one breaker fault), and the verdicts stay the oracle's."""
+    from cometbft_tpu.crypto import batch as cbatch
+    from cometbft_tpu.libs import tracing
+    from cometbft_tpu.ops import ecdsa_kernel as eck
+
+    calls = []
+
+    def sick(*arrays):
+        calls.append(len(arrays[-1]))
+        if len(calls) == 2:
+            raise RuntimeError("device lost")
+        return LazyVerdicts(np.asarray(arrays[-1]) != 0, [], "secp256k1")
+
+    brk = cbatch.CircuitBreaker(failure_threshold=5)
+    monkeypatch.setattr(cbatch, "_DEVICE_BREAKER", brk)
+    monkeypatch.setattr(eck, "verify_kernel", sick)
+    bad = (3, T + 1)
+    pubs, msgs, sigs = make_secp_rows(2 * T + 5, bad)
+    tracing.set_clock(None)
+    got = validation.device_batch_fn(use_pallas=False)(pubs, msgs, sigs)
+    np.testing.assert_array_equal(
+        got, validation.oracle_batch_fn()(pubs, msgs, sigs))
+    assert tuple(np.flatnonzero(~got)) == bad
+    assert calls == [T, T] and brk.faults == 1 and brk.state == "closed"
+    names = [r[0] for r in tracing.stage_records()]
+    assert names.count("secp256k1.pack") == 2  # the third was not packed
+    assert "secp256k1.fetch" not in names
+
+
+def test_a_mixed_ed25519_secp256k1_commit_shares_one_queue(
+        chunked, ed_standin, ecdsa_standin):
+    """A commit whose validators hold ed25519 and secp256k1 keys in
+    turn: both groups are cut into chunks of the one shape, every chunk
+    of both is dispatched before the first verdict is fetched, `flying`
+    counts the chunks of either, and the blame is the oracle's."""
+    from cometbft_tpu.libs import tracing
+
+    n = 6 * T
+    probe, _, _ = make_secp_commit(n, every=2)
+    bad = next(i for i, v in enumerate(probe.validators)
+               if i > 2 * T and v.pub_key.key_type == "secp256k1")
+    for invalid in ((), (bad,)):
+        vs, commit, bid = make_secp_commit(n, invalid=invalid, every=2)
+        del ed_standin[:], ecdsa_standin[:]
+        tracing.set_clock(None)
+        got = outcome_of(lambda: validation.verify_commit_light(
+            CHAIN_ID, vs, bid, HEIGHT, commit,
+            validation.device_batch_fn(use_pallas=False)))
+        assert got == outcome_of(lambda: validation.verify_commit_light(
+            CHAIN_ID, vs, bid, HEIGHT, commit,
+            validation.oracle_batch_fn()))
+        assert got == (("invalid_signature", bad) if invalid else ("ok",))
+        log = ed_standin + ecdsa_standin
+        packs = [r for r in tracing.stage_records()
+                 if r[0].endswith(".pack")]
+        assert {r[0] for r in packs} == {"ed25519.pack", "secp256k1.pack"}
+        assert len(packs) >= 4
+        assert [r[4]["flying"] for r in packs] == list(range(len(packs)))
+        assert all(r[4]["padded"] == T for r in packs)
+        assert sorted(e[0] for e in log) == (
+            ["dispatch"] * len(packs) + ["fetch"] * len(packs))
+        # the stage ring's order: every dispatch, then the fetches
+        names = [r[0] for r in tracing.stage_records()
+                 if r[0].endswith((".dispatch", ".fetch"))]
+        assert names[:len(packs)] == [r[0][:-4] + "dispatch" for r in packs]
+        assert sorted(names[len(packs):]) == ["ed25519.fetch",
+                                              "secp256k1.fetch"]
