@@ -141,6 +141,19 @@ def _load() -> Optional[ctypes.CDLL]:
             u8p, ctypes.c_uint64, u8p, u8p, ctypes.c_uint64, u8p,
         ]
         lib.sr25519_batch_challenges.restype = None
+        lib.secp256k1_pack.argtypes = [
+            u8p, u8p, u8p, u64p, u64p, ctypes.c_uint64,
+            i32p, i32p, i32p, i32p, i32p, i32p, u8p, u64p,
+        ]
+        lib.secp256k1_pack.restype = None
+        # the differential tests' surfaces (tests/test_native.py)
+        lib.batch_sha256.argtypes = [u8p, u64p, u64p, ctypes.c_uint64, u8p]
+        lib.batch_sha256.restype = None
+        lib.secp256k1_batch_mulmod_n.argtypes = [u8p, u8p, ctypes.c_uint64,
+                                                 u8p]
+        lib.secp256k1_batch_mulmod_n.restype = None
+        lib.secp256k1_batch_invmod_n.argtypes = [u8p, ctypes.c_uint64, u8p]
+        lib.secp256k1_batch_invmod_n.restype = None
         _lib = lib
         return _lib
 
@@ -378,3 +391,50 @@ def sr25519_batch_challenges(prefix_state: bytes, pos: int,
         np.ascontiguousarray(rs, np.uint8), n, out,
     )
     return out
+
+
+def secp256k1_pack(pub_cat: bytes, sig_cat: bytes, msgs, padded: int):
+    """Full host pack of one ECDSA chunk: n concatenated 33-byte keys
+    and 64-byte signatures, and their messages as a list of bytes or as
+    rows of one matrix (anything with `mat` and `lens`, as
+    types/canonical.SignRows: each row is hashed where it lies) ->
+    device arrays padded to `padded` rows. None without the native
+    library.
+
+    Returns (qx, qparity, u1dig, u2dig, xr1, xr2, precheck), equal
+    array for array to the Python loop of ops/ecdsa_kernel.pack_batch
+    (tests/test_native.py)."""
+    lib = _load()
+    if lib is None:
+        return None
+    n = len(msgs)
+    if len(pub_cat) != 33 * n or len(sig_cat) != 64 * n or padded < n:
+        raise ValueError("secp256k1_pack: keys, signatures and messages "
+                         "of unequal number, or more rows than `padded`")
+    qx = np.zeros((padded, 20), np.int32)
+    xr1 = np.zeros((padded, 20), np.int32)
+    xr2 = np.zeros((padded, 20), np.int32)
+    qparity = np.zeros(padded, np.int32)
+    u1dig = np.zeros((padded, 64), np.int32)
+    u2dig = np.zeros((padded, 64), np.int32)
+    precheck = np.zeros(padded, np.uint8)
+    if n:
+        if hasattr(msgs, "mat"):
+            mat = np.ascontiguousarray(msgs.mat, dtype=np.uint8)
+            mlens = np.ascontiguousarray(msgs.lens, dtype=np.uint64)
+            if int(mlens.max()) > mat.shape[1]:
+                raise ValueError("secp256k1_pack: a row longer than "
+                                 "the matrix is wide")
+            moffs = np.arange(n, dtype=np.uint64) * np.uint64(mat.shape[1])
+            mdata = mat.reshape(-1)
+            if mdata.size == 0:
+                mdata = np.zeros(1, np.uint8)
+        else:
+            mdata, moffs, mlens = _msg_arrays(msgs)
+        lib.secp256k1_pack(
+            np.frombuffer(pub_cat, np.uint8), np.frombuffer(sig_cat, np.uint8),
+            mdata, moffs, mlens, n,
+            qx, qparity, u1dig, u2dig, xr1, xr2, precheck,
+            np.empty((n, 8), np.uint64),
+        )
+    return qx, qparity, u1dig, u2dig, xr1, xr2, precheck.astype(np.bool_)

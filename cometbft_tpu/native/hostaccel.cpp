@@ -6,9 +6,12 @@
 // per-signature Python call overhead from batch digesting:
 // one call hashes every (R || A || M) row of a commit.
 //
-// Self-contained FIPS 180-4 SHA-512 (no OpenSSL linkage — the image's
-// toolchain is plain g++); differentially tested against hashlib in
-// tests/test_native.py.
+// Self-contained FIPS 180-4 SHA-512 and SHA-256 (no OpenSSL linkage —
+// the image's toolchain is plain g++), reduction mod the ed25519 group
+// order L, keccak-f[1600] / STROBE-128 for the sr25519 transcripts, and
+// multiplication and inversion mod the secp256k1 group order n for the
+// ECDSA chunk pack; each differentially tested against hashlib or
+// Python integers in tests/test_native.py.
 //
 // Build: g++ -O3 -shared -fPIC -o _hostaccel.<source sha256>.so hostaccel.cpp
 // (done on demand by cometbft_tpu/native/__init__.py).
@@ -509,9 +512,8 @@ inline void f1600_one(u64* s /* 25 lanes, order x + 5y */) {
 // Full sr25519 challenge transcripts in native code
 // (crypto/merlin.py Strobe128 semantics, differential-tested in
 // tests/test_native.py). The numpy BatchStrobe route pays python+numpy
-// dispatch for every transcript op (~70 ms host_pack for a 5k-row
-// mixed commit, round-4 verdict cfg3 weakness); one C call walks each
-// lane's whole transcript.
+// dispatch for every transcript op; one C call walks each lane's whole
+// transcript.
 
 namespace {
 
@@ -625,6 +627,254 @@ void sr25519_batch_challenges(const u8* prefix, int pos, int pos_begin,
     s.append_message((const u8*)"sign:pk", 7, pks + i * 32, 32);
     s.append_message((const u8*)"sign:R", 6, rs + i * 32, 32);
     s.challenge((const u8*)"sign:c", 6, out + i * 64, 64);
+  }
+}
+
+}  // extern "C"
+
+// ---- SHA-256 (FIPS 180-4) ---------------------------------------------
+// ECDSA over secp256k1 signs SHA-256(sign-bytes). A row's message lies
+// contiguous where it is hashed (a row of the group's one matrix, or a
+// slice of a concatenated buffer), so this is one-shot: whole blocks
+// straight from the message, then the padded tail.
+
+namespace {
+
+typedef uint32_t u32;
+
+const u32 K256[64] = {
+    0x428a2f98, 0x71374491, 0xb5c0fbcf, 0xe9b5dba5, 0x3956c25b, 0x59f111f1,
+    0x923f82a4, 0xab1c5ed5, 0xd807aa98, 0x12835b01, 0x243185be, 0x550c7dc3,
+    0x72be5d74, 0x80deb1fe, 0x9bdc06a7, 0xc19bf174, 0xe49b69c1, 0xefbe4786,
+    0x0fc19dc6, 0x240ca1cc, 0x2de92c6f, 0x4a7484aa, 0x5cb0a9dc, 0x76f988da,
+    0x983e5152, 0xa831c66d, 0xb00327c8, 0xbf597fc7, 0xc6e00bf3, 0xd5a79147,
+    0x06ca6351, 0x14292967, 0x27b70a85, 0x2e1b2138, 0x4d2c6dfc, 0x53380d13,
+    0x650a7354, 0x766a0abb, 0x81c2c92e, 0x92722c85, 0xa2bfe8a1, 0xa81a664b,
+    0xc24b8b70, 0xc76c51a3, 0xd192e819, 0xd6990624, 0xf40e3585, 0x106aa070,
+    0x19a4c116, 0x1e376c08, 0x2748774c, 0x34b0bcb5, 0x391c0cb3, 0x4ed8aa4a,
+    0x5b9cca4f, 0x682e6ff3, 0x748f82ee, 0x78a5636f, 0x84c87814, 0x8cc70208,
+    0x90befffa, 0xa4506ceb, 0xbef9a3f7, 0xc67178f2};
+
+inline u32 rotr32(u32 x, int n) { return (x >> n) | (x << (32 - n)); }
+
+inline void sha256_block(u32* h, const u8* p) {
+  u32 w[64];
+  for (int i = 0; i < 16; i++) {
+    w[i] = ((u32)p[4 * i] << 24) | ((u32)p[4 * i + 1] << 16) |
+           ((u32)p[4 * i + 2] << 8) | (u32)p[4 * i + 3];
+  }
+  for (int i = 16; i < 64; i++) {
+    u32 s0 = rotr32(w[i - 15], 7) ^ rotr32(w[i - 15], 18) ^ (w[i - 15] >> 3);
+    u32 s1 = rotr32(w[i - 2], 17) ^ rotr32(w[i - 2], 19) ^ (w[i - 2] >> 10);
+    w[i] = w[i - 16] + s0 + w[i - 7] + s1;
+  }
+  u32 a = h[0], b = h[1], c = h[2], d = h[3];
+  u32 e = h[4], f = h[5], g = h[6], hh = h[7];
+  for (int i = 0; i < 64; i++) {
+    u32 S1 = rotr32(e, 6) ^ rotr32(e, 11) ^ rotr32(e, 25);
+    u32 ch = (e & f) ^ (~e & g);
+    u32 t1 = hh + S1 + ch + K256[i] + w[i];
+    u32 S0 = rotr32(a, 2) ^ rotr32(a, 13) ^ rotr32(a, 22);
+    u32 maj = (a & b) ^ (a & c) ^ (b & c);
+    u32 t2 = S0 + maj;
+    hh = g; g = f; f = e; e = d + t1;
+    d = c; c = b; b = a; a = t1 + t2;
+  }
+  h[0] += a; h[1] += b; h[2] += c; h[3] += d;
+  h[4] += e; h[5] += f; h[6] += g; h[7] += hh;
+}
+
+inline void sha256(const u8* p, u64 n, u8* out32) {
+  u32 h[8] = {0x6a09e667, 0xbb67ae85, 0x3c6ef372, 0xa54ff53a,
+              0x510e527f, 0x9b05688c, 0x1f83d9ab, 0x5be0cd19};
+  u64 rem = n;
+  for (; rem >= 64; p += 64, rem -= 64) sha256_block(h, p);
+  // 0x80, zeros, the length in bits: one block more, or two where
+  // fewer than 8 bytes are left after the 0x80
+  u8 tail[128] = {0};
+  memcpy(tail, p, rem);
+  tail[rem] = 0x80;
+  int blocks = rem < 56 ? 1 : 2;
+  store_be(tail + 64 * blocks - 8, n * 8);
+  for (int b = 0; b < blocks; b++) sha256_block(h, tail + 64 * b);
+  for (int i = 0; i < 8; i++) {
+    out32[4 * i] = (u8)(h[i] >> 24);
+    out32[4 * i + 1] = (u8)(h[i] >> 16);
+    out32[4 * i + 2] = (u8)(h[i] >> 8);
+    out32[4 * i + 3] = (u8)h[i];
+  }
+}
+
+// ---- arithmetic mod the secp256k1 group order n = 2^256 - c ------------
+// c = 0x14551231950B75FC4402DA1732FC9BEBF has 129 bits, so a 512-bit
+// product folds as lo + hi * c: three folds bring it under 2^256 + 2^134.
+
+const U256 SECP_N = {{0xBFD25E8CD0364141ULL, 0xBAAEDCE6AF48A03BULL,
+                      0xFFFFFFFFFFFFFFFEULL, 0xFFFFFFFFFFFFFFFFULL}};
+const U256 SECP_C = {{0x402DA1732FC9BEBFULL, 0x4551231950B75FC4ULL, 1, 0}};
+const U256 SECP_P = {{0xFFFFFFFEFFFFFC2FULL, 0xFFFFFFFFFFFFFFFFULL,
+                      0xFFFFFFFFFFFFFFFFULL, 0xFFFFFFFFFFFFFFFFULL}};
+// (n - 1) / 2: btcec's low-S rule refuses a larger s
+const U256 SECP_HALF_N = {{0xDFE92F46681B20A0ULL, 0x5D576E7357A4501DULL,
+                           0xFFFFFFFFFFFFFFFFULL, 0x7FFFFFFFFFFFFFFFULL}};
+// p - n: below it r + n is a second x that reduces to r
+const U256 SECP_P_MINUS_N = {{0x402DA1722FC9BAEEULL, 0x4551231950B75FC4ULL,
+                              1, 0}};
+
+inline U256 load_be256(const u8* b) {
+  return {{load_be(b + 24), load_be(b + 16), load_be(b + 8), load_be(b)}};
+}
+
+inline void store_le256(u8* out, const U256& v) {
+  for (int i = 0; i < 4; i++) {
+    u64 w = v.w[i];
+    for (int j = 0; j < 8; j++) {
+      out[8 * i + j] = (u8)w;
+      w >>= 8;
+    }
+  }
+}
+
+inline bool is_zero256(const U256& a) {
+  return (a.w[0] | a.w[1] | a.w[2] | a.w[3]) == 0;
+}
+
+// out[0 .. hn+3] = lo[0..3] + h[0 .. hn-1] * c; fits, as h * c is under
+// 2^(64 hn + 129)
+inline void fold_c(const u64* lo, const u64* h, int hn, u64* out) {
+  int on = hn + 4;
+  for (int i = 0; i < on; i++) out[i] = i < 4 ? lo[i] : 0;
+  for (int i = 0; i < hn; i++) {
+    unsigned __int128 carry = 0;
+    for (int j = 0; j < 3; j++) {
+      unsigned __int128 t =
+          (unsigned __int128)h[i] * SECP_C.w[j] + out[i + j] + carry;
+      out[i + j] = (u64)t;
+      carry = t >> 64;
+    }
+    for (int k = i + 3; carry && k < on; k++) {
+      unsigned __int128 t = (unsigned __int128)out[k] + carry;
+      out[k] = (u64)t;
+      carry = t >> 64;
+    }
+  }
+}
+
+// a * b mod n for ANY 256-bit a and b (a digest is not reduced first).
+// One body for its seven callers: inlined into each, the three unrolled
+// folds make the build two seconds longer and the pack no faster.
+__attribute__((noinline)) U256 mulmod_n(const U256& a, const U256& b) {
+  u64 t[8] = {0};
+  for (int i = 0; i < 4; i++) {
+    unsigned __int128 carry = 0;
+    for (int j = 0; j < 4; j++) {
+      unsigned __int128 p =
+          (unsigned __int128)a.w[i] * b.w[j] + t[i + j] + carry;
+      t[i + j] = (u64)p;
+      carry = p >> 64;
+    }
+    t[i + 4] = (u64)carry;
+  }
+  u64 f1[8], f2[7], f3[5];
+  fold_c(t, t + 4, 4, f1);    // under 2^386: f1[7] = 0
+  fold_c(f1, f1 + 4, 3, f2);  // under 2^260: f2[5] = f2[6] = 0
+  fold_c(f2, f2 + 4, 1, f3);  // under 2^256 + 2^134: f3[4] is 0 or 1
+  U256 r = {{f3[0], f3[1], f3[2], f3[3]}};
+  if (f3[4]) add256(r, SECP_C);  // 2^256 = c (mod n); r was under 2^134
+  if (geq256(r, SECP_N)) sub256(r, SECP_N);
+  return r;
+}
+
+// a^(n-2) mod n (Fermat; n is prime): the ONE inversion of a chunk
+inline U256 invmod_n(const U256& a) {
+  U256 e = SECP_N;
+  e.w[0] -= 2;
+  U256 r = {{1, 0, 0, 0}};
+  for (int bit = 255; bit >= 0; bit--) {
+    r = mulmod_n(r, r);
+    if ((e.w[bit >> 6] >> (bit & 63)) & 1) r = mulmod_n(r, a);
+  }
+  return r;
+}
+
+}  // namespace
+
+extern "C" {
+
+// standalone SHA-256 over rows of one buffer (differential-test surface)
+void batch_sha256(const u8* data, const u64* offs, const u64* lens, u64 n,
+                  u8* out /* n x 32 */) {
+  for (u64 i = 0; i < n; i++) sha256(data + offs[i], lens[i], out + 32 * i);
+}
+
+// standalone a * b mod n and a^-1 mod n over rows of 32 big-endian bytes,
+// the result little-endian (differential-test surface)
+void secp256k1_batch_mulmod_n(const u8* a, const u8* b, u64 n, u8* out) {
+  for (u64 i = 0; i < n; i++) {
+    store_le256(out + 32 * i,
+                mulmod_n(load_be256(a + 32 * i), load_be256(b + 32 * i)));
+  }
+}
+
+void secp256k1_batch_invmod_n(const u8* a, u64 n, u8* out) {
+  for (u64 i = 0; i < n; i++) {
+    store_le256(out + 32 * i, invmod_n(load_be256(a + 32 * i)));
+  }
+}
+
+// Full host pack for one ECDSA chunk (ops/ecdsa_kernel.pack_batch's
+// Python loop, array for array): btcec's screen (prefix 2 or 3, x < p,
+// 1 <= r < n, 1 <= s <= (n-1)/2), SHA-256 of the row's message where it
+// lies, w = s^-1 by Montgomery's trick over the screened rows (one
+// inversion, three multiplications a row), u1 = z w, u2 = r w, the x
+// candidates r and r + n, limbs and digits. The outputs come zeroed: a
+// refused row keeps precheck 0 and an all-zero payload. scratch holds
+// a row's prefix product and digest between the two sweeps.
+void secp256k1_pack(const u8* pubs /* n x 33 */, const u8* sigs /* n x 64 */,
+                    const u8* msgs, const u64* moffs, const u64* mlens,
+                    u64 n, int32_t* qx /* n x 20 */, int32_t* qparity,
+                    int32_t* u1dig /* n x 64 */, int32_t* u2dig,
+                    int32_t* xr1 /* n x 20 */, int32_t* xr2, u8* precheck,
+                    u64* scratch /* n x 8 */) {
+  U256* held = (U256*)scratch;  // [2i] prefix product, [2i+1] digest
+  U256 acc = {{1, 0, 0, 0}};
+  u8 digest[32], le[32];
+  for (u64 i = 0; i < n; i++) {
+    const u8* pk = pubs + 33 * i;
+    const u8* sig = sigs + 64 * i;
+    if (pk[0] != 2 && pk[0] != 3) continue;
+    U256 r = load_be256(sig), s = load_be256(sig + 32);
+    if (geq256(load_be256(pk + 1), SECP_P) || is_zero256(r) ||
+        geq256(r, SECP_N) || is_zero256(s) || !geq256(SECP_HALF_N, s)) {
+      continue;
+    }
+    precheck[i] = 1;
+    sha256(msgs + moffs[i], mlens[i], digest);
+    held[2 * i] = acc;
+    held[2 * i + 1] = load_be256(digest);
+    acc = mulmod_n(acc, s);
+  }
+  U256 inv = invmod_n(acc);  // of the product of every screened s
+  for (u64 i = n; i-- > 0;) {
+    if (!precheck[i]) continue;
+    const u8* pk = pubs + 33 * i;
+    const u8* sig = sigs + 64 * i;
+    U256 r = load_be256(sig), s = load_be256(sig + 32);
+    U256 w = mulmod_n(held[2 * i], inv);
+    inv = mulmod_n(inv, s);
+
+    store_le256(le, load_be256(pk + 1));
+    limbs13(le, qx + 20 * i);
+    qparity[i] = pk[0] & 1;
+    store_le256(le, mulmod_n(held[2 * i + 1], w));
+    nibbles64(le, u1dig + 64 * i);
+    store_le256(le, mulmod_n(r, w));
+    nibbles64(le, u2dig + 64 * i);
+    store_le256(le, r);
+    limbs13(le, xr1 + 20 * i);
+    if (!geq256(r, SECP_P_MINUS_N)) add256(r, SECP_N);
+    store_le256(le, r);
+    limbs13(le, xr2 + 20 * i);
   }
 }
 

@@ -23,6 +23,7 @@ from typing import NamedTuple, Optional, Sequence
 import jax
 import numpy as np
 
+from cometbft_tpu import native
 from cometbft_tpu.crypto import secp256k1_ref as ref
 from cometbft_tpu.ops import secp256k1 as curve
 from cometbft_tpu.ops.ed25519_kernel import bucket_size, nibbles
@@ -42,6 +43,7 @@ class PackedEcdsaBatch(NamedTuple):
     xr1: np.ndarray       # (B, NLIMBS) candidate x = r
     xr2: np.ndarray       # (B, NLIMBS) candidate x = r + N (or r again)
     precheck: np.ndarray  # (B,) host-side validity screen
+    native: bool = False  # packed by the one C call, not the loop below
 
 
 def pack_batch(
@@ -58,15 +60,27 @@ def pack_batch(
     get precheck=False and zeroed payloads. `msgs` may be a commit's
     lazy rows (canonical.TemplateRows) or the matrix they expand to
     (canonical.SignRows, or a run of it): SHA-256 then reads each row
-    where it lies in the matrix, and no bytes object a row is made."""
+    where it lies in the matrix, and no bytes object a row is made.
+
+    Where the native library loads and every key is 33 and every
+    signature 64 bytes long, the whole chunk is ONE C call
+    (native.secp256k1_pack) and the result's `native` is True; anything
+    else is the Python loop below. The arrays are the same either way
+    (tests/test_native.py)."""
     n = len(pubkeys)
     assert len(msgs) == n and len(sigs) == n
     if isinstance(msgs, canonical.TemplateRows):
         msgs = msgs.expand()
-    if isinstance(msgs, canonical.SignRows):
-        msgs = [row[:ln] for row, ln in zip(msgs.mat, msgs.lens.tolist())]
     padded = pad_to if pad_to is not None else bucket_size(max(n, 1))
     assert padded >= n
+    if (n and set(map(len, pubkeys)) == {33}
+            and set(map(len, sigs)) == {64}):
+        packed = native.secp256k1_pack(b"".join(pubkeys), b"".join(sigs),
+                                       msgs, padded)
+        if packed is not None:
+            return PackedEcdsaBatch(n, padded, *packed, native=True)
+    if isinstance(msgs, canonical.SignRows):
+        msgs = [row[:ln] for row, ln in zip(msgs.mat, msgs.lens.tolist())]
 
     x_raw = np.zeros((padded, 32), np.uint8)
     parity = np.zeros((padded,), np.int32)

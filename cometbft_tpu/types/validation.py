@@ -363,9 +363,11 @@ def _verify_chunked(kind, queue: list, pub_bytes, msgs, sigs):
     run, one_pass, prepare): reads the group's messages once
     (`prepare(msgs)`: what every chunk's pack will be handed runs of),
     cuts its rows into chunks, packs each on the host
-    (`pack(pubs, msgs, sigs, pad)` -> the packed rows, and whether the
-    chunk's sign-bytes never existed as Python objects: the pack
-    stage's `templated` arg, 1 or 0) and hands it to the device
+    (`pack(pubs, msgs, sigs, pad)` -> the packed rows, and the pack
+    stage's args that are known only once it is done: `templated`, 1
+    where the chunk's sign-bytes never existed as Python objects, and
+    for secp256k1 `native`, 1 where the pack was the one C call) and
+    hands it to the device
     (`run(packed)`, which returns while the device works), and returns
     the verdicts NOT YET FETCHED (cbatch.PendingVerdicts).
 
@@ -391,10 +393,9 @@ def _verify_chunked(kind, queue: list, pub_bytes, msgs, sigs):
         at = {"rows": min(n - lo, pad), "chunk": k, "chunks": chunks,
               "flying": sum(not o.is_ready() for o in queue)}
         with tracing.stage(name + ".pack", padded=pad, **at) as st:
-            packed, templated = pack(pub_bytes[lo:lo + pad],
-                                     msgs[lo:lo + pad],
-                                     sigs[lo:lo + pad], pad)
-            st.args["templated"] = int(templated)
+            packed, done = pack(pub_bytes[lo:lo + pad], msgs[lo:lo + pad],
+                                sigs[lo:lo + pad], pad)
+            st.args.update(done)
         # returns while the device runs
         with tracing.stage(name + ".dispatch", **at):
             outs.append(run(packed))
@@ -441,7 +442,7 @@ def device_batch_fn(use_pallas: Optional[bool] = None,
 
     def pack_ed(p, m, s, pad):
         pb, templated = ek.pack_templated(p, m, s, pad_to=pad)
-        return rows_of(pb), templated
+        return rows_of(pb), {"templated": int(templated)}
 
     # its pack builds a commit's lazy rows where it hashes them: as is
     ed_kind = ("ed25519", pack_ed, run_ed, one_pass, lambda m: m)
@@ -455,7 +456,7 @@ def device_batch_fn(use_pallas: Optional[bool] = None,
     sr_kind = ("sr25519",
                lambda p, m, s, pad: (
                    srk().pack_batch_sr(p, m, s, pad_to=pad),
-                   isinstance(m, canonical.SignRows)),
+                   {"templated": int(isinstance(m, canonical.SignRows))}),
                lambda rows: srk().verify_rows(rows),
                lambda n: srk().kp.pad_to_tile(n),
                # merlin hashes the message bytes themselves: a commit's
@@ -488,13 +489,16 @@ def device_batch_fn(use_pallas: Optional[bool] = None,
             pb.precheck)
         secp_one_pass = one_pass
 
+    def pack_secp(p, m, s, pad):
+        pb = eck().pack_batch(p, m, s, pad_to=pad)
+        return secp_rows_of(pb), {
+            "templated": int(isinstance(m, canonical.SignRows)),
+            "native": int(pb.native)}
+
     # SHA-256 reads a message's bytes where they lie in the group's
     # one matrix, as merlin does
-    secp_kind = ("secp256k1",
-                 lambda p, m, s, pad: (
-                     secp_rows_of(eck().pack_batch(p, m, s, pad_to=pad)),
-                     isinstance(m, canonical.SignRows)),
-                 run_secp, secp_one_pass, _rows_matrix)
+    secp_kind = ("secp256k1", pack_secp, run_secp, secp_one_pass,
+                 _rows_matrix)
 
     def ed25519_cached(pub_bytes, msgs, sigs):
         # Cached-valset kernel (opt-in): ~3x the general kernel's

@@ -256,3 +256,77 @@ def test_ecdsa_pallas_matches_oracle():
     np.testing.assert_array_equal(got, exp)
     assert not exp[2] and not exp[5] and not exp[7] and not exp[8]
     assert exp[0]
+
+
+def _openssl_on_the_packed_rows(qx, qparity, u1dig, u2dig, xr1, xr2,
+                                precheck):
+    """Stands in for ek.verify_kernel (2.5 s a 64-row pass on a CPU; the
+    real kernel over these chunks is chip_smoke's `ecdsa_chunked` leg):
+    what the packed rows SAY, judged by OpenSSL. A row's r is xr1, its
+    s = r / u2 and its digest z = u1 s mod n, its key the compressed
+    point (2 + parity, qx): verdict = precheck and ECDSA over the
+    prehashed z."""
+    from cryptography.exceptions import InvalidSignature
+    from cryptography.hazmat.primitives import hashes
+    from cryptography.hazmat.primitives.asymmetric import ec
+    from cryptography.hazmat.primitives.asymmetric.utils import (
+        Prehashed,
+        encode_dss_signature,
+    )
+    import jax.numpy as jnp
+
+    def digits(row):
+        return sum(int(d) << (4 * k) for k, d in enumerate(row))
+
+    out = np.zeros(len(precheck), bool)
+    for i in np.flatnonzero(precheck):
+        r, u1, u2 = limbs_to_int(xr1[i]), digits(u1dig[i]), digits(u2dig[i])
+        assert limbs_to_int(xr2[i]) in (r, r + ref.N) and u2
+        s = r * pow(u2, ref.N - 2, ref.N) % ref.N
+        key = ec.EllipticCurvePublicKey.from_encoded_point(
+            ec.SECP256K1(), bytes([2 + int(qparity[i])])
+            + limbs_to_int(qx[i]).to_bytes(32, "big"))
+        try:
+            key.verify(encode_dss_signature(r, s),
+                       (u1 * s % ref.N).to_bytes(32, "big"),
+                       ec.ECDSA(Prehashed(hashes.SHA256())))
+            out[i] = True
+        except InvalidSignature:
+            pass
+    return jnp.asarray(out)  # a jax.Array: the chunk loop asks is_ready()
+
+
+@pytest.mark.parametrize("library", ["native", "python-loop"])
+def test_served_ecdsa_chunks_give_the_oracles_verdicts(monkeypatch, library):
+    """device_batch_fn() over a 1,120-row secp256k1 group with one bad
+    row (two chunks of COMMIT_CHUNK_ROWS): the verdicts are
+    oracle_batch_fn()'s with the chunk pack in C and with the library
+    forced off, and the `secp256k1.pack` stage says which it was."""
+    from cometbft_tpu import native
+    from cometbft_tpu.crypto.keys import Secp256k1PrivKey
+    from cometbft_tpu.libs import tracing
+    from cometbft_tpu.types import validation
+
+    pytest.importorskip("cryptography")
+    if library == "python-loop":
+        monkeypatch.setattr(native, "_load", lambda: None)
+    elif not native.available():
+        pytest.skip("no native library here")
+    monkeypatch.setattr(ek, "verify_kernel", _openssl_on_the_packed_rows)
+    n, bad = 1120, 1077
+    ks = [Secp256k1PrivKey.generate(bytes([i + 1]) * 32) for i in range(8)]
+    msgs = [b"served-ecdsa-%d" % i * (1 + i % 9) for i in range(n)]
+    sigs = [ks[i % 8].sign(m) for i, m in enumerate(msgs)]
+    sigs[bad] = sigs[bad][:40] + bytes([sigs[bad][40] ^ 4]) + sigs[bad][41:]
+    pubs = [ks[i % 8].pub_key() for i in range(n)]
+    tracing.set_clock(None)  # an empty stage ring
+    got = validation.device_batch_fn(use_pallas=False)(pubs, msgs, sigs)
+    want = validation.oracle_batch_fn()(pubs, msgs, sigs)
+    np.testing.assert_array_equal(got, want)
+    assert np.flatnonzero(~got).tolist() == [bad]
+    packs = [r[4] for r in tracing.stage_records()
+             if r[0] == "secp256k1.pack"]
+    assert [(p["rows"], p["padded"]) for p in packs] == [
+        (1024, 1024), (96, 1024)]
+    assert [p["native"] for p in packs] == [int(library == "native")] * 2
+    assert [p["templated"] for p in packs] == [0, 0]  # a list of bytes
