@@ -357,12 +357,13 @@ def _annotation():
 
 
 class _Stage:
-    __slots__ = ("name", "args", "t0", "ms", "_ann")
+    __slots__ = ("name", "args", "t0", "ms", "_ann", "_event")
 
-    def __init__(self, name: str, args: dict):
+    def __init__(self, name: str, args: dict, event: bool = True):
         self.name = name
         self.args = args
         self.ms = 0.0
+        self._event = event
 
     def __enter__(self):
         cls = _ANNOTATION or _annotation()
@@ -385,7 +386,7 @@ class _Stage:
         _STAGES.append((self.name, self.t0, dur, threading.get_ident(),
                         self.args))
         t = _TRACER
-        if t is not None:
+        if t is not None and self._event:
             t._complete(self.name, "", self.t0, dur, self.args)
         return False
 
@@ -414,6 +415,16 @@ def stage(name: str, **args) -> _Stage:
     Call sites fire per stage of an operation or per block, never per
     signature."""
     return _Stage(name, args)
+
+
+def stage_untraced(name: str, **args) -> _Stage:
+    """:func:`stage` without the tracer's "X" event, for a region whose
+    stamps the simnet's virtual clock cannot make repeat: a thread's
+    idle wait (the verify plane's ``plane.wait``) begins whenever that
+    thread gets there, while the event loop moves the clock on. The
+    ring and any profiler capture still get it; the exported trace of
+    one (seed, schedule) stays identical."""
+    return _Stage(name, args, event=False)
 
 
 def stages() -> List[tuple]:

@@ -1226,42 +1226,45 @@ class VerifyPlane:
             shed: List[_Submission] = []
             depth = 0
             with self._cv:
-                while self._running:
-                    cq = self._pending[LANE_CONSENSUS]
-                    waitq = wait_lane = None
-                    if not cq:
-                        # highest-priority sheddable lane with traffic
-                        # coalesces under its own longer window
-                        for lane in SHEDDABLE_LANES:
-                            if self._pending[lane]:
-                                waitq, wait_lane = \
-                                    self._pending[lane], lane
+                # ONE record a drain cycle, however many timeouts it
+                # held; no tracer event (see tracing.stage_untraced)
+                with tracing.stage_untraced("plane.wait", deck=len(deck)):
+                    while self._running:
+                        cq = self._pending[LANE_CONSENSUS]
+                        waitq = wait_lane = None
+                        if not cq:
+                            # highest-priority sheddable lane with traffic
+                            # coalesces under its own longer window
+                            for lane in SHEDDABLE_LANES:
+                                if self._pending[lane]:
+                                    waitq, wait_lane = \
+                                        self._pending[lane], lane
+                                    break
+                        if cq:
+                            # CONSENSUS owns the flush window: full GATEWAY
+                            # or BULK queues can never delay a consensus
+                            # flush past its deadline — their rows only
+                            # ride along
+                            age = time.perf_counter() - cq[0].t_submit
+                            if (deck
+                                    or age >= self.window
+                                    or self._pending_rows[LANE_CONSENSUS]
+                                    >= self.max_batch):
                                 break
-                    if cq:
-                        # CONSENSUS owns the flush window: full GATEWAY
-                        # or BULK queues can never delay a consensus
-                        # flush past its deadline — their rows only
-                        # ride along
-                        age = time.perf_counter() - cq[0].t_submit
-                        if (deck
-                                or age >= self.window
-                                or self._pending_rows[LANE_CONSENSUS]
-                                >= self.max_batch):
-                            break
-                        self._cv.wait(timeout=self.window - age)
-                    elif waitq is not None:
-                        win = self.lane_window[wait_lane]
-                        age = time.perf_counter() - waitq[0].t_submit
-                        if (deck
-                                or age >= win
-                                or self._pending_rows[wait_lane]
-                                >= self.max_batch):
-                            break
-                        self._cv.wait(timeout=win - age)
-                    elif deck:
-                        break  # nothing to pack: land a flight
-                    else:
-                        self._cv.wait(timeout=0.25)
+                            self._cv.wait(timeout=self.window - age)
+                        elif waitq is not None:
+                            win = self.lane_window[wait_lane]
+                            age = time.perf_counter() - waitq[0].t_submit
+                            if (deck
+                                    or age >= win
+                                    or self._pending_rows[wait_lane]
+                                    >= self.max_batch):
+                                break
+                            self._cv.wait(timeout=win - age)
+                        elif deck:
+                            break  # nothing to pack: land a flight
+                        else:
+                            self._cv.wait(timeout=0.25)
                 if not self._running \
                         and not any(self._pending[lane]
                                     for lane in LANES):
@@ -1477,22 +1480,34 @@ class VerifyPlane:
         must resolve even when the runtime offers no readiness probe.
         Only ever called with device flights airborne, so the simnet
         host path (and its ledger determinism) never touches the
-        real-clock polling here."""
-        idx = _ready_index(deck)
-        if idx is None:
-            deadline = time.perf_counter() + max(self.window, 0.1)
-            while True:
-                with self._cv:
-                    if self._running and not self._depth_locked():
-                        self._cv.wait(timeout=0.005)
-                    if self._depth_locked():
-                        return  # pack the new flush first
-                idx = _ready_index(deck)
-                if idx is not None or not self._running \
-                        or time.perf_counter() >= deadline:
-                    break
+        real-clock polling here. The wait is one "plane.land" stage;
+        what it came to is known, and put in its args, when it ends."""
+        polls, packed = 1, 0
+        with tracing.stage("plane.land") as land:
+            idx = _ready_index(deck)
+            if idx is None:
+                deadline = time.perf_counter() + max(self.window, 0.1)
+                while True:
+                    with self._cv:
+                        if self._running and not self._depth_locked():
+                            self._cv.wait(timeout=0.005)
+                        if self._depth_locked():
+                            packed = 1  # pack the new flush first
+                            break
+                    idx = _ready_index(deck)
+                    polls += 1
+                    if idx is not None or not self._running \
+                            or time.perf_counter() >= deadline:
+                        break
+            # probes made, whether one said ready (else FIFO), whether
+            # new work cut the wait short
+            land.args.update(polls=polls, ready=int(idx is not None),
+                             packed=packed)
+            if packed:
+                return
             if idx is None:
                 idx = 0  # probe can't tell: land FIFO, collect blocks
+            land.args["flush"] = deck[idx].fid
         self._finish_flight(deck.pop(idx))
         self._deck_update(deck)
 
@@ -1526,48 +1541,28 @@ class VerifyPlane:
         return halves[0]
 
     def _finish_flight(self, flight: _Flight) -> None:
-        # hook audit (r05 post-mortem suspect #1): every tracing span
-        # here sits behind an `enabled()` check so the DISABLED path
-        # constructs no span object and no kwargs dict — the only
-        # per-flush bookkeeping is the ledger stamps (plain int clock
-        # reads) and the ring tuple.
+        # the stages are the flush's only clock reads here: the ledger's
+        # collect_ms / settle_ms are their .ms, flight_ms and dev_ms
+        # hang on their start stamps
         batch, finish, airborne, fid, led = (
             flight.batch, flight.finish, flight.airborne, flight.fid,
             flight.led)
-        traced = tracing.enabled()
-        t_exec = tracing.monotonic_ns()
         # collect-time compiles (first grouped-path kernel build, a
         # faulted flight's host fallback re-trace) attribute to this
         # flush too — comp_ms must name every compile the flush paid
         attr = deviceledger.attr_begin("plane.collect", led[_L_SEQ])
+        # a synchronous flush's deferred host/grouped verification
+        # happens here, under its own name
+        with tracing.stage("plane.collect" if airborne else "plane.verify",
+                           flush=fid) as collect:
+            verdicts, fused_tallies = finish()
         if airborne:
-            if traced:
-                with tracing.span("plane.collect", cat="verifyplane",
-                                  flush=fid):
-                    verdicts, fused_tallies = finish()
-                tracing.flight_end("plane.flight", fid, cat="verifyplane")
-            else:
-                verdicts, fused_tallies = finish()
-        else:
-            # synchronous flush: the deferred host/grouped verification
-            # happens here, attributed to its own stage
-            if traced:
-                with tracing.span("plane.verify", cat="verifyplane",
-                                  flush=fid):
-                    verdicts, fused_tallies = finish()
-            else:
-                verdicts, fused_tallies = finish()
+            tracing.flight_end("plane.flight", fid, cat="verifyplane")
         deviceledger.attr_end(attr)
         if attr.ms:
             led[_L_COMP] = round(led[_L_COMP] + attr.ms, 3)
-        t_settle = tracing.monotonic_ns()
-        if traced:
-            with tracing.span("plane.settle", cat="verifyplane",
-                              flush=fid):
-                self._settle(batch, verdicts, fused_tallies=fused_tallies)
-        else:
+        with tracing.stage("plane.settle", flush=fid) as settle:
             self._settle(batch, verdicts, fused_tallies=fused_tallies)
-        t_done = tracing.monotonic_ns()
         # flight_ms: time the pass was airborne before the dispatcher
         # came back for it (the overlap window the double buffer wins);
         # collect_ms: the blocking fetch (or the sync verify itself).
@@ -1580,17 +1575,18 @@ class VerifyPlane:
         # timings are recorded as 0.0 then; the record itself stays.
         if tracing.clock_gen() == led[_L_GEN]:
             if airborne:
-                led[_L_FLIGHT] = round((t_exec - led[_L_TPACKED]) / 1e6, 3)
+                led[_L_FLIGHT] = round(
+                    (collect.t0 - led[_L_TPACKED]) / 1e6, 3)
                 # on-device time estimate: dispatch -> the first TRUE
                 # readiness probe when the deck observed one (the
                 # kernel-flight figure), else dispatch -> fetch done
                 # (an upper bound that includes the d2h copy)
                 ready_ns = led[_L_READY]
                 led[_L_DEV] = round(
-                    ((ready_ns if ready_ns else t_settle)
+                    ((ready_ns if ready_ns else settle.t0)
                      - led[_L_TPACKED]) / 1e6, 3)
-            led[_L_COLLECT] = round((t_settle - t_exec) / 1e6, 3)
-            led[_L_SETTLE] = round((t_done - t_settle) / 1e6, 3)
+            led[_L_COLLECT] = round(collect.ms, 3)
+            led[_L_SETTLE] = round(settle.ms, 3)
         self._charge_flush(led)
         self.ledger.record(led)
 
@@ -1637,14 +1633,9 @@ class VerifyPlane:
         flies. `deck` is the airborne flights: the fan-out policy picks
         a disjoint half for this flush, and a flush the policy sends to
         the full mesh lands the deck before dispatching. The whole
-        host-side staging is one "plane.pack" trace span keyed by
-        flush id, so pack(k+1) visibly overlaps device-flight(k) in
-        the exported timeline.
-
-        Ledger accounting happens on BOTH paths: the disabled-tracing
-        fast path still stamps the clock and fills the scratch list
-        (ints and interned strings only — no dict/span construction,
-        the r05 post-mortem's suspect #1)."""
+        host-side staging is one always-on "plane.pack" stage keyed by
+        flush id (its .ms is the ledger's pack_ms), so pack(k+1)
+        visibly overlaps device-flight(k) in the exported timeline."""
         fid = next(_FLUSH_IDS)
         self._packs += 1
         t0 = tracing.monotonic_ns()
@@ -1683,20 +1674,12 @@ class VerifyPlane:
             # the join key consumers read AFTER the future resolves
             # (height ledger -> /dump_flushes attribution)
             s.future.flush_seq = led[_L_SEQ]
-        if not tracing.enabled():
-            # disabled fast path: no O(batch) span-arg computation on
-            # the dispatcher hot path
+        with tracing.stage("plane.pack", flush=fid, rows=rows,
+                           subs=len(batch), queued_ms=queued_ms) as pack:
             finish, airborne, devs, ready = self._stage_inner(
                 batch, fid, led, deck)
-        else:
-            with tracing.span("plane.pack", cat="verifyplane", flush=fid,
-                              rows=rows, subs=len(batch),
-                              queued_ms=queued_ms):
-                finish, airborne, devs, ready = self._stage_inner(
-                    batch, fid, led, deck)
-        t1 = tracing.monotonic_ns()
-        led[_L_PACK] = round((t1 - t0) / 1e6, 3)
-        led[_L_TPACKED] = t1
+        led[_L_PACK] = round(pack.ms, 3)
+        led[_L_TPACKED] = pack.t0 + round(pack.ms * 1e6)  # its end, ns
         if ready is not None:
             # wrap the readiness probe to stamp the FIRST true reading
             # (dispatcher thread only): dev_ms = dispatch -> kernel
@@ -1809,9 +1792,8 @@ class VerifyPlane:
             # — comp_ms in the ledger, site/flush_seq in /dump_devices
             attr = deviceledger.attr_begin("plane.flush", led[_L_SEQ])
             try:
-                t_d0 = tracing.monotonic_ns()
-                fz.dispatch_fused(plan)
-                t_d1 = tracing.monotonic_ns()
+                with tracing.stage("plane.dispatch", flush=fid) as disp:
+                    fz.dispatch_fused(plan)
                 deviceledger.attr_end(attr)
                 tracing.flight_begin("plane.flight", fid,
                                      cat="verifyplane", rows=len(rows))
@@ -1827,8 +1809,7 @@ class VerifyPlane:
                     # h2d estimate: the synchronous dispatch wall
                     # (device_put staging + kernel enqueue) net of the
                     # compile time attributed above
-                    led[_L_H2D] = round(
-                        max((t_d1 - t_d0) / 1e6 - attr.ms, 0.0), 3)
+                    led[_L_H2D] = round(max(disp.ms - attr.ms, 0.0), 3)
                 if plan.mesh is not None:
                     led[_L_PATH] = PATH_FUSED_SHARDED
                     led[_L_NDEV] = plan.n_dev

@@ -172,6 +172,24 @@ def test_stage_exports_the_event_a_span_would():
         ("unit.same", 3000, 1000), ("unit.bare", 5000, 1000)]
 
 
+def test_stage_untraced_is_in_the_ring_and_not_in_the_trace():
+    """A stage whose stamps a virtual clock cannot make repeat (the
+    verify plane's idle wait) keeps its ring record and its .ms, and
+    pushes no event of the exported trace."""
+    ticks = iter(range(1000, 100000, 1000))
+    tracing.enable(capacity=16, clock=lambda: next(ticks),
+                   deterministic=True)
+    with tracing.stage_untraced("unit.wait", deck=1) as st:
+        pass
+    with tracing.stage("unit.traced", deck=1):
+        pass
+    assert [e["name"] for e in tracing.export_chrome()["traceEvents"]] \
+        == ["unit.traced"]
+    assert [(r[0], r[2], r[4]) for r in tracing.stage_records()] == [
+        ("unit.wait", 1000, {"deck": 1}), ("unit.traced", 1000, {"deck": 1})]
+    assert st.ms == 0.001
+
+
 def test_set_clock_clears_stages_and_a_virtual_clock_repeats():
     def run():
         ticks = iter(range(0, 10**6, 250))

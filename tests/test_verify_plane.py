@@ -634,11 +634,12 @@ def test_flush_ledger_summary_stamp_attribution():
 
 def _disabled_flush_bookkeeping_us(k):
     """One replay of the always-on accounting _stage/_finish_flight run
-    per flush with tracing off (four clock reads, the one FIELDS-ordered
-    scratch list that becomes the ring slot, the in-place stage fills,
-    the ring append), then of one disabled tracing.span() behind the
-    guard every flush-path hook uses. Returns (ledger us per flush,
-    span us per call)."""
+    per flush with tracing off (one clock read, the one FIELDS-ordered
+    scratch list that becomes the ring slot, the three stages a
+    host-path flush enters and whose .ms fill its columns, the ring
+    append), then of one disabled tracing.span() behind the guard the
+    flush path's instants use. Returns (ledger us per flush, span us
+    per call)."""
     from cometbft_tpu.libs import tracing
     from cometbft_tpu.verifyplane.plane import (
         PATH_HOST,
@@ -658,12 +659,16 @@ def _disabled_flush_bookkeeping_us(k):
                PATH_HOST, STAMP_HOST, "closed", 0, 0, 64, 0, 0, 0, 1,
                1, 0, 0, 0.0, 0.0, 0, 0.0, 0.0, (), SPLIT_EXACT,
                t0, t0, gen, 0]
-        t1 = tracing.monotonic_ns()
-        rec[5] = round((t1 - t0) / 1e6, 3)
-        t2 = tracing.monotonic_ns()
-        rec[7] = round((t2 - t1) / 1e6, 3)
-        t3 = tracing.monotonic_ns()
-        rec[8] = round((t3 - t2) / 1e6, 3)
+        with tracing.stage("plane.pack", flush=i, rows=64, subs=4,
+                           queued_ms=0.0) as st:
+            pass
+        rec[5] = round(st.ms, 3)
+        with tracing.stage("plane.verify", flush=i) as st:
+            pass
+        rec[7] = round(st.ms, 3)
+        with tracing.stage("plane.settle", flush=i) as st:
+            pass
+        rec[8] = round(st.ms, 3)
         led.record(rec)
     ledger_us = (time.perf_counter() - t_led) * 1e6 / k
     assert len(rec) == len(FlushLedger.FIELDS) + 4, "replay drifted"
@@ -678,10 +683,10 @@ def _disabled_flush_bookkeeping_us(k):
 
 def test_disabled_flush_path_bookkeeping():
     """What every flush pays with tracing off: the ledger's bookkeeping
-    (some 6 us on this sandbox) stays under 50 us, small beside a
-    flush that takes a millisecond or more, and one disabled span under
-    10 us. Best of 3: one reading on a shared host measures the
-    neighbours."""
+    and its three stages (some 10 us on this sandbox with jax
+    imported) stay under 50 us, small beside a flush that takes a
+    millisecond or more, and one disabled span under 10 us. Best of 3:
+    one reading on a shared host measures the neighbours."""
     rows = [_disabled_flush_bookkeeping_us(5_000) for _ in range(3)]
     best_ledger = min(r[0] for r in rows)
     best_span = min(r[1] for r in rows)
@@ -750,3 +755,244 @@ def test_plane_pack_metrics_and_overlap_counters(plane):
     count_line = [ln for ln in text.splitlines()
                   if ln.startswith("cometbft_verifyplane_pack_seconds_count")]
     assert count_line and float(count_line[0].split()[-1]) >= 1
+
+
+# -- the dispatcher's always-on stages (ISSUE 37) ---------------------------
+
+
+@pytest.fixture()
+def stage_ring():
+    """An empty stage ring, no tracer, before and after."""
+    from cometbft_tpu.libs import tracing
+
+    tracing.disable()  # a change of clock domain clears the ring
+    yield tracing
+    tracing.disable()
+
+
+def _by_name(recs):
+    out = {}
+    for r in recs:
+        out.setdefault(r[0], []).append(r)
+    return out
+
+
+def _ms(rec):
+    return round(rec[2] / 1e6, 3)
+
+
+def _inside(inner, outer):
+    return outer[1] <= inner[1] and inner[1] + inner[2] <= outer[1] + outer[2]
+
+
+def test_host_flush_leaves_its_stages_with_no_tracer(stage_ring):
+    """With NO tracer a host-path flush leaves plane.wait / .pack /
+    .verify / .settle in the stage ring, on the dispatcher's thread,
+    one flush id across them, and the ledger's columns are the stages'
+    own durations."""
+    assert not stage_ring.enabled()
+    p = VerifyPlane(window_ms=0.5, use_device=False)
+    p.start()
+    tid = p._thread.ident
+    try:
+        pubs, msgs, sigs, exp = make_rows(3)
+        got = p.submit_and_wait(pubs, msgs, sigs)
+    finally:
+        p.stop()
+    assert list(got) == exp
+    recs = stage_ring.stage_records()
+    assert recs and {r[3] for r in recs} == {tid}
+    by = _by_name(recs)
+    assert set(by) == {"plane.wait", "plane.pack", "plane.verify",
+                       "plane.settle"}
+    (pack,), (verify,), (settle,) = (by["plane.pack"], by["plane.verify"],
+                                     by["plane.settle"])
+    assert all(w[4] == {"deck": 0} for w in by["plane.wait"])
+    # the cycle that cut the flush ended before its pack began
+    assert by["plane.wait"][0][1] + by["plane.wait"][0][2] <= pack[1]
+    fid = pack[4]["flush"]
+    assert pack[4] == {"flush": fid, "rows": 3, "subs": 1,
+                       "queued_ms": pack[4]["queued_ms"]}
+    assert pack[4]["queued_ms"] >= 0.4  # it sat out the 0.5 ms window
+    assert verify[4] == {"flush": fid} and settle[4] == {"flush": fid}
+    assert pack[1] + pack[2] <= verify[1] <= settle[1]
+    (led,) = p.dump_flushes()["flushes"]
+    assert led["path"] == "host"
+    assert (led["pack_ms"], led["collect_ms"], led["settle_ms"]) == (
+        _ms(pack), _ms(verify), _ms(settle))
+    assert led["flight_ms"] == 0.0 and led["h2d_ms"] == 0.0
+    assert stage_ring.stages_dropped() == 0
+    assert stage_ring.export_chrome()["traceEvents"] == []
+
+
+def test_traced_flush_keeps_the_spans_names_and_args(stage_ring):
+    """With a tracer on, the stages export the "X" events the spans
+    did, under the same names with the same args; plane.wait, whose
+    stamps the simnet's clock cannot make repeat, exports none."""
+    stage_ring.enable(capacity=256)
+    p = VerifyPlane(window_ms=0.5, use_device=False)
+    p.start()
+    try:
+        pubs, msgs, sigs, _ = make_rows(2)
+        p.submit_and_wait(pubs, msgs, sigs)
+    finally:
+        p.stop()
+    evs = stage_ring.export_chrome()["traceEvents"]
+    spans = {e["name"]: e for e in evs if e["ph"] == "X"}
+    assert set(spans) == {"plane.pack", "plane.verify", "plane.settle"}
+    fid = spans["plane.pack"]["args"]["flush"]
+    assert set(spans["plane.pack"]["args"]) == {"flush", "rows", "subs",
+                                                "queued_ms"}
+    assert spans["plane.pack"]["args"]["rows"] == 2
+    assert spans["plane.verify"]["args"] == {"flush": fid}
+    assert spans["plane.settle"]["args"] == {"flush": fid}
+    assert [e["name"] for e in evs if e["ph"] == "i"] == ["plane.submit"]
+    # the ring has them all, the wait too, on the tracer's clock
+    by = _by_name(stage_ring.stage_records())
+    assert "plane.wait" in by
+    assert by["plane.pack"][0][1] / 1000.0 == spans["plane.pack"]["ts"]
+
+
+def test_an_idle_plane_writes_one_wait_record_at_most(stage_ring):
+    """plane.wait is ONE record a drain cycle, however many timeouts of
+    the condition variable the cycle held: nothing while the plane
+    idles, one when it stops."""
+    p = VerifyPlane(window_ms=0.5, use_device=False)
+    p.start()
+    try:
+        time.sleep(0.6)  # two 0.25 s timeouts and a part of a third
+        assert stage_ring.stage_records() == []
+    finally:
+        p.stop()
+    recs = stage_ring.stage_records()
+    assert [r[0] for r in recs] == ["plane.wait"]
+    assert recs[0][2] >= 0.6e9
+
+
+class _FakeFused:
+    """Stand-ins for verifyplane.fused's five calls the dispatcher
+    makes, so that a flush flies (`path` fused, a flight on the deck, a
+    readiness probe) without a device program: the plane's own loop,
+    stages and ledger are what runs. `ready_after` probes say not
+    ready, then every one says ready (None: never)."""
+
+    def __init__(self, ready_after):
+        from types import SimpleNamespace
+
+        self.ready_after = ready_after
+        self.probes = 0
+        self.ns = SimpleNamespace
+
+    def install(self, monkeypatch):
+        from cometbft_tpu.verifyplane import fused as fz
+
+        for name in ("plan_fused", "dispatch_fused", "collect_fused",
+                     "plan_ready", "plan_h2d_bytes"):
+            monkeypatch.setattr(fz, name, getattr(self, name))
+
+    def plan_fused(self, batch, **_):
+        rows = [r for sub in batch for r in sub.rows]
+        return self.ns(rows=rows, drain_first=False, stamped=True,
+                       delta_bytes=0, util=0.25, mesh=None, n_dev=1,
+                       devs=(0,), warm=True)
+
+    def dispatch_fused(self, plan):
+        time.sleep(0.001)
+        self.probes = 0
+
+    def plan_ready(self, plan):
+        self.probes += 1
+        return (self.ready_after is not None
+                and self.probes > self.ready_after)
+
+    def plan_h2d_bytes(self, plan):
+        return 80 * len(plan.rows)
+
+    def collect_fused(self, plan):
+        time.sleep(0.001)
+        return [ed.verify(p.data, m, s) for p, m, s in plan.rows], {}
+
+
+@pytest.mark.parametrize("ready_after,polls,ready", [
+    (0, 1, 1),      # ready at the first probe: no sleep at all
+    (2, 3, 1),      # two poll slices, then a probe that says ready
+    (None, None, 0),  # the probe never tells: FIFO after the deadline
+])
+def test_fused_flush_adds_dispatch_land_and_collect(
+        stage_ring, monkeypatch, ready_after, polls, ready):
+    """A flush that flies adds plane.dispatch inside plane.pack,
+    plane.land (probes made, whether one said ready, the flight it
+    chose) and plane.collect; the ledger's pack_ms / collect_ms /
+    settle_ms / h2d_ms are the stages' durations and flight_ms runs
+    from the pack's end to the collect's start."""
+    fake = _FakeFused(ready_after)
+    fake.install(monkeypatch)
+    p = VerifyPlane(window_ms=0.5, use_device=True)
+    p.start()
+    tid = p._thread.ident
+    try:
+        pubs, msgs, sigs, exp = make_rows(4)
+        got = p.submit_and_wait(pubs, msgs, sigs)
+    finally:
+        p.stop()
+    (led,) = p.dump_flushes()["flushes"]
+    assert led["path"] == "fused" and led["stamp"] == "device"
+    assert list(got) == exp
+    recs = stage_ring.stage_records()
+    assert {r[3] for r in recs} == {tid}
+    by = _by_name(recs)
+    assert set(by) == {"plane.wait", "plane.pack", "plane.dispatch",
+                       "plane.land", "plane.collect", "plane.settle"}
+    (pack,), (disp,), (land,), (collect,), (settle,) = (
+        by["plane.pack"], by["plane.dispatch"], by["plane.land"],
+        by["plane.collect"], by["plane.settle"])
+    fid = pack[4]["flush"]
+    assert _inside(disp, pack) and disp[4] == {"flush": fid}
+    # two drain cycles a flush: the one that cut it (deck 0) and the one
+    # that found nothing to pack and went to land it (deck 1)
+    assert [w[4]["deck"] for w in by["plane.wait"]][:2] == [0, 1]
+    assert land[4]["flush"] == fid and land[4]["packed"] == 0
+    assert land[4]["ready"] == ready and land[4]["polls"] >= 1
+    if polls is not None:
+        assert land[4]["polls"] == polls == fake.probes
+    else:
+        assert land[2] >= 0.1e9 and land[4]["polls"] == fake.probes >= 2
+    assert collect[4] == {"flush": fid} and settle[4] == {"flush": fid}
+    assert pack[1] + pack[2] <= land[1]
+    assert land[1] + land[2] <= collect[1] <= settle[1]
+    assert (led["pack_ms"], led["collect_ms"], led["settle_ms"],
+            led["h2d_ms"]) == (_ms(pack), _ms(collect), _ms(settle),
+                               _ms(disp))
+    assert led["flight_ms"] == pytest.approx(
+        (collect[1] - pack[1] - pack[2]) / 1e6, abs=0.002)
+    # the land is the flight but for the loop's way there and back
+    assert 0 <= led["flight_ms"] - land[2] / 1e6 < 50.0
+
+
+def test_land_cut_short_by_new_work_says_packed(stage_ring, monkeypatch):
+    """New work that arrives while a flight is airborne ends the land
+    with `packed` 1 and no flight chosen; the flush it cuts is packed
+    with the first still on the deck."""
+    fake = _FakeFused(None)  # never ready: the land waits in slices
+    fake.install(monkeypatch)
+    p = VerifyPlane(window_ms=0.5, use_device=True)
+    p.start()
+    try:
+        pubs, msgs, sigs, exp = make_rows(2)
+        f0 = p.submit(pubs[0], msgs[0], sigs[0])
+        deadline = time.monotonic() + 5.0
+        while p.deck_airborne == 0 and time.monotonic() < deadline:
+            time.sleep(0.001)
+        f1 = p.submit(pubs[1], msgs[1], sigs[1])
+        assert [f0.result(10.0), f1.result(10.0)] == [(exp[0],), (exp[1],)]
+    finally:
+        p.stop()
+    lands = _by_name(stage_ring.stage_records())["plane.land"]
+    assert lands[0][4]["packed"] == 1 and "flush" not in lands[0][4]
+    assert lands[0][4]["ready"] == 0
+    # with one flight allowed the second dispatch lands the first
+    # (_land_one: no wait, no stage); the second lands through the wait
+    chosen = [ld[4]["flush"] for ld in lands if not ld[4]["packed"]]
+    packs = _by_name(stage_ring.stage_records())["plane.pack"]
+    assert len(packs) == 2
+    assert chosen == [packs[1][4]["flush"]]
