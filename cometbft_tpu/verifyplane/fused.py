@@ -34,7 +34,9 @@ while a flush past the half's per-device budget (or over the
 half_mesh_rows knob) takes the full mesh and sets ``drain_first`` so
 the dispatcher lands the airborne deck before dispatching it.
 plan_ready is the non-blocking landing probe that lets the deck settle
-flights out of order.
+flights out of order; plan_wait is its blocking twin, on which the
+plane's lander thread sits so that the dispatcher is woken when a
+flight's results are ready rather than a poll slice later.
 
 This is the plane's TPU specialization; it is bypassed on CPU backends
 (the interpret-mode cached kernel costs minutes of compile) where the
@@ -518,6 +520,21 @@ def plan_ready(plan: _Plan) -> bool:
         return all(bool(a.is_ready()) for a in p)
     except Exception:  # noqa: BLE001 - no readiness probe: FIFO lands
         return False
+
+
+def plan_wait(plan: _Plan) -> None:
+    """Blocking twin of plan_ready: returns once every in-flight output
+    array of a dispatched plan is ready to fetch (at once where nothing
+    is pending). It fetches nothing, and the wait releases the
+    interpreter lock. An in-flight device fault may raise out of it;
+    the plane's lander thread swallows that, and the fault surfaces in
+    collect_fused under the breaker, where it always did."""
+    p = plan.pending
+    if p is None:
+        return
+    import jax
+
+    jax.block_until_ready(p)
 
 
 def plan_h2d_bytes(plan: _Plan) -> int:

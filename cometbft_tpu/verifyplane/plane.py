@@ -43,11 +43,19 @@ flushes airborne at once instead of a single in-flight slot. With a
 — while flush k verifies on one half, flush k+1 packs on the host AND
 dispatches on the other half, so no chip idles between collect(k) and
 dispatch(k+1). Landing is out-of-order (fused.plan_ready probes
-readiness; flight k+1 finishing first never blocks behind k), and the
-size-aware policy in fused.plan_fused sends a flush past one half's
-budget (or the half_mesh_rows knob) to the full mesh after draining
-the deck. The private staging pool is flights+1 deep per shape so
-pack(k+2) never waits on a buffer still pinned under flight k.
+readiness; flight k+1 finishing first never blocks behind k). With
+a flight airborne and nothing to pack the dispatcher sleeps until it
+is woken: a device plane's LANDER thread blocks on each airborne
+flight's outputs in dispatch order (fused.plan_wait) and, when they
+are ready, marks the flight done under the plane's condition variable
+and wakes the dispatcher, whose own probe still decides what lands; a
+5 ms slice is the backstop for what the lander cannot see (a later
+flight of a deeper deck that finishes first, a runtime with no
+blocking wait). The size-aware policy in fused.plan_fused sends a
+flush past one half's budget (or the half_mesh_rows knob) to the full
+mesh after draining the deck. The private staging pool is flights+1
+deep per shape so pack(k+2) never waits on a buffer still pinned under
+flight k.
 
 QoS lanes (overload resilience): every submission rides one of three
 priority classes.  CONSENSUS (the default: gossiped votes, commits,
@@ -77,6 +85,7 @@ from __future__ import annotations
 
 import itertools
 import logging
+import queue
 import threading
 import time
 from collections import deque
@@ -693,14 +702,16 @@ class _Flight:
     deferred finish() that blocks for verdicts, whether a device pass
     is genuinely airborne, the flush id, the ledger scratch record,
     the device ids the pass occupies (None = single-device/host — the
-    deck's disjoint-halves bookkeeping), and an optional non-blocking
-    readiness probe for out-of-order landing."""
+    deck's disjoint-halves bookkeeping), an optional non-blocking
+    readiness probe for out-of-order landing, and its blocking twin
+    for the lander thread, which sets `done` (under the plane's
+    condition variable) once that wait returned."""
 
     __slots__ = ("batch", "finish", "airborne", "fid", "led", "devs",
-                 "ready", "pack_idx")
+                 "ready", "wait", "done", "pack_idx")
 
     def __init__(self, batch, finish, airborne, fid, led, devs=None,
-                 ready=None, pack_idx=0):
+                 ready=None, wait=None, pack_idx=0):
         self.batch = batch
         self.finish = finish
         self.airborne = airborne
@@ -708,6 +719,8 @@ class _Flight:
         self.led = led
         self.devs = devs
         self.ready = ready
+        self.wait = wait
+        self.done = False
         # per-plane pack ordinal: the staging pool rotates flights+1
         # slots round-robin, so pack m reuses pack m-(flights+1)'s
         # buffers — the dispatcher force-lands any flight that old
@@ -725,6 +738,13 @@ def _ready_index(deck) -> Optional[int]:
         if f.ready is not None and f.ready():
             return i
     return None
+
+
+def _marked(deck) -> int:
+    """How many deck flights the lander has marked done. A mark never
+    clears and only the dispatcher changes the deck, so within one
+    landing wait a higher count is a mark made since the last look."""
+    return sum(f.done for f in deck)
 
 
 def _host_verdicts(rows) -> List[bool]:
@@ -811,6 +831,10 @@ class VerifyPlane:
         self._pending: dict = {lane: deque() for lane in LANES}
         self._pending_rows: dict = {lane: 0 for lane in LANES}
         self._thread: Optional[threading.Thread] = None
+        # the lander (device planes only): blocks on each airborne
+        # flight's outputs and wakes the dispatcher when they are ready
+        self._lander: Optional[threading.Thread] = None
+        self._landq: Optional[queue.SimpleQueue] = None
         self._running = False
         # observability (also mirrored into NodeMetrics when attached)
         self.dispatch_log: deque = deque(maxlen=DISPATCH_LOG_MAX)
@@ -909,6 +933,13 @@ class VerifyPlane:
             # plane's flushes trigger lands in /dump_devices (refused
             # before jax imports; the dispatch seam re-arms lazily)
             self._listener_armed = deviceledger.arm_compile_listener()
+            # a host-path plane never has a flight airborne: no lander
+            self._landq = queue.SimpleQueue()
+            self._lander = threading.Thread(
+                target=self._land_watch, args=(self._landq,),
+                name="verify-plane-lander", daemon=True
+            )
+            self._lander.start()
         self._thread = threading.Thread(
             target=self._run, name="verify-plane", daemon=True
         )
@@ -922,6 +953,12 @@ class VerifyPlane:
             self._cv.notify_all()
         if self._thread is not None:
             self._thread.join(timeout=5.0)
+        if self._lander is not None:
+            # after the dispatcher: every flight it handed over is in
+            # the queue ahead of the sentinel, and landed already
+            self._landq.put(None)
+            self._lander.join(timeout=5.0)
+            self._lander = self._landq = None
         # resolve anything the dispatcher didn't drain (dispatcher died,
         # or the join timed out mid-flush) so no submitter ever hangs on
         # a stopped plane — and resolve with REAL verdicts via the inline
@@ -1217,6 +1254,7 @@ class VerifyPlane:
         pipeline_flights=1 is exactly the classic single-slot double
         buffer."""
         deck: List[_Flight] = []  # airborne flights, dispatch order
+        landq = self._landq  # the lander's queue; None on a host plane
         while True:
             # self-tuning seam: one controller poke per drain cycle,
             # OUTSIDE the cv (the controller may call actuator setters
@@ -1380,6 +1418,8 @@ class VerifyPlane:
             if flight.airborne:
                 deck.append(flight)
                 self._deck_update(deck)
+                if landq is not None:
+                    landq.put(flight)
                 while len(deck) > self.flights:
                     self._land_one(deck)
             else:
@@ -1472,37 +1512,69 @@ class VerifyPlane:
         self._finish_flight(deck.pop(0 if idx is None else idx))
         self._deck_update(deck)
 
+    def _land_watch(self, landq: "queue.SimpleQueue") -> None:
+        """The lander thread: block on each airborne flight's outputs,
+        in dispatch order, and wake the dispatcher when they are ready.
+        The mark is made under the condition variable, so a flight
+        that is done between the dispatcher's probe and its sleep is
+        seen there and never costs a slice. A fault out of the wait is
+        the dispatcher's to meet, in finish() under the breaker: the
+        flight is marked done all the same."""
+        while True:
+            flight = landq.get()
+            if flight is None:  # stop()'s sentinel
+                return
+            try:
+                flight.wait()
+            except Exception:  # noqa: BLE001 - surfaces in finish()
+                pass
+            with self._cv:
+                flight.done = True
+                self._cv.notify_all()
+
     def _land_or_wait(self, deck: List[_Flight]) -> None:
         """Idle-deck landing: settle a READY flight immediately; with
-        none ready, poll in short slices for readiness or new work for
-        up to one window (new work wins — it can fly a free half while
-        the deck stays airborne), then land FIFO regardless: futures
-        must resolve even when the runtime offers no readiness probe.
-        Only ever called with device flights airborne, so the simnet
-        host path (and its ledger determinism) never touches the
-        real-clock polling here. The wait is one "plane.land" stage;
-        what it came to is known, and put in its args, when it ends."""
-        polls, packed = 1, 0
+        none ready, sleep until the lander marks a flight of the deck
+        done, new work arrives or a 5 ms slice runs out, then probe
+        again, for up to max(window, 0.1) s (new work wins — it can fly
+        a free half while the deck stays airborne), then land FIFO
+        regardless: futures must resolve even when the runtime offers
+        no readiness probe. The flights' own probes stay the one
+        authority on what lands: a mark only cuts a sleep short, and
+        the probe that follows it spends it, so a flight whose probe
+        keeps saying "not ready" falls back to the slice and never
+        spins. Only ever called with device flights airborne, so the
+        simnet host path (and its ledger determinism) never touches
+        the real-clock waiting here. The wait is one "plane.land"
+        stage; what it came to is known, and put in its args, when it
+        ends."""
+        polls, packed, woke = 1, 0, 0
         with tracing.stage("plane.land") as land:
+            marked = _marked(deck)  # before the probe that spends them
             idx = _ready_index(deck)
             if idx is None:
                 deadline = time.perf_counter() + max(self.window, 0.1)
                 while True:
                     with self._cv:
-                        if self._running and not self._depth_locked():
-                            self._cv.wait(timeout=0.005)
+                        if self._running and not self._depth_locked() \
+                                and _marked(deck) == marked:
+                            if self._cv.wait(timeout=0.005) \
+                                    and _marked(deck) != marked:
+                                woke = 1  # a mark ended this sleep
                         if self._depth_locked():
                             packed = 1  # pack the new flush first
                             break
+                        marked = _marked(deck)
                     idx = _ready_index(deck)
                     polls += 1
                     if idx is not None or not self._running \
                             or time.perf_counter() >= deadline:
                         break
             # probes made, whether one said ready (else FIFO), whether
-            # new work cut the wait short
+            # new work cut the wait short, whether the lander ended a
+            # sleep of it
             land.args.update(polls=polls, ready=int(idx is not None),
-                             packed=packed)
+                             packed=packed, woke=woke)
             if packed:
                 return
             if idx is None:
@@ -1676,7 +1748,7 @@ class VerifyPlane:
             s.future.flush_seq = led[_L_SEQ]
         with tracing.stage("plane.pack", flush=fid, rows=rows,
                            subs=len(batch), queued_ms=queued_ms) as pack:
-            finish, airborne, devs, ready = self._stage_inner(
+            finish, airborne, devs, ready, wait = self._stage_inner(
                 batch, fid, led, deck)
         led[_L_PACK] = round(pack.ms, 3)
         led[_L_TPACKED] = pack.t0 + round(pack.ms * 1e6)  # its end, ns
@@ -1693,7 +1765,7 @@ class VerifyPlane:
 
             ready = probe
         return _Flight(batch, finish, airborne, fid, led, devs, ready,
-                       pack_idx=self._packs)
+                       wait, pack_idx=self._packs)
 
     def _flush_mesh(self, rows: int):
         """The mesh a fused flush of `rows` rows should shard over, or
@@ -1753,7 +1825,7 @@ class VerifyPlane:
             # synchronous flushes — same thread, same ordering)
             led[_L_PATH] = PATH_FAILPOINT
             return (lambda: (_host_verdicts(rows), None)), False, \
-                None, None
+                None, None, None
         plan = None
         if self._use_device:
             # lazy re-arm: start()'s attempt is refused when jax was
@@ -1867,10 +1939,12 @@ class VerifyPlane:
                             self.metrics.plane_shard_rows.inc(len(rows))
                     return out
 
-                # the module-attr lookup keeps the probe patchable
-                # (the forced-4-device deck test gates it)
+                # the module-attr lookups keep the probe and the
+                # lander's wait patchable (the forced-4-device deck
+                # test gates the probe)
                 return finish, True, plan.devs, \
-                    (lambda: fz.plan_ready(plan))
+                    (lambda: fz.plan_ready(plan)), \
+                    (lambda: fz.plan_wait(plan))
             except Exception:  # noqa: BLE001 - device fault at dispatch
                 deviceledger.attr_end(attr)
                 # compiles a FAILED dispatch paid still belong to this
@@ -1888,7 +1962,7 @@ class VerifyPlane:
         # plane.pack span) cover staging; the host/grouped verify runs
         # inside finish() under its own plane.verify span
         return (lambda: (self._verify_rows(rows), None)), False, \
-            None, None
+            None, None, None
 
     def _verify_rows(self, rows) -> List[bool]:
         """One padded device pass under the circuit breaker, or the

@@ -870,53 +870,89 @@ def test_an_idle_plane_writes_one_wait_record_at_most(stage_ring):
 
 
 class _FakeFused:
-    """Stand-ins for verifyplane.fused's five calls the dispatcher
-    makes, so that a flush flies (`path` fused, a flight on the deck, a
-    readiness probe) without a device program: the plane's own loop,
-    stages and ledger are what runs. `ready_after` probes say not
-    ready, then every one says ready (None: never)."""
+    """Stand-ins for verifyplane.fused's six calls the plane makes, so
+    that a flush flies (`path` fused, a flight on the deck, a readiness
+    probe, a blocking wait for the lander) without a device program:
+    the plane's own loop, stages and ledger are what runs. The fake
+    device is done when `done` is set: `plan_wait` blocks until then
+    (the collect, which blocks for the results on a real device too,
+    sets it at the latest). With `ready_after` a number, that many
+    probes say not ready and every later one ready, whatever `done`
+    says (None: never); with "done" the probe follows `done`. Every
+    plan has a `done` of its own; `fake.done` is the newest plan's."""
 
     def __init__(self, ready_after):
         from types import SimpleNamespace
 
         self.ready_after = ready_after
-        self.probes = 0
+        self.probes = 0         # of the newest flight
+        self.waits = 0          # plan_wait calls that returned or raised
+        self.plans = []
+        self.wait_fault = None  # raised out of plan_wait
+        self.collect_fault = None  # raised out of collect_fused, once
+        self.after_probe = None  # called with (plan, probes, answer)
         self.ns = SimpleNamespace
+
+    @property
+    def done(self):
+        return self.plans[-1].done
 
     def install(self, monkeypatch):
         from cometbft_tpu.verifyplane import fused as fz
 
         for name in ("plan_fused", "dispatch_fused", "collect_fused",
-                     "plan_ready", "plan_h2d_bytes"):
+                     "plan_ready", "plan_wait", "plan_h2d_bytes"):
             monkeypatch.setattr(fz, name, getattr(self, name))
 
     def plan_fused(self, batch, **_):
         rows = [r for sub in batch for r in sub.rows]
         return self.ns(rows=rows, drain_first=False, stamped=True,
                        delta_bytes=0, util=0.25, mesh=None, n_dev=1,
-                       devs=(0,), warm=True)
+                       devs=(0,), warm=True, done=threading.Event())
 
     def dispatch_fused(self, plan):
         time.sleep(0.001)
         self.probes = 0
+        self.plans.append(plan)
 
     def plan_ready(self, plan):
-        self.probes += 1
-        return (self.ready_after is not None
-                and self.probes > self.ready_after)
+        if plan is self.plans[-1]:
+            self.probes += 1
+        if self.ready_after == "done":
+            ans = plan.done.is_set()
+        else:
+            ans = (self.ready_after is not None
+                   and self.probes > self.ready_after)
+        if self.after_probe is not None:
+            self.after_probe(plan, self.probes, ans)
+        return ans
+
+    def plan_wait(self, plan):
+        try:
+            if self.wait_fault is not None:
+                raise self.wait_fault
+            assert plan.done.wait(60.0), "the fake device never finished"
+        finally:
+            self.waits += 1
 
     def plan_h2d_bytes(self, plan):
         return 80 * len(plan.rows)
 
     def collect_fused(self, plan):
         time.sleep(0.001)
+        plan.done.set()
+        fault, self.collect_fault = self.collect_fault, None
+        if fault is not None:
+            raise fault
         return [ed.verify(p.data, m, s) for p, m, s in plan.rows], {}
 
 
 @pytest.mark.parametrize("ready_after,polls,ready", [
     (0, 1, 1),      # ready at the first probe: no sleep at all
     (2, 3, 1),      # two poll slices, then a probe that says ready
-    (None, None, 0),  # the probe never tells: FIFO after the deadline
+    # the probe never tells and the lander's wait never returns: FIFO
+    # after the deadline
+    (None, None, 0),
 ])
 def test_fused_flush_adds_dispatch_land_and_collect(
         stage_ring, monkeypatch, ready_after, polls, ready):
@@ -953,6 +989,9 @@ def test_fused_flush_adds_dispatch_land_and_collect(
     assert [w[4]["deck"] for w in by["plane.wait"]][:2] == [0, 1]
     assert land[4]["flush"] == fid and land[4]["packed"] == 0
     assert land[4]["ready"] == ready and land[4]["polls"] >= 1
+    # the fake device is done only at the collect: every sleep of the
+    # land ran out its slice
+    assert land[4]["woke"] == 0 and fake.waits == 1
     if polls is not None:
         assert land[4]["polls"] == polls == fake.probes
     else:
@@ -989,10 +1028,362 @@ def test_land_cut_short_by_new_work_says_packed(stage_ring, monkeypatch):
         p.stop()
     lands = _by_name(stage_ring.stage_records())["plane.land"]
     assert lands[0][4]["packed"] == 1 and "flush" not in lands[0][4]
-    assert lands[0][4]["ready"] == 0
+    assert lands[0][4]["ready"] == 0 and lands[0][4]["woke"] == 0
     # with one flight allowed the second dispatch lands the first
     # (_land_one: no wait, no stage); the second lands through the wait
     chosen = [ld[4]["flush"] for ld in lands if not ld[4]["packed"]]
     packs = _by_name(stage_ring.stage_records())["plane.pack"]
     assert len(packs) == 2
     assert chosen == [packs[1][4]["flush"]]
+
+
+# -- the landing wait is ended by the lander, not by a clock (ISSUE 38) ------
+
+
+class _LandSleeps:
+    """Looks on at the plane's condition variable: `sleeps` holds the
+    fake's probe count at every sleep the dispatcher makes with a
+    flight airborne (only a land sleeps then), `asleep` is set just
+    before each (the lock is still held: a mark made from then on ends
+    that sleep, it cannot be lost), `marks` counts the lander's
+    notifications and `marked` is set at each. `stretch` lengthens the
+    land's slices, so that on a loaded machine no slice runs out
+    before the mark the test is about to make."""
+
+    def __init__(self, plane, fake, stretch=1):
+        self.sleeps, self.marks = [], 0
+        self.asleep, self.marked = threading.Event(), threading.Event()
+        wait, notify_all = plane._cv.wait, plane._cv.notify_all
+
+        def looked_on_wait(timeout=None):
+            if plane.in_dispatcher() and plane.deck_airborne:
+                self.sleeps.append(fake.probes)
+                self.asleep.set()
+                return wait(timeout * stretch)
+            return wait(timeout)
+
+        def looked_on_notify_all():
+            notify_all()
+            if threading.current_thread() is plane._lander:
+                self.marks += 1
+                self.marked.set()
+
+        plane._cv.wait = looked_on_wait
+        plane._cv.notify_all = looked_on_notify_all
+
+    def finish_when_asleep(self, fake, n, after_s=0.0):
+        """A thread that plays the device for n flushes: each is done
+        `after_s` after the dispatcher went to sleep over it."""
+        def device():
+            for _ in range(n):
+                assert self.asleep.wait(30.0), "no land ever slept"
+                self.asleep.clear()
+                time.sleep(after_s)
+                fake.done.set()
+
+        t = threading.Thread(target=device, name="fake-device", daemon=True)
+        t.start()
+        return t
+
+
+def _lands(tracing):
+    by = _by_name(tracing.stage_records())
+    return by.get("plane.land", []), by.get("plane.pack", [])
+
+
+def test_land_is_woken_when_the_flight_is_done(stage_ring, monkeypatch):
+    """(a) A flight that is done 1 ms after the dispatcher went to
+    sleep over it: ONE sleep, ended by the lander's mark (`woke` 1),
+    then the probe that says ready (`polls` 2), and a land shorter than
+    the 5 ms slice it would have slept out (the best of six)."""
+    fake = _FakeFused("done")
+    fake.install(monkeypatch)
+    p = VerifyPlane(window_ms=0.5, use_device=True)
+    sleeps = _LandSleeps(p, fake, stretch=40)
+    p.start()
+    try:
+        device = sleeps.finish_when_asleep(fake, 6, after_s=0.001)
+        pubs, msgs, sigs, exp = make_rows(6)
+        got = [p.submit(pubs[i], msgs[i], sigs[i]).result(30.0)
+               for i in range(6)]
+        device.join(10.0)
+        lander = p._lander
+        assert lander.is_alive() and lander.name == "verify-plane-lander"
+    finally:
+        p.stop()
+    assert got == [(e,) for e in exp]
+    lands, packs = _lands(stage_ring)
+    assert len(lands) == len(packs) == 6
+    for land, pack in zip(lands, packs):
+        assert land[4] == {"polls": 2, "ready": 1, "packed": 0, "woke": 1,
+                           "flush": pack[4]["flush"]}
+        assert pack[1] + pack[2] <= land[1]
+    # one sleep a land, after the entry probe, and a mark a flight
+    assert sleeps.sleeps == [1] * 6 and sleeps.marks == fake.waits == 6
+    assert all(f["path"] == "fused" for f in p.dump_flushes()["flushes"])
+    assert min(ld[2] for ld in lands) < 5e6, [ld[2] for ld in lands]
+
+
+def test_a_flight_done_before_the_sleep_costs_no_slice(stage_ring,
+                                                       monkeypatch):
+    """(b) The lost wake-up: the entry probe says not ready, and the
+    flight is done and marked before the dispatcher reaches its sleep.
+    The mark is looked at under the lock, so no slice is slept: the
+    next probe follows at once (`woke` 0: no sleep was there to end)."""
+    fake = _FakeFused("done")
+    fake.install(monkeypatch)
+    p = VerifyPlane(window_ms=0.5, use_device=True)
+    sleeps = _LandSleeps(p, fake)
+
+    def done_behind_the_entry_probe(plan, probes, ans):
+        if probes == 1:
+            assert not ans
+            sleeps.marked.clear()
+            plan.done.set()
+            assert sleeps.marked.wait(30.0), "the lander never marked"
+
+    fake.after_probe = done_behind_the_entry_probe
+    p.start()
+    try:
+        pubs, msgs, sigs, exp = make_rows(3)
+        got = [p.submit(pubs[i], msgs[i], sigs[i]).result(30.0)
+               for i in range(3)]
+    finally:
+        p.stop()
+    assert got == [(e,) for e in exp]
+    lands, packs = _lands(stage_ring)
+    assert [ld[4] for ld in lands] == [
+        {"polls": 2, "ready": 1, "packed": 0, "woke": 0,
+         "flush": pk[4]["flush"]} for pk in packs] and len(lands) == 3
+    assert sleeps.sleeps == [] and sleeps.marks == 3
+
+
+def test_a_mark_is_spent_by_the_probe_that_follows_it(stage_ring,
+                                                      monkeypatch):
+    """A flight whose probe keeps saying not ready after its mark (a
+    gated probe, a runtime whose wait returns early) falls back to the
+    slice: it lands FIFO after the deadline and the land never spins."""
+    fake = _FakeFused(None)  # the probe never tells
+    fake.install(monkeypatch)
+    fake.after_probe = lambda plan, probes, ans: plan.done.set()
+    p = VerifyPlane(window_ms=0.5, use_device=True)
+    sleeps = _LandSleeps(p, fake)
+    p.start()
+    try:
+        pubs, msgs, sigs, exp = make_rows(2)
+        assert p.submit(pubs[1], msgs[1], sigs[1]).result(30.0) == (exp[1],)
+    finally:
+        p.stop()
+    (land,), _ = _lands(stage_ring)
+    assert land[4]["ready"] == 0 and land[4]["packed"] == 0
+    assert land[2] >= 0.1e9 and sleeps.marks == 1
+    # a slice a probe but for the one the mark cut short or spared:
+    # 20 slices fit the 0.1 s, a spin would make thousands of probes
+    assert 2 <= land[4]["polls"] == fake.probes <= 24
+    assert len(sleeps.sleeps) >= land[4]["polls"] - 2
+
+
+def test_a_fault_in_the_landers_wait_reaches_the_breaker_through_finish(
+        stage_ring, monkeypatch):
+    """(c) `plan_wait` raises: the lander swallows it and marks the
+    flight done all the same; the fault surfaces in finish(), under the
+    breaker, and the rows get their verdicts from the host. The lander
+    lives on and wakes the dispatcher for the next flight."""
+    fake = _FakeFused("done")
+    fake.install(monkeypatch)
+    fake.wait_fault = RuntimeError("injected fault in the blocking wait")
+    fake.collect_fault = RuntimeError("injected fault in flight")
+    breaker = cbatch.CircuitBreaker(failure_threshold=2)
+    p = VerifyPlane(window_ms=0.5, use_device=True, breaker=breaker)
+    sleeps = _LandSleeps(p, fake, stretch=40)
+    p.start()
+    try:
+        pubs, msgs, sigs, exp = make_rows(4)
+        got = p.submit_and_wait(pubs, msgs, sigs)
+        assert list(got) == exp and breaker.faults == 1
+        assert sleeps.marks == fake.waits == 1 and p._lander.is_alive()
+        fake.wait_fault = None
+        sleeps.asleep.clear()
+        device = sleeps.finish_when_asleep(fake, 1)
+        assert list(p.submit_and_wait(pubs, msgs, sigs)) == exp
+        device.join(10.0)
+        assert p._lander.is_alive()
+    finally:
+        p.stop()
+    first, second = p.dump_flushes()["flushes"]
+    assert (first["path"], first["stamp"]) == ("fused_host_fallback", "host")
+    assert (second["path"], second["stamp"]) == ("fused", "device")
+    assert breaker.faults == 1 and breaker.state == "closed"
+    lands, packs = _lands(stage_ring)
+    # the faulted flight: its probe never said ready, it landed FIFO
+    assert lands[0][4]["ready"] == 0
+    assert lands[0][4]["flush"] == packs[0][4]["flush"]
+    assert lands[1][4] == {"polls": 2, "ready": 1, "packed": 0, "woke": 1,
+                           "flush": packs[1][4]["flush"]}
+    assert sleeps.marks == fake.waits == 2
+
+
+def _plane_threads(before):
+    return sorted(t.name for t in threading.enumerate()
+                  if t not in before and t.name.startswith("verify-plane"))
+
+
+def test_stop_with_a_flight_airborne_leaves_no_thread(stage_ring,
+                                                      monkeypatch):
+    """(f) stop() with a flight airborne: its verdicts resolve, neither
+    the dispatcher nor the lander outlives the call, and the plane,
+    started again, lands a flight woken as before."""
+    before = set(threading.enumerate())
+    fake = _FakeFused("done")
+    fake.install(monkeypatch)
+    p = VerifyPlane(window_ms=0.5, use_device=True)
+    sleeps = _LandSleeps(p, fake, stretch=40)
+    pubs, msgs, sigs, exp = make_rows(2)
+    p.start()
+    try:
+        assert _plane_threads(before) == ["verify-plane",
+                                          "verify-plane-lander"]
+        fut = p.submit(pubs[0], msgs[0], sigs[0])
+        assert sleeps.asleep.wait(30.0) and p.deck_airborne == 1
+        assert not fut.done()
+    finally:
+        p.stop()
+    assert fut.done() and fut.result(0) == (exp[0],)
+    assert _plane_threads(before) == [] and p._lander is None
+    assert fake.waits == 1  # the wait returned once the collect had
+    p.stop()  # a second stop is nothing
+    sleeps.asleep.clear()
+    p.start()
+    try:
+        assert _plane_threads(before) == ["verify-plane",
+                                          "verify-plane-lander"]
+        device = sleeps.finish_when_asleep(fake, 1)
+        assert p.submit(pubs[1], msgs[1], sigs[1]).result(30.0) == (exp[1],)
+        device.join(10.0)
+    finally:
+        p.stop()
+    assert _plane_threads(before) == []
+    lands, packs = _lands(stage_ring)
+    assert [ld[4]["flush"] for ld in lands] == [pk[4]["flush"]
+                                                for pk in packs]
+    assert (lands[0][4]["ready"], lands[0][4]["woke"]) == (0, 0)
+    assert lands[1][4]["woke"] == 1 and lands[1][4]["polls"] == 2
+
+
+def test_a_host_path_plane_starts_no_lander(stage_ring):
+    """(g) No flight is ever airborne on the host path: no lander
+    thread, no queue, and the flush's stages are what they were."""
+    before = set(threading.enumerate())
+    p = VerifyPlane(window_ms=0.5, use_device=False)
+    p.start()
+    try:
+        assert _plane_threads(before) == ["verify-plane"]
+        assert p._lander is None and p._landq is None
+        pubs, msgs, sigs, exp = make_rows(3)
+        assert list(p.submit_and_wait(pubs, msgs, sigs)) == exp
+    finally:
+        p.stop()
+    assert _plane_threads(before) == []
+    assert "plane.land" not in _by_name(stage_ring.stage_records())
+
+
+def test_of_two_airborne_flights_the_one_ready_first_lands_first(
+        stage_ring, monkeypatch):
+    """The lander waits on the deck's flights in dispatch order, so a
+    LATER flight that finishes first is the slice's to find (`woke` 0):
+    it lands first, as before. The earlier one, done afterwards, is the
+    lander's: its land is woken."""
+    fake = _FakeFused("done")
+    fake.install(monkeypatch)
+    # a window far longer than the test: a flush is cut by max_batch,
+    # and no land reaches its FIFO deadline
+    p = VerifyPlane(window_ms=20_000.0, max_batch=1, use_device=True,
+                    pipeline_flights=2)
+    sleeps = _LandSleeps(p, fake)
+    p.start()
+    try:
+        pubs, msgs, sigs, exp = make_rows(2)
+        f0 = p.submit(pubs[0], msgs[0], sigs[0])
+        assert sleeps.asleep.wait(30.0) and p.deck_airborne == 1
+        f1 = p.submit(pubs[1], msgs[1], sigs[1])
+        deadline = time.monotonic() + 30.0
+        while p.deck_airborne < 2 and time.monotonic() < deadline:
+            time.sleep(0.001)
+        assert p.deck_airborne == 2 and len(fake.plans) == 2
+        fake.plans[1].done.set()
+        assert f1.result(30.0) == (exp[1],) and not f0.done()
+        assert sleeps.marks == 0  # the lander still waits on the first
+        sleeps.asleep.clear()
+        assert sleeps.asleep.wait(30.0)
+        fake.plans[0].done.set()
+        assert f0.result(30.0) == (exp[0],)
+    finally:
+        p.stop()
+    lands, packs = _lands(stage_ring)
+    fids = [pk[4]["flush"] for pk in packs]
+    assert lands[0][4]["packed"] == 1 and lands[0][4]["woke"] == 0
+    chosen = [ld[4] for ld in lands if not ld[4]["packed"]]
+    assert [c["flush"] for c in chosen] == [fids[1], fids[0]]
+    assert [(c["ready"], c["woke"]) for c in chosen] == [(1, 0), (1, 1)]
+    assert sleeps.marks == fake.waits == 2
+
+
+def test_new_work_wins_over_a_mark_that_arrives_with_it(stage_ring,
+                                                        monkeypatch):
+    """A submission and the lander's mark reach the sleeping dispatcher
+    together: the land ends `packed` 1 with no flight chosen, the new
+    flush is packed first, and the flight that was ready lands next."""
+    fake = _FakeFused("done")
+    fake.install(monkeypatch)
+    p = VerifyPlane(window_ms=20_000.0, max_batch=1, use_device=True,
+                    pipeline_flights=2)
+    sleeps = _LandSleeps(p, fake, stretch=40)
+    p.start()
+    try:
+        pubs, msgs, sigs, exp = make_rows(2)
+        f0 = p.submit(pubs[0], msgs[0], sigs[0])
+        assert sleeps.asleep.wait(30.0) and p.deck_airborne == 1
+        with p._cv:  # the dispatcher cannot look before both are in
+            f1 = p.submit(pubs[1], msgs[1], sigs[1])
+            fake.plans[0].done.set()
+            deadline = time.monotonic() + 30.0
+            while not fake.plans[0].done.is_set() or fake.waits < 1:
+                assert time.monotonic() < deadline
+                time.sleep(0.001)
+        assert f0.result(30.0) == (exp[0],)
+        fake.done.set()
+        assert f1.result(30.0) == (exp[1],)
+    finally:
+        p.stop()
+    lands, packs = _lands(stage_ring)
+    assert lands[0][4]["packed"] == 1 and "flush" not in lands[0][4]
+    assert len(packs) == 2
+    chosen = [ld[4]["flush"] for ld in lands if not ld[4]["packed"]]
+    assert chosen == [pk[4]["flush"] for pk in packs]
+
+
+def test_plan_wait_returns_once_the_outputs_are_ready():
+    """fused.plan_wait: at once where nothing is pending, else when
+    every pending array is ready; it fetches nothing and returns
+    nothing; a fault in flight raises out of it."""
+    from types import SimpleNamespace
+
+    import jax.numpy as jnp
+
+    from cometbft_tpu.verifyplane import fused as fz
+
+    plan = SimpleNamespace(pending=None)
+    assert fz.plan_wait(plan) is None and fz.plan_ready(plan)
+    x = jnp.arange(8) * 3
+    plan.pending = (x > 5, x + 1, x[:1] > 0)
+    assert fz.plan_wait(plan) is None
+    assert fz.plan_ready(plan)
+    assert all(hasattr(a, "is_ready") for a in plan.pending)  # unfetched
+
+    class Faulted:
+        def block_until_ready(self):
+            raise RuntimeError("injected fault in flight")
+
+    plan.pending = (x, Faulted())
+    with pytest.raises(RuntimeError, match="in flight"):
+        fz.plan_wait(plan)
