@@ -7,9 +7,11 @@ framework flows through these functions, so the 0x00/0x01 domain
 separation and the largest-power-of-two-less-than split rule are
 consensus-critical.
 
-Host-side sequential hashing for now. The batched-leaf-hash device kernel
-(thousands of leaves per block at blocksync rates) is a planned pallas op;
-the tree shape logic here stays the single source of truth for it.
+Host-side sequential hashing. One tree has a native twin: a validator
+set's root (types/validator.ValidatorSet.hash) is built in C from the
+members' keys and powers (native.valset_root), leaves, prefixes and
+split as here; tests/test_native.py holds the two to the same bytes.
+The tree shape logic here stays the single source of truth.
 """
 from __future__ import annotations
 

@@ -115,10 +115,10 @@ class Client:
     held across the device-verify wait inside `_verify_one` — two
     threads bisecting disjoint ranges submit to the verify plane
     concurrently, so their flushes coalesce and overlap. Bisection
-    state itself (`cur`, the pivot stack) is method-local; concurrent
-    verifications of overlapping ranges duplicate work at worst (the
-    gateway's coalescer exists to prevent exactly that), never corrupt
-    trust."""
+    state itself (the verified block, the pivot cache) is method-local;
+    concurrent verifications of overlapping ranges duplicate work at
+    worst (the gateway's coalescer exists to prevent exactly that),
+    never corrupt trust."""
 
     def __init__(
         self,
@@ -199,8 +199,7 @@ class Client:
             # walk DOWN from the earliest trusted header, checking each
             # header's last_block_id hash-links to its parent
             return self._verify_backwards(height, now)
-        target = self.primary.light_block(height)
-        target.validate_basic(self.chain_id)
+        target = self._fetch(height, pivot=False)
         if self.skipping:
             self._verify_skipping(latest, target, now)
         else:
@@ -221,8 +220,7 @@ class Client:
             raise LightClientError("trusted anchor expired")
         cur = anchor
         for h in range(anchor.height - 1, height - 1, -1):
-            prev = self.primary.light_block(h)
-            prev.validate_basic(self.chain_id)
+            prev = self._fetch(h, pivot=True)
             self._count_verification()
             want = cur.signed_header.header.last_block_id.hash
             if prev.signed_header.header.hash() != want:
@@ -235,6 +233,16 @@ class Client:
         return cur
 
     # -- verification strategies ------------------------------------------
+
+    def _fetch(self, height: int, pivot: bool) -> LightBlock:
+        """The primary's light block at `height` and its validate_basic,
+        which builds the root of a validator set met for the first time,
+        under the always-on `light.fetch` stage (`pivot` 0 for the
+        target, 1 for a block fetched on the way to it)."""
+        with tracing.stage("light.fetch", height=height, pivot=int(pivot)):
+            lb = self.primary.light_block(height)
+            lb.validate_basic(self.chain_id)
+        return lb
 
     def _verify_one(self, trusted: LightBlock, new: LightBlock,
                     now: Timestamp) -> None:
@@ -269,8 +277,7 @@ class Client:
         """light/client.go:613 verifySequential: walk every height."""
         cur = trusted
         for h in range(trusted.height + 1, target.height):
-            nxt = self.primary.light_block(h)
-            nxt.validate_basic(self.chain_id)
+            nxt = self._fetch(h, pivot=True)
             self._verify_one(cur, nxt, now)
             self.store.save(nxt)
             cur = nxt
@@ -278,28 +285,40 @@ class Client:
 
     def _verify_skipping(self, trusted: LightBlock, target: LightBlock,
                          now: Timestamp) -> None:
-        """light/client.go:706 verifySkipping: try the jump; on
-        ErrNewValSetCantBeTrusted bisect toward the trusted height."""
-        cur = trusted
-        pivot_stack: List[LightBlock] = [target]
-        while pivot_stack:
-            candidate = pivot_stack[-1]
+        """light/client.go:706 verifySkipping. `cache` holds the upper
+        bounds fetched so far, the target first; `depth` is the one
+        tried next. On ErrNewValSetCantBeTrusted the client fetches a
+        pivot halfway between the last verified block and the deepest
+        bound (unless it has one already) and tries it; after EVERY
+        verified block it tries the target again (depth 0), keeping the
+        pivots it fetched above that block. A pivot the primary lacks
+        ends the verification with the error that asked for it, as
+        upstream's benign provider errors do. Unlike upstream, which
+        saves the target alone, every verified block is saved: the
+        gateway's shared store walks them."""
+        cache: List[LightBlock] = [target]
+        depth = 0
+        verified = trusted
+        while True:
+            candidate = cache[depth]
             try:
-                self._verify_one(cur, candidate, now)
-            except ErrNewValSetCantBeTrusted:
-                pivot_h = (cur.height + candidate.height) // 2
-                if pivot_h in (cur.height, candidate.height):
-                    raise LightClientError(
-                        "bisection exhausted: validator set changed too "
-                        "much between adjacent heights"
-                    )
-                pivot = self.primary.light_block(pivot_h)
-                pivot.validate_basic(self.chain_id)
-                pivot_stack.append(pivot)
+                self._verify_one(verified, candidate, now)
+            except ErrNewValSetCantBeTrusted as err:
+                if depth == len(cache) - 1:
+                    pivot_h = verified.height + (
+                        candidate.height - verified.height) // 2
+                    try:
+                        cache.append(self._fetch(pivot_h, pivot=True))
+                    except NoSuchBlockError:
+                        raise err
+                depth += 1
                 continue
             self.store.save(candidate)
-            cur = candidate
-            pivot_stack.pop()
+            if depth == 0:
+                return
+            verified = candidate
+            del cache[depth:]
+            depth = 0
 
     # -- witness cross-examination ----------------------------------------
 

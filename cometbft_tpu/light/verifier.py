@@ -159,13 +159,21 @@ def verify_non_adjacent(
 
     # 1/3+ of the OLD (trusted) set must have signed the new header
     # (light/verifier.go:58); always-on stages around the two checks
-    # split a step's time between them and what the client does around
+    # split a step's time between them and what the client does around;
+    # the first's `refused` is 1 where too little of the old set signed
+    # (the skipping client then bisects)
     try:
-        with tracing.stage("light.trusting", height=untrusted.height):
-            verify_commit_light_trusting(
-                chain_id, trusted_next_vals, untrusted.commit,
-                trust_level, batch_fn,
-            )
+        with tracing.stage("light.trusting",
+                           height=untrusted.height) as st:
+            st.args["refused"] = 0
+            try:
+                verify_commit_light_trusting(
+                    chain_id, trusted_next_vals, untrusted.commit,
+                    trust_level, batch_fn,
+                )
+            except NotEnoughPowerError:
+                st.args["refused"] = 1
+                raise
     except NotEnoughPowerError as e:
         raise ErrNewValSetCantBeTrusted(str(e)) from e
     except VerificationError as e:

@@ -146,6 +146,9 @@ def _load() -> Optional[ctypes.CDLL]:
             i32p, i32p, i32p, i32p, i32p, i32p, u8p, u64p,
         ]
         lib.secp256k1_pack.restype = None
+        lib.valset_root.argtypes = [u8p, ctypes.c_uint64, u8p, u64p,
+                                    ctypes.c_uint64, u8p, u8p]
+        lib.valset_root.restype = None
         # the differential tests' surfaces (tests/test_native.py)
         lib.batch_sha256.argtypes = [u8p, u64p, u64p, ctypes.c_uint64, u8p]
         lib.batch_sha256.restype = None
@@ -438,3 +441,29 @@ def secp256k1_pack(pub_cat: bytes, sig_cat: bytes, msgs, padded: int):
             np.empty((n, 8), np.uint64),
         )
     return qx, qparity, u1dig, u2dig, xr1, xr2, precheck.astype(np.bool_)
+
+
+def valset_root(keys: bytes, klen: int, fields: np.ndarray,
+                powers: np.ndarray) -> Optional[bytes]:
+    """The merkle root of a validator set's SimpleValidator leaves
+    (types/validator.ValidatorSet.hash) in ONE native call: n
+    concatenated keys of `klen` bytes each, each key's PublicKey field
+    number (Validator.bytes()) and voting power (int64). The leaves are
+    written, hashed and folded into crypto/merkle.hash_from_byte_slices's
+    tree in C; n >= 1. None without the native library."""
+    lib = _load()
+    if lib is None:
+        return None
+    n = len(powers)
+    if (not n or not 0 < klen <= 128 or len(keys) != klen * n
+            or len(fields) != n):
+        raise ValueError("valset_root: no validator, keys, fields and "
+                         "powers of unequal number, or a key length "
+                         "outside 1..128")
+    out = np.empty(32, np.uint8)
+    lib.valset_root(
+        np.frombuffer(keys, np.uint8), klen,
+        np.ascontiguousarray(fields, dtype=np.uint8),
+        np.ascontiguousarray(powers, dtype=np.int64).view(np.uint64), n,
+        np.empty((n, 32), np.uint8), out)
+    return out.tobytes()

@@ -797,9 +797,57 @@ inline U256 invmod_n(const U256& a) {
   return r;
 }
 
+// ---- validator-set merkle root -----------------------------------------
+// ValidatorSet.hash (types/validator.py; upstream validator_set.go:347):
+// crypto/merkle.hash_from_byte_slices's RFC 6962 tree (leaf 0x00 ||
+// leaf, inner 0x01 || left || right, split at the largest power of two
+// below n) over the SimpleValidator leaves, each written here from a
+// key and a power as Validator.bytes() writes it:
+//   0x0a len {(field << 3 | 2) klen key} [0x10 uvarint(power)]
+// the power's field left out where it is 0 (proto3).
+
+// the root of n >= 1 leaf hashes lying 32 bytes apart
+void merkle_subtree(const u8* leaves, u64 n, u8* out32) {
+  if (n == 1) {
+    memcpy(out32, leaves, 32);
+    return;
+  }
+  u64 k = 1;
+  while (2 * k < n) k *= 2;
+  u8 inner[65];
+  inner[0] = 1;
+  merkle_subtree(leaves, k, inner + 1);
+  merkle_subtree(leaves + 32 * k, n - k, inner + 33);
+  sha256(inner, 65, out32);
+}
+
 }  // namespace
 
 extern "C" {
+
+// The root of n >= 1 validators: keys n x klen bytes (1 <= klen <= 128),
+// each key's PublicKey oneof field number, powers as int64 two's
+// complement; leaves holds the n leaf hashes between the two sweeps.
+void valset_root(const u8* keys, u64 klen, const u8* fields,
+                 const u64* powers, u64 n, u8* leaves /* n x 32 */,
+                 u8* out32) {
+  u8 leaf[160];  // 0x00 0x0a len(2) tag len(2) key(<=128) 0x10 varint(10)
+  // the PublicKey message: tag, uvarint(klen), the key
+  const int body = 1 + (klen < 128 ? 1 : 2) + (int)klen;
+  for (u64 i = 0; i < n; i++) {
+    int at = 0;
+    leaf[at++] = 0;     // RFC 6962 leaf prefix
+    leaf[at++] = 0x0a;  // field 1, bytes: the PublicKey message
+    at += put_uvarint(leaf + at, (u64)body);
+    leaf[at++] = (u8)(fields[i] << 3 | 2);
+    at += put_uvarint(leaf + at, klen);
+    memcpy(leaf + at, keys + klen * i, klen);
+    at += (int)klen;
+    at += put_field_varint(leaf + at, 2, (long long)powers[i]);
+    sha256(leaf, (u64)at, leaves + 32 * i);
+  }
+  merkle_subtree(leaves, n, out32);
+}
 
 // standalone SHA-256 over rows of one buffer (differential-test surface)
 void batch_sha256(const u8* data, const u64* offs, const u64* lens, u64 n,

@@ -15,6 +15,9 @@ from __future__ import annotations
 from dataclasses import dataclass, replace
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import numpy as np
+
+from cometbft_tpu import native
 from cometbft_tpu.crypto import merkle
 from cometbft_tpu.crypto.keys import PubKey
 from cometbft_tpu.libs import protoenc as pe
@@ -45,10 +48,9 @@ class Validator:
 
     def bytes(self) -> bytes:
         """SimpleValidator proto bytes — the merkle leaf for valset Hash
-        (types/validator.go:119). PublicKey oneof: ed25519 = field 1,
-        secp256k1 = field 2 (proto/tendermint/crypto/keys.proto)."""
-        key_field = 1 if self.pub_key.key_type == "ed25519" else 2
-        pk_body = pe.f_bytes(key_field, self.pub_key.data)
+        (types/validator.go:119)."""
+        pk_body = pe.f_bytes(_key_field(self.pub_key.key_type),
+                             self.pub_key.data)
         return pe.f_msg(1, pk_body) + pe.f_varint(2, self.voting_power)
 
     def compare_proposer_priority(self, other: "Validator") -> "Validator":
@@ -59,6 +61,28 @@ class Validator:
         if self.proposer_priority < other.proposer_priority:
             return other
         return self if self.address < other.address else other
+
+
+def _key_field(key_type: str) -> int:
+    """The PublicKey oneof field a key is written under in a leaf:
+    ed25519 = 1, secp256k1 = 2 (proto/tendermint/crypto/keys.proto)."""
+    return 1 if key_type == "ed25519" else 2
+
+
+def _native_root(vals: Sequence[Validator]) -> Optional[bytes]:
+    """hash()'s root in ONE native call over the members' keys and
+    powers (native.valset_root), or None where there is no member, the
+    library did not build, or a key's length differs from the first's:
+    then the leaves are built in Python."""
+    keys = [v.pub_key for v in vals]
+    klen = len(keys[0].data) if keys else 0
+    if not keys or any(len(k.data) != klen for k in keys):
+        return None
+    n = len(keys)
+    return native.valset_root(
+        b"".join(k.data for k in keys), klen,
+        np.fromiter((_key_field(k.key_type) for k in keys), np.uint8, n),
+        np.fromiter((v.voting_power for v in vals), np.int64, n))
 
 
 def _power_sort_key(v: Validator):
@@ -139,7 +163,11 @@ class ValidatorSet:
         computed once per membership and remembered.
 
         The leaves are Validator.bytes(): key type, key bytes, voting
-        power, in list order. Proposer priorities are not in them, so
+        power, in list order. A root is built in ONE native call over
+        the keys and powers (native.valset_root) where the library
+        loads and every key has one length, else from the leaves in
+        Python: the same bytes (tests/test_native.py). Proposer
+        priorities are not in them, so
         rotating the proposer keeps the memo and copy() carries it over.
         The memo is held against the `validators` list object it was
         computed from: update_with_change_set replaces that list
@@ -155,8 +183,13 @@ class ValidatorSet:
         if memo is not None and memo[0] is self.validators:
             return memo[1]
         vals = self.validators
-        with tracing.stage(HASH_STAGE, n=len(vals)):
-            root = merkle.hash_from_byte_slices([v.bytes() for v in vals])
+        # the stage's `native`: 1 where the C call built the root
+        with tracing.stage(HASH_STAGE, n=len(vals)) as st:
+            root = _native_root(vals)
+            st.args["native"] = int(root is not None)
+            if root is None:
+                root = merkle.hash_from_byte_slices(
+                    [v.bytes() for v in vals])
         self._root = (vals, root)
         return root
 

@@ -108,6 +108,7 @@ def plain_reference():
         os.path.abspath(__file__))), "benchmarks")
     if bench not in sys.path:
         sys.path.insert(0, bench)
-    from reference import ecdsa, plain, schnorrkel
+    from reference import bisection, ecdsa, plain, schnorrkel
 
-    return SimpleNamespace(plain=plain, schnorrkel=schnorrkel, ecdsa=ecdsa)
+    return SimpleNamespace(plain=plain, schnorrkel=schnorrkel, ecdsa=ecdsa,
+                           bisection=bisection)

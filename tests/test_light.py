@@ -4,6 +4,8 @@ validator-set churn, witness divergence, trusting-period expiry.
 Mirrors light/client_test.go + light/verifier_test.go case structure with
 an in-process chain generator standing in for the RPC providers.
 """
+import functools
+
 import pytest
 
 from cometbft_tpu.crypto.keys import PrivKey
@@ -603,8 +605,10 @@ def secp_step(blocks):
             out = ("invalid_header", "not_enough_power", cause.needed)
         else:
             out = ("invalid_header", "double_vote", str(cause)[17:])
-    except lc.NoSuchBlockError:
-        out = ("bisects",)  # ErrNewValSetCantBeTrusted: the pivot is asked
+    except lv.ErrNewValSetCantBeTrusted:
+        # the client asked for the pivot halfway, which this provider
+        # lacks: upstream's loop then ends with the error that asked
+        out = ("bisects",)
     stored = c.store.get(SECP_H1) is not None
     assert stored == (out == ("ok",))
     return out, [r for r in tracing.stage_records()
@@ -649,16 +653,24 @@ def test_a_secp256k1_skip_ends_as_the_plain_reference_says(
                         kw["tampered"])
     if case == "double-vote":
         assert want[:2] == ("invalid_header", "double_vote")
-    # the step and the checks it reached, each closed on its way out
+    # the target's fetch, then the step and the checks it reached,
+    # each closed on its way out; a refused trusting check says so, and
+    # the client goes on to fetch the pivot halfway
     names = [r[0] for r in stages]
     reached = {"accepted": 2, "refused-by-the-new-set": 2,
                "too-few-old-seats": 1, "double-vote": 1}[case]
-    assert names == ["light.trusting", "light.new_set"][:reached] + [
-        "light.step"]
-    assert stages[-1][4] == {"adjacent": 0, "height": SECP_H1}
-    for name, t0, dur, _, _ in stages[:-1]:
-        assert stages[-1][1] <= t0 and t0 + dur <= (stages[-1][1]
-                                                     + stages[-1][2])
+    refused = case == "too-few-old-seats"
+    assert names == ["light.fetch"] + ["light.trusting", "light.new_set"][
+        :reached] + ["light.step"] + ["light.fetch"] * refused
+    assert stages[0][4] == {"height": SECP_H1, "pivot": 0}
+    if refused:
+        assert stages[-1][4] == {"height": (SECP_H0 + SECP_H1) // 2,
+                                 "pivot": 1}
+    assert stages[1][4] == {"height": SECP_H1, "refused": int(refused)}
+    step = stages[reached + 1]
+    assert step[4] == {"adjacent": 0, "height": SECP_H1}
+    for name, t0, dur, _, _ in stages[1:reached + 1]:
+        assert step[1] <= t0 and t0 + dur <= step[1] + step[2]
 
 
 def test_too_few_old_seats_cannot_be_trusted(plain_reference):
@@ -685,5 +697,173 @@ def test_an_adjacent_step_records_the_new_set_check_alone():
     tracing.set_clock(None)
     c.verify_light_block_at_height(2, now=NOW)
     recs = [r for r in tracing.stage_records() if r[0].startswith("light.")]
-    assert [r[0] for r in recs] == ["light.new_set", "light.step"]
+    assert [r[0] for r in recs] == ["light.fetch", "light.new_set",
+                                    "light.step"]
     assert recs[-1][4] == {"adjacent": 1, "height": 2}
+
+
+# --------------------------------------------------------------------------
+# upstream's skipping order, against the benchmark's plain reference
+# (benchmarks/reference/bisection.py) at 48 ed25519 validators
+# --------------------------------------------------------------------------
+
+BIS_H0, BIS_GAP = 10, 100
+BIS_NOW = Timestamp(T0 + BIS_H0 + 8 * BIS_GAP + 60, 0)
+
+
+@functools.lru_cache(maxsize=1)
+def _bis_keys():
+    return [PrivKey.generate(i.to_bytes(2, "big") + b"\x5b" * 30)
+            for i in range(160)]
+
+
+def _bis_power(i):
+    return 500 + (37 * i) % 1001
+
+
+def bisect_chain(plan, tamper=None):
+    """Light blocks k = 0..len(plan)-1 at BIS_H0 + BIS_GAP k, block k's
+    set holding the keys `plan[k]` at unequal powers, every validator
+    signing; `tamper` = (k, row) flips that row's signature. Returns
+    ({height: LightBlock}, {height: the block as the plain reference
+    takes it})."""
+    keys = _bis_keys()
+    blocks, plain = {}, {}
+    for k, seats in enumerate(plan):
+        h = BIS_H0 + BIS_GAP * k
+        by_addr = {keys[i].pub_key().address(): keys[i] for i in seats}
+        vs = ValidatorSet([Validator(keys[i].pub_key(), _bis_power(i))
+                           for i in seats])
+        header = Header(
+            chain_id=CHAIN_ID, height=h, time=Timestamp(T0 + h, 0),
+            last_block_id=BlockID(), validators_hash=vs.hash(),
+            next_validators_hash=vs.hash(),
+            proposer_address=vs.validators[0].address,
+            app_hash=b"\x01" * 32)
+        bid = BlockID(header.hash(), PartSetHeader(1, header.hash()))
+        msgs, sigs = [], []
+        for idx, v in enumerate(vs.validators):
+            ts = Timestamp(T0 + h, idx)
+            msgs.append(canonical.canonical_vote_bytes(
+                CHAIN_ID, canonical.PRECOMMIT_TYPE, h, 0, bid, ts))
+            sigs.append(by_addr[v.address].sign(msgs[-1]))
+        if tamper is not None and tamper[0] == k:
+            sig = sigs[tamper[1]]
+            sigs[tamper[1]] = sig[:5] + bytes([sig[5] ^ 1]) + sig[6:]
+        commit = Commit(h, 0, bid, [
+            CommitSig(BLOCK_ID_FLAG_COMMIT, v.address, Timestamp(T0 + h, i),
+                      sig)
+            for i, (v, sig) in enumerate(zip(vs.validators, sigs))])
+        blocks[h] = lv.LightBlock(lv.SignedHeader(header, commit), vs)
+        plain[h] = {
+            "height": h, "time_ns": header.time.to_ns(),
+            "validators_hash": header.validators_hash,
+            "next_validators_hash": header.next_validators_hash,
+            "pubs": [v.pub_key.data for v in vs.validators],
+            "powers": [v.voting_power for v in vs.validators],
+            "msgs": msgs, "sigs": sigs}
+    return blocks, plain
+
+
+def _sliding(slide=10):
+    return [range(slide * k, slide * k + 48) for k in range(9)]
+
+
+def _bisection_case(case, plain_reference):
+    """(plan, tamper, heights the provider lacks) of each case."""
+    if case == "changes-and-changes-back":
+        a, e = range(48), [*range(24), *range(48, 72)]
+        b, d = range(100, 148), [*range(8), *range(48, 88)]
+        return [a, a, e, b, b, b, b, d, d], None, ()
+    if case == "tampered-pivot":
+        # a row of H6 the new-set check collects whose seat H4 lacks
+        _, plain = bisect_chain(_sliding())
+        h4, h6 = (plain[BIS_H0 + BIS_GAP * k] for k in (4, 6))
+        ecdsa = plain_reference.ecdsa
+        light = ecdsa.light_rows(h6["powers"], h6["sigs"])[0]
+        row = next(i for i in light if h6["pubs"][i] not in h4["pubs"])
+        return _sliding(), (6, row), ()
+    missing = (BIS_H0 + 2 * BIS_GAP,) if case == "missing-pivot" else ()
+    return _sliding(), None, missing
+
+
+@pytest.mark.parametrize("case", ["sliding", "changes-and-changes-back",
+                                  "tampered-pivot", "missing-pivot"])
+def test_bisection_follows_upstreams_skipping_loop(
+        plain_reference, monkeypatch, case):
+    """The client's attempts (trusted height, candidate height, result),
+    its verdict and the heights it verified equal upstream's
+    `verifySkipping` as the plain reference writes it out."""
+    bisection = plain_reference.bisection
+    plan, tamper, missing = _bisection_case(case, plain_reference)
+    blocks, plain = bisect_chain(plan, tamper)
+    target = BIS_H0 + BIS_GAP * 8
+
+    def fetch_plain(h):
+        return None if h in missing else plain.get(h)
+
+    want = bisection.verify_skipping(
+        plain[BIS_H0], target, fetch_plain, BIS_NOW.to_ns(), 1e6)
+
+    def outcome(err):
+        if err is None:
+            return ("ok",)
+        cause = err.__cause__
+        if isinstance(err, lv.ErrNewValSetCantBeTrusted):
+            return ("cant_be_trusted", cause.needed)
+        assert isinstance(cause, validation.InvalidSignatureError)
+        return ("invalid_header", "invalid_signature", cause.idx)
+
+    attempts = []
+
+    def recorded(verify, new_at):
+        def attempt(*args, **kw):
+            err = None
+            try:
+                verify(*args, **kw)
+            except lv.LightClientError as e:
+                err = e
+                raise
+            finally:
+                attempts.append((args[1].height, args[new_at].height,
+                                 outcome(err)))
+        return attempt
+
+    monkeypatch.setattr(lc, "verify_non_adjacent",
+                        recorded(lv.verify_non_adjacent, 3))
+    monkeypatch.setattr(lc, "verify_adjacent",
+                        recorded(lv.verify_adjacent, 2))
+    c = lc.Client(CHAIN_ID, lc.Provider(
+        CHAIN_ID, lambda h: None if h in missing else blocks.get(h)),
+        witnesses=[], trusting_period=1e6, trust_level=(1, 3),
+        batch_fn=validation.oracle_batch_fn())
+    c.trust_light_block(blocks[BIS_H0])
+    try:
+        c.verify_light_block_at_height(target, now=BIS_NOW)
+        verdict = ("trusted",)
+    except lv.LightClientError as e:
+        verdict = ("refused", attempts[-1][1]) + outcome(e)
+    assert (verdict, c.store.heights(), attempts) == want
+    pivots = {"sliding": 3, "changes-and-changes-back": 1,
+              "tampered-pivot": 2, "missing-pivot": 0}[case]
+    assert len(want[1]) - 1 - (want[0] == ("trusted",)) == pivots
+    if case == "sliding":  # 4 refused, then H2, H4, H6 and the target
+        assert [a[:2] for a in want[2]] == [
+            (BIS_H0 + BIS_GAP * i, BIS_H0 + BIS_GAP * j)
+            for i, j in ((0, 8), (0, 4), (0, 2), (2, 8), (2, 4), (4, 8),
+                         (4, 6), (6, 8))]
+        assert [a[2][0] for a in want[2]] == [
+            "cant_be_trusted", "cant_be_trusted", "ok", "cant_be_trusted",
+            "ok", "cant_be_trusted", "ok", "ok"]
+    if case == "changes-and-changes-back":
+        # after H2 the target is tried again and trusted from there; a
+        # client that went on to its next cached pivot (H4) bisects into
+        # the middle era instead, down to heights between light blocks
+        assert want[1] == [BIS_H0, BIS_H0 + 2 * BIS_GAP, target]
+    if case == "tampered-pivot":
+        assert want[0] == ("refused", BIS_H0 + 6 * BIS_GAP, "invalid_header",
+                           "invalid_signature", tamper[1])
+        assert len(want[2]) == 7
+    if case == "missing-pivot":
+        assert want[0][:3] == ("refused", BIS_H0 + 4 * BIS_GAP,
+                               "cant_be_trusted")

@@ -475,3 +475,89 @@ def test_secp256k1_pack_without_the_library(monkeypatch):
                                  4) is None
     pb = eck.pack_batch(pubs, msgs, sigs, pad_to=4)
     assert not pb.native and pb.precheck[:3].all()
+
+
+# --------------------------------------------------------------------------
+# a validator set's merkle root in one native call (native.valset_root)
+# --------------------------------------------------------------------------
+
+
+def _valset(n, key_type, rnd, klen=None):
+    from cometbft_tpu.crypto.keys import PubKey
+    from cometbft_tpu.types.validator import (MAX_TOTAL_VOTING_POWER,
+                                              Validator, ValidatorSet)
+
+    klen = klen or (32 if key_type == "ed25519" else 33)
+    # powers across the varint's byte boundaries, the largest at which
+    # the set's total stays under MAX_TOTAL_VOTING_POWER among them
+    top = MAX_TOTAL_VOTING_POWER // n
+    powers = [1, 127, 128, 16383, 16384, 2**35, top]
+    return ValidatorSet([
+        Validator(PubKey(rnd.randbytes(klen), key_type),
+                  min(top, rnd.choice(powers + [rnd.randint(1, top)])))
+        for _ in range(n)])
+
+
+def _root_and_stage(vs):
+    from cometbft_tpu.libs import tracing
+    from cometbft_tpu.types.validator import HASH_STAGE
+
+    tracing.set_clock(None)  # an empty stage ring
+    root = vs.hash()
+    rec, = [r for r in tracing.stage_records() if r[0] == HASH_STAGE]
+    return root, rec[4]
+
+
+@pytest.mark.parametrize("n", [1, 2, 3, 4, 5, 7, 8, 9, 100, 1000, 10000])
+@pytest.mark.parametrize("key_type", ["ed25519", "secp256k1"])
+def test_valset_root_is_the_merkle_root_of_the_leaves(have_native, n,
+                                                      key_type):
+    """ValidatorSet.hash() through the one C call equals the Python
+    tree over Validator.bytes(), at every shape of the tree's split."""
+    from cometbft_tpu.crypto import merkle
+
+    vs = _valset(n, key_type, random.Random(f"root/{n}/{key_type}"))
+    root, args = _root_and_stage(vs)
+    assert args == {"n": n, "native": 1}
+    assert root == merkle.hash_from_byte_slices(
+        [v.bytes() for v in vs.validators])
+
+
+@pytest.mark.parametrize("why", ["no-library", "a-33-byte-key-among-32",
+                                 "mixed-key-types"])
+def test_valset_root_falls_back_to_the_python_tree(have_native, monkeypatch,
+                                                   why):
+    """No library, or keys of unequal length: the leaves are built in
+    Python, `native` 0, the same root; keys of one length and two types
+    stay in the C call, each under its own field number."""
+    from cometbft_tpu.crypto import merkle
+    from cometbft_tpu.crypto.keys import PubKey
+    from cometbft_tpu.types.validator import Validator
+
+    rnd = random.Random(f"fallback/{why}")
+    vs = _valset(9, "ed25519", rnd)
+    if why == "no-library":
+        monkeypatch.setattr(native, "_load", lambda: None)
+    elif why == "a-33-byte-key-among-32":
+        vs.validators = list(vs.validators)
+        vs.validators[4] = Validator(PubKey(rnd.randbytes(33), "secp256k1"),
+                                     7)
+    else:
+        vs.validators = list(vs.validators)
+        vs.validators[4] = Validator(PubKey(rnd.randbytes(32), "sr25519"), 7)
+    root, args = _root_and_stage(vs)
+    assert args == {"n": 9, "native": int(why == "mixed-key-types")}
+    assert root == merkle.hash_from_byte_slices(
+        [v.bytes() for v in vs.validators])
+
+
+def test_valset_root_wrapper_checks_its_sizes(have_native):
+    keys, fields = b"\x01" * 64, np.ones(2, np.uint8)
+    powers = np.ones(2, np.int64)
+    assert len(native.valset_root(keys, 32, fields, powers)) == 32
+    for bad in ((keys[:-1], 32, fields, powers), (keys, 32, fields[:1],
+                                                   powers),
+                (b"", 32, fields[:0], powers[:0]),
+                (b"\x01" * 258, 129, fields, powers)):
+        with pytest.raises(ValueError):
+            native.valset_root(*bad)

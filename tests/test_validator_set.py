@@ -370,3 +370,29 @@ def test_hash_computes_once_per_membership():
     # whoever replaces the list by hand drops the memo with it
     vs.validators = list(vs.validators)
     assert vs.hash() == changed and _hash_computes() == c0 + 4
+
+
+def test_a_native_root_is_remembered_and_dropped_as_before():
+    """A root the C call built (`native` 1 on its `valset.hash` record)
+    answers every repeated hash() from the memo, and a change set drops
+    it: one more record, a root equal to the Python tree's."""
+    from cometbft_tpu import native
+    from cometbft_tpu.libs import tracing
+    from cometbft_tpu.types.validator import HASH_STAGE
+
+    c = int(native.available())  # no compiler: the Python tree, 0
+
+    def records():
+        return [r[4] for r in tracing.stage_records() if r[0] == HASH_STAGE]
+
+    vals = mkvals([10, 20, 30, 40])
+    vs = ValidatorSet(vals)
+    tracing.set_clock(None)  # an empty stage ring
+    root = vs.hash()
+    for _ in range(3):
+        assert vs.hash() == root and vs.copy().hash() == root
+    assert records() == [{"n": 4, "native": c}]
+    assert root == _fresh_root(vs)
+    vs.update_with_change_set([Validator(vals[1].pub_key, 0)])
+    assert vs.hash() != root and vs.hash() == _fresh_root(vs)
+    assert records() == [{"n": 4, "native": c}, {"n": 3, "native": c}]
