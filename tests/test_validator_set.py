@@ -3,9 +3,11 @@
 Mirrors types/validator_set_test.go case structure (proposer rotation
 frequency proportional to power, update semantics, power cap).
 """
+from dataclasses import replace
+
 import pytest
 
-from cometbft_tpu.crypto.keys import PrivKey
+from cometbft_tpu.crypto.keys import PrivKey, PubKey
 from cometbft_tpu.types.validator import (
     MAX_TOTAL_VOTING_POWER,
     Validator,
@@ -396,3 +398,207 @@ def test_a_native_root_is_remembered_and_dropped_as_before():
     vs.update_with_change_set([Validator(vals[1].pub_key, 0)])
     assert vs.hash() != root and vs.hash() == _fresh_root(vs)
     assert records() == [{"n": 4, "native": c}, {"n": 3, "native": c}]
+
+
+# ---------------------------------------------------------------------------
+# A set is built column-wise (sort, index, total, proposer rounds over
+# int64 arrays); the per-member loops stay as the fallback for values
+# int64 cannot hold. Both give the same set on every input.
+# ---------------------------------------------------------------------------
+
+_I64_MAX, _I64_MIN = 2**63 - 1, -(2**63)
+
+
+def _by_loops(validators):
+    """The set as the per-member loops build it: `sorted`, the index and
+    total over the members, one round of `_rotate_loops`."""
+    from cometbft_tpu.types.validator import _power_sort_key
+
+    vs = ValidatorSet.__new__(ValidatorSet)
+    vs.validators = sorted(validators, key=_power_sort_key)
+    vs._reindex()
+    vs._total_power = None
+    vs._update_total_voting_power()
+    vs.proposer = None
+    vs._rotate_loops(1)
+    return vs
+
+
+def _loop_total(validators):
+    """The reference's running total in set order, and its error."""
+    from cometbft_tpu.types.validator import _power_sort_key
+
+    total = 0
+    for v in sorted(validators, key=_power_sort_key):
+        total += v.voting_power
+        if total > MAX_TOTAL_VOTING_POWER:
+            raise ValidatorSetError(
+                "total voting power exceeds MaxTotalVotingPower")
+    return total
+
+
+def _built(validators):
+    """ValidatorSet(validators) and its `valset.build` record's args."""
+    from cometbft_tpu.libs import tracing
+    from cometbft_tpu.types.validator import BUILD_STAGE
+
+    tracing.set_clock(None)  # an empty stage ring
+    vs = ValidatorSet(validators)
+    rec, = [r for r in tracing.stage_records() if r[0] == BUILD_STAGE]
+    return vs, rec[4]
+
+
+def _fresh(validators):
+    return [replace(v) for v in validators]
+
+
+def _state(vs):
+    return ([(v.address, v.voting_power, v.proposer_priority)
+             for v in vs.validators], vs._index, vs.total_voting_power(),
+            vs.get_proposer().address)
+
+
+def _members(case, rnd):
+    """(validators, rounds on the built set) of one equivalence case."""
+    def key(kind=None):
+        kind = kind or rnd.choice(["ed25519", "secp256k1"])
+        return PubKey(rnd.randbytes(32 if kind == "ed25519" else 33), kind)
+
+    n, times = {"members-1": (1, 0), "members-4": (4, 0),
+                "members-10000": (10000, 0), "rounds-1": (175, 1),
+                "rounds-7": (175, 7)}.get(case, (175, 0))
+    kind = {"secp256k1-keys": "secp256k1",
+            "mixed-key-lengths": None}.get(case, "ed25519")
+    vals = []
+    for _ in range(n):
+        power = 10 if case == "equal-powers" else rnd.randint(500, 1500)
+        prio = (rnd.randint(-10**12, 10**12)
+                if case in ("incoming-priorities", "rounds-7") else 0)
+        vals.append(Validator(key(kind), power, b"", prio))
+    return vals, times
+
+
+@pytest.mark.parametrize("case", [
+    "members-1", "members-4", "members-175", "members-10000",
+    "equal-powers", "incoming-priorities", "secp256k1-keys",
+    "mixed-key-lengths", "rounds-1", "rounds-7"])
+def test_a_columnar_set_is_the_loops_set(case):
+    """Order, index, total, every priority, the proposer, the root, a
+    copy's root and a change set's root: the columns' set (`columnar` 1)
+    equals the loops' on every case, and `increment_proposer_priority`
+    equals `_rotate_loops` on a built set."""
+    import random
+
+    from cometbft_tpu.crypto import merkle
+
+    vals, times = _members(case, random.Random(f"columnar/{case}"))
+    got, args = _built(_fresh(vals))
+    want = _by_loops(_fresh(vals))
+    assert args == {"n": len(vals), "columnar": 1}
+    assert _state(got) == _state(want)
+    assert got.total_voting_power() == _loop_total(vals)
+    if times:
+        got.increment_proposer_priority(times)
+        want._rotate_loops(times)
+        assert _state(got) == _state(want)
+        cp = got.copy_increment_proposer_priority(times)
+        ref = want.copy()
+        ref._rotate_loops(times)
+        assert _state(cp) == _state(ref)
+    root = merkle.hash_from_byte_slices([v.bytes() for v in want.validators])
+    assert got.hash() == want.hash() == root
+    cp = got.copy()  # carries the columns as it carries the root
+    assert cp._cols[0] is cp.validators and cp._cols[1] is got._cols[1]
+    assert cp.hash() == root
+    # a change set replaces the list: the columns go with the root
+    members = got.validators
+    changes = [Validator(members[0].pub_key, 0),
+               Validator(members[-1].pub_key, 777)] if len(members) > 1 \
+        else [Validator(members[0].pub_key, 778)]
+    changes.append(Validator(PubKey(b"\x07" * 32), 5))
+    for vs in (got, want):
+        vs.update_with_change_set(_fresh(changes))
+    assert _state(got) == _state(want)
+    assert got._cols is None or got._cols[0] is got.validators
+    assert got.hash() == merkle.hash_from_byte_slices(
+        [v.bytes() for v in got.validators]) == want.hash()
+
+
+def _edge(case):
+    """(powers, priorities[, addresses]) of one error or fallback case."""
+    top = MAX_TOTAL_VOTING_POWER
+    return {
+        # the duplicate is found before the total, as by the loops
+        "duplicate-address": ([5, 7], [0, 0]),
+        "total-above-cap": ([top, 1], [0, 0]),
+        "running-total-passes-cap": ([top, 5, -10], [0, 0, 0]),
+        "negative-powers-under-cap": ([5, -3, 10], [0, 0, 0]),
+        # the loops' arithmetic leaves int64: they clip or carry it
+        "priority-past-int64": ([10, 20, 30], [2**63, -(2**63) - 1, 0]),
+        "centring-clips": ([0, 0, 0], [_I64_MAX, _I64_MAX, _I64_MIN]),
+        "ratio-past-int64": ([1, 0], [_I64_MAX, _I64_MIN]),
+        "power-near-int64-clips": ([_I64_MIN + 1, 3], [-5, 0]),
+        "addition-wraps": ([10, -10], [_I64_MAX - 3, 3 - _I64_MAX]),
+        # no int64 limit near: the columns, bytes order of addresses
+        # where a null-padded column would tie them
+        "addresses-of-unequal-length": (
+            [7, 7, 7, 7], [0, 0, 0, 0],
+            [b"\x05\x00\x00", b"\x05\x00", b"\x04\xff", b"\x05"]),
+    }[case]
+
+
+@pytest.mark.parametrize("case", [
+    "duplicate-address", "total-above-cap", "running-total-passes-cap",
+    "negative-powers-under-cap", "update-passes-cap", "priority-past-int64",
+    "centring-clips", "ratio-past-int64", "power-near-int64-clips",
+    "addition-wraps", "addresses-of-unequal-length"])
+def test_errors_and_int64_limits_are_the_loops(case):
+    """The errors are the loops' and come at their point; where a value
+    the loops compute leaves int64 the build takes the loops
+    (`columnar` 0) and gives their clipped or unbounded priorities."""
+    if case == "update-passes-cap":
+        # the change set's running total passes the cap where its sum
+        # does not: the error, and the total stays unknown after it
+        vals = mkvals([5, -10])
+        newcomer = mkvals([1, 1, MAX_TOTAL_VOTING_POWER])[2]
+        for vs in (ValidatorSet(_fresh(vals)), _by_loops(_fresh(vals))):
+            for _ in range(2):
+                with pytest.raises(ValidatorSetError, match="exceeds Max"):
+                    vs.update_with_change_set([replace(newcomer)])
+                with pytest.raises(ValidatorSetError, match="exceeds Max"):
+                    vs.total_voting_power()
+        return
+    powers, prios, *addresses = _edge(case)
+    addresses = addresses[0] if addresses else [b""] * len(powers)
+    vals = [Validator(k.pub_key(), p, a, q) for k, p, q, a in
+            zip((PrivKey.generate(bytes([i + 1]) * 32)
+                 for i in range(len(powers))), powers, prios, addresses)]
+    if case == "duplicate-address":
+        vals.append(Validator(vals[0].pub_key, MAX_TOTAL_VOTING_POWER))
+    try:
+        want = _by_loops(_fresh(vals))
+    except ValidatorSetError as e:
+        with pytest.raises(ValidatorSetError, match=str(e)):
+            ValidatorSet(_fresh(vals))
+        if case != "duplicate-address":
+            with pytest.raises(ValidatorSetError, match=str(e)):
+                _loop_total(vals)
+        return
+    got, args = _built(_fresh(vals))
+    assert _state(got) == _state(want)
+    assert got.total_voting_power() == _loop_total(vals)
+    assert got.hash() == want.hash()
+    clipped = {v.proposer_priority for v in want.validators}
+    if case in ("negative-powers-under-cap", "addresses-of-unequal-length"):
+        assert args["columnar"] == 1
+        return
+    assert args["columnar"] == 0
+    if case in ("centring-clips", "power-near-int64-clips",
+                "addition-wraps"):
+        assert clipped & {_I64_MIN, _I64_MAX}  # the loops clipped
+    for times in (1, 7):  # a built set's rounds take the loops too
+        cp = got.copy()
+        cp.increment_proposer_priority(times)
+        ref = want.copy()
+        ref._rotate_loops(times)
+        assert _state(cp) == _state(ref)
