@@ -376,19 +376,27 @@ class CatchupEngine:
                 f"before tip {tip}"
             )
         # pre-scan: one fused segment = consecutive buffered blocks
-        # under the CURRENT valset, bounded at the first hash change
-        with tracing.stage("catchup.scan"):
+        # under the CURRENT valset, bounded at the first hash change.
+        # The stage says how long the segment is and what closed it:
+        # a set change to other keys (or to a set the state does not
+        # know yet), a change of powers alone, max_run, or the end of
+        # the read-ahead buffer
+        with tracing.stage("catchup.scan") as st_scan:
             vals = self.state.validators
             vhash = vals.hash()
             seg: List[tuple] = []
             boundary = False
+            closed_by = "buffer"
             for (h, blk, commit) in self._buf:
                 if blk.header.validators_hash != vhash:
                     boundary = True
+                    closed_by = self._boundary_kind(blk)
                     break
                 seg.append((h, blk, commit))
                 if len(seg) >= self.max_run:
+                    closed_by = "max_run"
                     break
+            st_scan.args.update(blocks=len(seg), closed_by=closed_by)
         if not seg:
             h0, blk0, _ = self._buf[0]
             raise CatchupError(
@@ -448,6 +456,18 @@ class CatchupEngine:
             warm_ms=warm_ms, boundary=boundary, warmed=warmed,
         )
         incidents.note_catchup(True)  # progress: re-arm the stall watch
+
+    def _boundary_kind(self, blk) -> str:
+        """What the validator-set change at `blk` is: "powers" where its
+        set is the state's next one (the only set the state knows ahead)
+        and holds the current set's keys, else "keys"."""
+        vals, nxt = self.state.validators, self.state.next_validators
+        if (nxt.hash() == blk.header.validators_hash
+                and len(nxt) == len(vals)
+                and all(vals.has_address(v.address)
+                        for v in nxt.validators)):
+            return "powers"
+        return "keys"
 
     def _maybe_warm_ahead(self) -> bool:
         nv = self.state.next_validators
