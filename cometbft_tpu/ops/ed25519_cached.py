@@ -56,6 +56,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from cometbft_tpu.crypto import ed25519_ref as ref
+from cometbft_tpu.libs import tracing
 from cometbft_tpu.libs.deviceledger import rows_bucket
 from cometbft_tpu.ops import table_cache as tc
 from cometbft_tpu.ops import curve25519 as curve
@@ -220,8 +221,9 @@ class ValsetTable:
         # path for this table; fused.plan_fused falls back to host pack.
         self.pub_raw = pub_raw
         # per-slot ACTUAL pubkey bytes + host power copy — lets
-        # table_for_pubs find a near-miss cached table and compute the
-        # exact (pubkey, power) delta without a device round trip.
+        # table_for_pubs find a cached table of the same keys (the key-
+        # set index) or a near-miss one and compute the exact (pubkey,
+        # power) delta without a device round trip.
         # Full bytes, not digests: the round-5 advisory showed an
         # 8-byte unkeyed digest lets a 2^32-work birthday collision
         # pin a retired key into cached tables (the reference likewise
@@ -294,6 +296,68 @@ def build_table(pub_bytes: Sequence[bytes],
                        padded, _pubs_host(pub_bytes, padded),
                        _powers_host(powers, padded),
                        _pub_raw(pub_bytes, padded))
+
+
+# -- a set of cached keys (powers moved, maybe the order) ------------------
+# The curve columns of a slot depend on its key alone, so a set whose
+# keys, taken as a set, are those of a cached table needs no column
+# built: same slots share the base's device arrays, other slots are
+# one gather of them. Only the powers upload (M x POWER_LIMBS int32).
+
+_CHURN_WARMED: set = set()  # padded sizes whose churn programs ran
+
+
+@jax.jit
+def _permute_slots(tab, ok, pub_raw, perm):
+    """Slot i of the result holds slot perm[i] of the table: the window
+    columns of the blocked (M/128 * ENT_BLOCK, 128) layout, ok and the
+    raw keys. Gathers whole 16 KB columns, slot-major."""
+    m = perm.shape[0]
+    cols = (tab.reshape(m // 128, ENT_BLOCK, 128).transpose(0, 2, 1)
+            .reshape(m, ENT_BLOCK))[perm]
+    tab = (cols.reshape(m // 128, 128, ENT_BLOCK).transpose(0, 2, 1)
+           .reshape(-1, 128))
+    return tab, ok[perm], (None if pub_raw is None else pub_raw[perm])
+
+
+def _rekey(base: ValsetTable, target: tuple, powers,
+           padded: int) -> ValsetTable:
+    """The table of `target` (padded per-slot keys) under `powers`,
+    from `base`, a table of the same keys taken as a set: byte for byte
+    what build_table would build, with no curve column computed.
+    Counted under `rekeyed`, by a lookup and a warm alike (as
+    `incremental_patches` counts a patch)."""
+    with _TABLE_LOCK:
+        _TABLE_STATS["rekeyed"] += 1
+        warm = padded not in _CHURN_WARMED
+        _CHURN_WARMED.add(padded)
+    if warm:
+        _warm_churn(base)
+    power5 = _power_dev(powers, padded)
+    ph = _powers_host(powers, padded)
+    if base.pubs_host == target:  # powers moved, the order did not
+        return ValsetTable(base.tab, base.ok, power5, padded,
+                           base.pubs_host, ph, base.pub_raw)
+    # every slot of one key holds the same columns (dead slots and
+    # malformed keys alike), so any base slot of the key will do
+    slot = {p: i for i, p in enumerate(base.pubs_host)}
+    perm = np.fromiter((slot[p] for p in target), np.int32, padded)
+    tab, ok, pub_raw = _permute_slots(base.tab, base.ok, base.pub_raw,
+                                      jnp.asarray(perm))
+    return ValsetTable(tab, ok, power5, padded, target, ph, pub_raw)
+
+
+def _warm_churn(base: ValsetTable) -> None:
+    """Run, once per padded size, the two programs that the later sets
+    of a chain whose powers move will need: the slot gather of a
+    re-sort, and the patch of a seat change (update_table, here slot 0
+    rewritten with its own key; the result is dropped). Before sets
+    were rekeyed, a chain's first power change compiled the patch.
+    Without this warm, a seat change deep into the stream compiles it
+    there: 38 s on a TPU v5e, with a cold compile cache."""
+    _permute_slots(base.tab, base.ok, base.pub_raw,
+                   jnp.arange(base.n_vals, dtype=jnp.int32))
+    update_table(base, [(0, base.pubs_host[0])])
 
 
 # -- incremental update (validator-set churn) ------------------------------
@@ -432,8 +496,10 @@ def update_table(table: ValsetTable, changes,
 # _TABLE_CACHE: LRU of built tables keyed by the pubkey list
 # (order-sensitive: the validator INDEX is the gather key). Commit
 # verification presents the same valset in the same order every block,
-# so this hits ~always; on a miss, a cached table for a near-identical
-# list (epoch churn) is updated incrementally instead of rebuilt.
+# so this hits ~always; on a miss, a cached table of the same keys in
+# any order (a power change) is rekeyed without building a column, and
+# one for a near-identical list (epoch churn) is updated incrementally
+# instead of rebuilt.
 _TABLE_CACHE = tc.TABLES
 _TABLE_LOCK = tc.LOCK
 _TABLE_STATS = tc.STATS
@@ -544,9 +610,17 @@ def _patch_from_base(cand: ValsetTable, diff, target, powers,
 def table_for_pubs_info(pub_bytes: Sequence[bytes],
                         powers=None) -> Tuple[ValsetTable, bool]:
     """(table, warm): warm=True when the lookup was a straight LRU hit
-    — no build and no incremental patch. The verify plane stamps this
-    into the flush ledger's `warm` column so /dump_flushes attributes
-    a post-rotation stall to the cold table build it actually paid."""
+    — no rekey, no build and no incremental patch. The verify plane
+    stamps this into the flush ledger's `warm` column so /dump_flushes
+    attributes a post-rotation stall to the cold table build it
+    actually paid.
+
+    A table the cache lacks is made by the cheapest of three paths,
+    each a `table.make` stage whose `path` arg names it: "rekeyed"
+    where a cached table holds the same keys, taken as a set (a power
+    change, or a re-sort by power: no curve column computed; counted
+    under `rekeyed`), else "patched" from a near-miss table or
+    "built" in full (both counted under `misses`)."""
     key = _memo_cache_key(pub_bytes, powers)
     with _TABLE_LOCK:
         t = _TABLE_CACHE.get(key)
@@ -554,44 +628,65 @@ def table_for_pubs_info(pub_bytes: Sequence[bytes],
             _TABLE_STATS["hits"] += 1
             tc.consume_warmed(key)
             return t, True
-        _TABLE_STATS["misses"] += 1
-        # near-miss scan: same padded size, few changed slots -> update
-        # the cached table incrementally (valset churn between epochs)
         padded = table_pad(len(pub_bytes))
         target = _pubs_host(pub_bytes, padded)
-        base = _find_incremental_base(target, padded)
-    t = None
-    if base is not None:
-        cand, diff = base
-        t = _patch_from_base(cand, diff, target, powers, padded)
-    if t is None:
-        t = build_table(pub_bytes, powers)
+        same, base = _bases(target, padded)
+        if same is None:
+            _TABLE_STATS["misses"] += 1
+    # no tracer event: a table is made on whichever thread needs it
+    # first (the warmer's among them), at stamps a simnet's virtual
+    # clock cannot make repeat (tracing.stage_untraced)
+    with tracing.stage_untraced("table.make", n=padded) as st:
+        t = None
+        if same is not None:
+            t, path = _rekey(same, target, powers, padded), "rekeyed"
+        elif base is not None:
+            t = _patch_from_base(*base, target, powers, padded)
+            path = "patched"
+        if t is None:  # no base, or its delta overflows the patch
+            t, path = build_table(pub_bytes, powers), "built"
+        st.args["path"] = path
     with _TABLE_LOCK:
         _TABLE_CACHE.put(key, t)
     return t, False
 
 
+def _bases(target, padded: int):
+    """(same, near): the cached table the key-set index names for
+    `target`'s keys, or None; and without one, the near-miss scan's
+    (table, changed slots) — same padded size, few changed slots, to
+    update incrementally (valset churn between epochs) — or None.
+    Callers hold _TABLE_LOCK."""
+    same = _TABLE_CACHE.find(tc.key_set(target))
+    if same is not None:
+        return same, None
+    return None, _find_incremental_base(target, padded)
+
+
 def warm_incremental(pub_bytes: Sequence[bytes], powers=None) -> bool:
-    """The warmer's incremental fast path: when a cached near-miss
-    table covers the change set (<= MAX_INCREMENTAL slots), patch its
-    delta rows into the cache instead of paying the full next-epoch
-    build — byte-identical to the cold build by update_table's
-    construction. Returns True when the target table is now cached
-    (already present, or patched in here); False means no eligible
-    base exists and the caller decides whether to pay the full build.
-    Counts neither a hit nor a miss: this is a warm, not a lookup."""
+    """The warmer's incremental fast path: a cached table of the same
+    keys is rekeyed (`_rekey`), else when a cached near-miss table
+    covers the change set (<= MAX_INCREMENTAL slots), its delta rows
+    are patched into the cache instead of paying the full next-epoch
+    build — byte-identical to the cold build either way. Returns True
+    when the target table is now cached (already present, or made
+    here); False means no eligible base exists and the caller decides
+    whether to pay the full build. Counts neither a hit nor a miss:
+    this is a warm, not a lookup."""
     key = _memo_cache_key(pub_bytes, powers)
     with _TABLE_LOCK:
         if _TABLE_CACHE.get(key) is not None:
             return True
         padded = table_pad(len(pub_bytes))
         target = _pubs_host(pub_bytes, padded)
-        base = _find_incremental_base(target, padded)
-    if base is None:
-        return False
-    cand, diff = base
-    t = _patch_from_base(cand, diff, target, powers, padded)
-    if t is None:
+        same, base = _bases(target, padded)
+    if same is not None:
+        t = _rekey(same, target, powers, padded)
+    elif base is not None:
+        t = _patch_from_base(*base, target, powers, padded)
+        if t is None:
+            return False
+    else:
         return False
     with _TABLE_LOCK:
         _TABLE_CACHE.put(key, t)

@@ -53,7 +53,11 @@ LOCK = threading.RLock()
 # count entries each bounded cache dropped under churn pressure;
 # warmed_hits counts lookups answered by a table the next-epoch warmer
 # pre-built (the first commit after a rotation, when the warmer won).
-STATS = {"hits": 0, "misses": 0, "key_memo_hits": 0,
+# misses count lookups that computed curve columns (a full build or an
+# incremental patch); rekeyed counts tables made from a cached table of
+# the same keys instead, their powers uploaded and slots permuted (by
+# a lookup or a warm, as incremental_patches counts a patch).
+STATS = {"hits": 0, "misses": 0, "rekeyed": 0, "key_memo_hits": 0,
          "valset_hits": 0, "valset_misses": 0,
          "shard_hits": 0, "shard_misses": 0,
          "template_hits": 0, "template_misses": 0,
@@ -87,21 +91,43 @@ def default_size(value) -> int:
     return total
 
 
+def key_set(pubs_host) -> Optional[tuple]:
+    """A table's keys taken as a set: its padded per-slot key bytes,
+    sorted (dead slots are ``b""``). Two tables with one key set hold
+    the same curve columns in some slot order. Whole keys, not digests:
+    an index entry is found by comparing every byte of every key (see
+    ``ValsetTable.pubs_host``). None for a value without host keys."""
+    return None if pubs_host is None else tuple(sorted(pubs_host))
+
+
+def table_key_set(table) -> Optional[tuple]:
+    """:func:`key_set` of a cached table's ``pubs_host`` (None for a
+    table without one): the index of :data:`TABLES`."""
+    return key_set(getattr(table, "pubs_host", None))
+
+
 class BoundedLRU:
     """An LRU mapping with a settable capacity, per-kind eviction
     accounting in :data:`STATS`, and incrementally-maintained resident
-    bytes. NOT internally locked — callers hold :data:`LOCK` (the
+    bytes. With an ``index_fn`` it also keeps, for each index key of a
+    held value, the newest entry inserted under it (:meth:`find`).
+    NOT internally locked — callers hold :data:`LOCK` (the
     ed25519_cached contract)."""
 
-    __slots__ = ("kind", "capacity", "_od", "_size_fn", "_bytes")
+    __slots__ = ("kind", "capacity", "_od", "_size_fn", "_bytes",
+                 "_index_fn", "_ikey", "_index")
 
     def __init__(self, kind: str, capacity: int,
-                 size_fn: Optional[Callable] = None):
+                 size_fn: Optional[Callable] = None,
+                 index_fn: Optional[Callable] = None):
         self.kind = kind
         self.capacity = max(2, int(capacity))
         self._od: "OrderedDict" = OrderedDict()
         self._size_fn = size_fn
         self._bytes = 0
+        self._index_fn = index_fn
+        self._ikey: dict = {}   # cache key -> its value's index key
+        self._index: dict = {}  # index key -> the cache key find() names
 
     def __len__(self) -> int:
         return len(self._od)
@@ -120,20 +146,33 @@ class BoundedLRU:
         """Value for key WITHOUT refreshing recency (scans)."""
         return self._od.get(key)
 
+    def find(self, ikey):
+        """The value the index names for `ikey`, WITHOUT refreshing
+        recency: the newest inserted under it, or once that one left,
+        the most recently used other; None without one or without an
+        ``index_fn``."""
+        key = self._index.get(ikey)
+        return None if key is None else self._od.get(key)
+
     def put(self, key, value) -> None:
         old = self._od.get(key)
-        if old is not None and self._size_fn is not None:
-            self._bytes -= self._size_fn(old)
+        if old is not None:
+            self._forget(key, old)
         self._od[key] = value
         self._od.move_to_end(key)
         if self._size_fn is not None:
             self._bytes += self._size_fn(value)
+        if self._index_fn is not None:
+            ikey = self._index_fn(value)
+            if ikey is not None:
+                self._ikey[key] = ikey
+                self._index[ikey] = key
         self._trim()
 
     def pop(self, key) -> None:
         v = self._od.pop(key, None)
-        if v is not None and self._size_fn is not None:
-            self._bytes -= self._size_fn(v)
+        if v is not None:
+            self._forget(key, v)
 
     def values(self) -> Iterator:
         return self._od.values()
@@ -141,6 +180,8 @@ class BoundedLRU:
     def clear(self) -> None:
         self._od.clear()
         self._bytes = 0
+        self._ikey.clear()
+        self._index.clear()
 
     def resident_bytes(self) -> int:
         return self._bytes
@@ -152,10 +193,24 @@ class BoundedLRU:
 
     def _trim(self) -> None:
         while len(self._od) > self.capacity:
-            _, v = self._od.popitem(last=False)
-            if self._size_fn is not None:
-                self._bytes -= self._size_fn(v)
+            k, v = self._od.popitem(last=False)
+            self._forget(k, v)
             STATS["evictions_" + self.kind] += 1
+
+    def _forget(self, key, value) -> None:
+        """Account for `value` leaving `key`: its bytes, and its index
+        entry, which passes to the most recently used other entry of
+        the same index key, or goes."""
+        if self._size_fn is not None:
+            self._bytes -= self._size_fn(value)
+        ikey = self._ikey.pop(key, None)
+        if ikey is None or self._index.get(ikey) != key:
+            return
+        del self._index[ikey]
+        for k in reversed(self._od):
+            if k != key and self._ikey.get(k) == ikey:
+                self._index[ikey] = k
+                return
 
 
 # -- the cache instances ---------------------------------------------------
@@ -163,8 +218,11 @@ class BoundedLRU:
 # (order-sensitive: the validator INDEX is the gather key). Commit
 # verification presents the same valset in the same order every block,
 # so this hits ~always; epoch churn inserts one new table per epoch
-# and the OLDEST retired epoch evicts.
-TABLES = BoundedLRU("tables", 8, size_fn=default_size)
+# and the OLDEST retired epoch evicts. Indexed by key set: a set whose
+# powers moved (and maybe its order with them) finds the newest table
+# of its keys, whose columns it reuses.
+TABLES = BoundedLRU("tables", 8, size_fn=default_size,
+                    index_fn=table_key_set)
 # (content key, mesh identity) -> ShardedValsetTable: a node serves one
 # live valset per mesh in the steady state; churn evicts.
 SHARDS = BoundedLRU("shard", 4, size_fn=default_size)

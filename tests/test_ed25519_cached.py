@@ -299,3 +299,66 @@ def test_warm_incremental_byte_identical_to_cold_build():
     assert patched.pubs_host == cold.pubs_host
     np.testing.assert_array_equal(patched.powers_host,
                                   cold.powers_host)
+
+
+def test_rekeyed_table_verifies_and_tallies_as_a_cold_build():
+    """A power change that re-sorts the set is served by permuting a
+    cached table of the same keys (`rekeyed`, no column built). Under it
+    the cached verify and tally equal a cold build's, row by row and
+    commit by commit, and the tally takes the NEW powers: commit 1 is
+    signed by the validators that held 2/3 before the change and hold
+    1/11 after it, so it clears 2/3 under the old powers and not under
+    the new ones."""
+    pubs, msgs, sigs = make_sigs(64, msg_fn=lambda i: b"rk-%d" % i)
+    old_power = [10] * 32 + [1] * 32  # upstream's order: power down
+    new_power = [1] * 32 + [10] * 32
+    ec.table_for_pubs(pubs, old_power)
+    order = list(range(32, 64)) + list(range(32))  # sorted anew
+    keys = [pubs[i] for i in order]
+    s0 = ec.table_cache_stats()
+    rekeyed = ec.table_for_pubs(keys, [new_power[i] for i in order])
+    before = ec.table_for_pubs(keys, [old_power[i] for i in order])
+    s1 = ec.table_cache_stats()
+    assert s1["rekeyed"] - s0["rekeyed"] == 2
+    assert s1["misses"] == s0["misses"]
+    cold = ec.build_table(keys, [new_power[i] for i in order])
+
+    M = rekeyed.n_vals
+    assert M == cold.n_vals == 128
+    B = 2 * M  # commit c occupies rows [c*M, c*M + 64), set order
+    signs = [[True] * 64, [i < 32 for i in order]]  # by slot
+    pub_rows, msg_rows, sig_rows = [], [], []
+    counted = np.zeros(B, np.bool_)
+    cids = np.zeros(B, np.int32)
+    for c in range(2):
+        for j, v in enumerate(order):
+            on = signs[c][j]
+            pub_rows.append(keys[j])
+            msg_rows.append(msgs[v] if on else b"")
+            sig_rows.append(sigs[v] if on else b"")
+            counted[c * M + j] = on
+            cids[c * M + j] = c
+        pub_rows += [b""] * (M - 64)
+        msg_rows += [b""] * (M - 64)
+        sig_rows += [b""] * (M - 64)
+    sig_rows[5] = b"\x01" * 64  # a bad signature in commit 0, slot 5
+    total = sum(new_power)
+    thresh = k.threshold_limbs(total * 2 // 3, n_commits=2)
+    pb = k.pack_batch(pub_rows, msg_rows, sig_rows, pad_to=B)
+    rows = ec.pack_rows_cached(pb, counted, cids, thresh)
+
+    got = [tuple(np.asarray(x) for x in
+                 ec.verify_tally_rows_cached(rows, t, 2))
+           for t in (rekeyed, cold, before)]
+    for a, b in zip(got[0], got[1]):
+        np.testing.assert_array_equal(a, b)
+    valid, tally, quorum = got[0]
+    exp = [bool(s) and ed.verify(p, m, s)
+           for p, m, s in zip(pub_rows, msg_rows, sig_rows)]
+    np.testing.assert_array_equal(valid, np.asarray(exp))
+    assert not valid[5] and valid[M + 32:M + 64].all()
+    t = k.tally_to_int(tally)
+    assert t[0] == total - 10 * (order[5] >= 32) - (order[5] < 32)
+    assert t[1] == 32 and not bool(quorum[1]) and bool(quorum[0])
+    # the same rows under the set's previous powers: commit 1 clears
+    assert k.tally_to_int(got[2][1])[1] == 320 and bool(got[2][2][1])
